@@ -19,7 +19,7 @@ def add_model_width_args(p: argparse.ArgumentParser) -> None:
                    help="base channel width — must match the trained "
                         "checkpoint (reference: 128 at 64^2, "
                         "xunet.py:229; smaller widths train/checkpoint "
-                        "faster on slow dev links)")
+                        "faster)")
     p.add_argument("--emb_ch", type=int, default=None,
                    help="conditioning embedding width (reference: 1024)")
     p.add_argument("--num_res_blocks", type=int, default=None,

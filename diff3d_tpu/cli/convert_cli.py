@@ -43,6 +43,8 @@ def main(argv=None) -> None:
     args = build_parser().parse_args(argv)
     logging.basicConfig(level=logging.INFO)
     logging.getLogger("absl").setLevel(logging.WARNING)
+    from diff3d_tpu.runtime import configure_compile_cache
+    configure_compile_cache()
 
     import jax
     import jax.numpy as jnp

@@ -128,7 +128,7 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--pallas", action="store_true",
                    help="route the GroupNorm->FiLM/SiLU epilogues through "
                         "the fused Pallas kernels (ops/pallas_film.py; "
-                        "interpret mode off-TPU).  Equivalent to "
+                        "interpret mode on a CPU process).  Equivalent to "
                         "model.kernels='pallas'")
     p.add_argument("--raw_params", action="store_true",
                    help="serve raw params instead of EMA")
@@ -368,6 +368,8 @@ def main(argv=None) -> None:
     args = build_parser().parse_args(argv)
     logging.basicConfig(level=logging.INFO)
     logging.getLogger("absl").setLevel(logging.WARNING)
+    from diff3d_tpu.runtime import configure_compile_cache
+    configure_compile_cache()
 
     service = build_service(args)
     service.start(serve_http=True)

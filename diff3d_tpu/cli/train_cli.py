@@ -73,7 +73,7 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--pallas", action="store_true",
                    help="route the GroupNorm->FiLM/SiLU epilogues through "
                         "the fused Pallas kernels (ops/pallas_film.py; "
-                        "interpret mode off-TPU).  Equivalent to "
+                        "interpret mode on a CPU process).  Equivalent to "
                         "model.kernels='pallas'")
     p.add_argument("--synthetic", action="store_true",
                    help="train on the procedural dataset (no SRN data "
@@ -151,30 +151,11 @@ def build_parser() -> argparse.ArgumentParser:
     return p
 
 
-def main(argv=None) -> None:
-    args = build_parser().parse_args(argv)
-    logging.basicConfig(level=logging.INFO)
-    logging.getLogger("absl").setLevel(logging.WARNING)
-    if args.synthetic and args.synthetic_scenes:
-        raise SystemExit(
-            "--synthetic and --synthetic_scenes are mutually exclusive")
-    if args.elastic and args.init_from:
-        raise SystemExit(
-            "--elastic and --init_from are mutually exclusive: seed the "
-            "workdir with a plain --init_from run first, then relaunch "
-            "--elastic (it resumes from the latest checkpoint)")
-
-    # Must precede any other JAX use (backend init closes the window).
-    from diff3d_tpu.parallel.multihost import maybe_initialize_distributed
-    maybe_initialize_distributed()
-
-    import jax
-
+def build_config(args):
+    """The :class:`~diff3d_tpu.config.Config` these flags describe — the
+    preset plus every override.  Anything that resumes from a workdir
+    this CLI wrote (``Trainer(..., transfer=True)``) rebuilds it here."""
     from diff3d_tpu import config as config_lib
-    from diff3d_tpu.data import (InfiniteLoader, SRNDataset, SyntheticDataset,
-                                 SyntheticScenesDataset, prefetch_to_device)
-    from diff3d_tpu.parallel import make_mesh
-    from diff3d_tpu.train import Trainer
 
     cfg = {"srn64": config_lib.srn64_config,
            "srn128": config_lib.srn128_config,
@@ -224,6 +205,36 @@ def main(argv=None) -> None:
     if model_over:
         cfg = dataclasses.replace(
             cfg, model=dataclasses.replace(cfg.model, **model_over))
+    return cfg
+
+
+def main(argv=None) -> None:
+    args = build_parser().parse_args(argv)
+    logging.basicConfig(level=logging.INFO)
+    logging.getLogger("absl").setLevel(logging.WARNING)
+    from diff3d_tpu.runtime import configure_compile_cache
+    configure_compile_cache()
+    if args.synthetic and args.synthetic_scenes:
+        raise SystemExit(
+            "--synthetic and --synthetic_scenes are mutually exclusive")
+    if args.elastic and args.init_from:
+        raise SystemExit(
+            "--elastic and --init_from are mutually exclusive: seed the "
+            "workdir with a plain --init_from run first, then relaunch "
+            "--elastic (it resumes from the latest checkpoint)")
+
+    # Must precede any other JAX use (backend init closes the window).
+    from diff3d_tpu.parallel.multihost import maybe_initialize_distributed
+    maybe_initialize_distributed()
+
+    import jax
+
+    from diff3d_tpu.data import (InfiniteLoader, SRNDataset, SyntheticDataset,
+                                 SyntheticScenesDataset, prefetch_to_device)
+    from diff3d_tpu.parallel import make_mesh
+    from diff3d_tpu.train import Trainer
+
+    cfg = build_config(args)
 
     if args.synthetic_scenes:
         ds = SyntheticScenesDataset(num_objects=args.scene_objects,

@@ -18,9 +18,18 @@ JSON ready line to stdout (``{"ready": true, "port": ..., "name":
 ``--hbm_budget_bytes`` arms the admission gate: requests whose
 resident-records + program-peak arithmetic (the ``runs/memcheck/``
 pins, see ``--memcheck_dir``) exceeds the slice budget are rejected at
-the door with a typed ``ReplicaOverBudget``.  ``--compile_cache DIR``
-points jax's persistent compilation cache at a shared directory so
-sibling workers and blue/green restarts skip cold compiles.
+the door with a typed ``ReplicaOverBudget``.  The persistent compile
+cache is placed by ``diff3d_tpu.runtime.configure_compile_cache``:
+``JAX_COMPILATION_CACHE_DIR`` where it is set, else ``--compile_cache
+DIR``, else the checkout's fixed directory — sibling workers and
+blue/green restarts share it and skip cold compiles.
+
+One process per chip: a TPU belongs to one process at a time, so on a
+real host ONE worker process drives all local chips (in-process
+``Replica``s behind the router are the supported single-host layout).
+Several worker processes on one host, each with its own ``--devices``
+slice, work on the virtual CPU mesh only — on a TPU host the second
+process cannot open the device.
 """
 
 from __future__ import annotations
@@ -87,7 +96,9 @@ def build_parser() -> argparse.ArgumentParser:
                         "pins (default: runs/memcheck)")
     p.add_argument("--compile_cache", default=None,
                    help="persistent XLA compile-cache dir shared "
-                        "across workers/restarts")
+                        "across workers/restarts (ignored where "
+                        "JAX_COMPILATION_CACHE_DIR is set; default: the "
+                        "checkout's .jax_cache)")
     p.add_argument("--shallow", action="store_true",
                    help="with --config test: shallow 2-level UNet")
     p.add_argument("--max_views", type=int, default=None)
@@ -167,7 +178,6 @@ def build_worker(args):
         hbm_budget_bytes=args.hbm_budget_bytes,
         memcheck_dir=(args.memcheck_dir
                       or membudgets.DEFAULT_MANIFEST_DIR),
-        compile_cache=args.compile_cache,
         scan_chunks=args.scan_chunks)
 
 
@@ -182,6 +192,9 @@ def main(argv=None) -> None:
             f"{args.host_device_count}").strip()
     logging.basicConfig(level=logging.INFO)
     logging.getLogger("absl").setLevel(logging.WARNING)
+    from diff3d_tpu.runtime import configure_compile_cache
+    logging.info("compile cache: %s",
+                 configure_compile_cache(args.compile_cache))
 
     worker = build_worker(args)
     worker.start(http_port=args.http_port)

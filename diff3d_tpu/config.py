@@ -60,9 +60,10 @@ class ModelConfig:
     # Kernel backend for the fused GroupNorm->FiLM/SiLU epilogues
     # (ops/pallas_film.py via ops/dispatch.py): 'xla' (default) keeps the
     # plain composition — bit-identical graphs to pre-kernel-layer
-    # checkpoints; 'pallas' forces the fused kernels (interpret mode
-    # off-TPU, so CPU tests exercise the TPU tile program); 'auto' uses
-    # pallas only on a TPU-default-backend process.  CLI: --pallas.
+    # checkpoints; 'pallas' forces the fused kernels or raises (compiled
+    # on a TPU process; interpret mode on a CPU process, so CPU tests
+    # exercise the TPU tile program); 'auto' uses pallas only on a
+    # TPU-default-backend process.  CLI: --pallas.
     kernels: str = "xla"
 
     @property

@@ -13,7 +13,7 @@ pixel 2i of 2H).
 Why this exists: the paper's 128^2 config costs ~4x the compute per
 example of 64^2, and training it from scratch inside a fixed chip-hour
 budget underfits (round-3: held-out PSNR 3.6 dB below the copy baseline
-at 640K examples, RESULTS.md).  Seeding from a trained 64^2 model hands
+at 640K examples).  Seeding from a trained 64^2 model hands
 the 128^2 run everything resolution-independent — geometry conditioning,
 cross-view attention, the denoising prior — so its budget is spent on the
 only new thing, fine spatial detail.  (The reference has no counterpart:

@@ -137,7 +137,7 @@ class SyntheticScenesDataset:
     the model conditions on.  Unlike :class:`SyntheticDataset`'s angle-
     parameterised patterns, these images ARE projections of a consistent
     3D scene, so novel-view synthesis on them is the real task at toy
-    scale — used for the quality-evidence training runs (RESULTS.md) when
+    scale — used for the quality-evidence training runs when
     the SRN zips are absent.  Same ``sample``/``all_views`` contract as
     :class:`diff3d_tpu.data.srn.SRNDataset`.
     """

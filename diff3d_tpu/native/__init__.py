@@ -2,9 +2,11 @@
 
 ``decoder.cpp`` is compiled on first use with the system ``g++`` into
 ``libd3dnative.so`` next to this file (rebuilt automatically when the
-source is newer).  Everything degrades gracefully: if the toolchain or
-libpng is missing, :func:`available` is False and callers (SRNDataset,
-InfiniteLoader) stay on the pure-PIL path.
+source is newer; the library is git-ignored, so a fresh checkout always
+builds it).  If the toolchain or libpng is missing, :func:`available`
+is False and callers (SRNDataset, InfiniteLoader) stay on the pure-PIL
+path; either way the first probe says once, at INFO, which PNG decoder
+the process took.
 
 Public surface:
   * :func:`available` — native runtime usable?
@@ -16,6 +18,7 @@ Public surface:
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
@@ -26,6 +29,8 @@ import numpy as np
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "decoder.cpp")
 _LIB = os.path.join(_DIR, "libd3dnative.so")
+
+log = logging.getLogger(__name__)
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None  # guarded-by: _lock
@@ -60,29 +65,36 @@ def _load() -> Optional[ctypes.CDLL]:
         if _tried:
             return _lib
         _tried = True
-        stale = (not os.path.exists(_LIB)
-                 or os.path.getmtime(_LIB) < os.path.getmtime(_SRC))
-        if stale and not _build():
-            return None
-        try:
-            lib = ctypes.CDLL(_LIB)
-        except OSError:
-            return None
-        lib.d3d_version.restype = ctypes.c_int
-        lib.d3d_decode.restype = ctypes.c_int
-        lib.d3d_decode.argtypes = [ctypes.c_char_p, ctypes.c_int,
-                                   ctypes.POINTER(ctypes.c_float)]
-        lib.d3d_pool_create.restype = ctypes.c_void_p
-        lib.d3d_pool_create.argtypes = [ctypes.c_int]
-        lib.d3d_pool_destroy.argtypes = [ctypes.c_void_p]
-        lib.d3d_pool_decode.restype = ctypes.c_int
-        lib.d3d_pool_decode.argtypes = [
-            ctypes.c_void_p, ctypes.POINTER(ctypes.c_char_p), ctypes.c_int,
-            ctypes.c_int, ctypes.POINTER(ctypes.c_float)]
-        if lib.d3d_version() != 1:
-            return None
-        _lib = lib
+        _lib, how = _load_once()
+        log.info("PNG decoder: %s", how)
         return _lib
+
+
+def _load_once():
+    """``(lib or None, one-line description of the decoder taken)``."""
+    stale = (not os.path.exists(_LIB)
+             or os.path.getmtime(_LIB) < os.path.getmtime(_SRC))
+    if stale and not _build():
+        return None, "PIL (building libd3dnative.so with g++ -lpng failed)"
+    try:
+        lib = ctypes.CDLL(_LIB)
+    except OSError as e:
+        return None, f"PIL (libd3dnative.so did not load: {e})"
+    lib.d3d_version.restype = ctypes.c_int
+    lib.d3d_decode.restype = ctypes.c_int
+    lib.d3d_decode.argtypes = [ctypes.c_char_p, ctypes.c_int,
+                               ctypes.POINTER(ctypes.c_float)]
+    lib.d3d_pool_create.restype = ctypes.c_void_p
+    lib.d3d_pool_create.argtypes = [ctypes.c_int]
+    lib.d3d_pool_destroy.argtypes = [ctypes.c_void_p]
+    lib.d3d_pool_decode.restype = ctypes.c_int
+    lib.d3d_pool_decode.argtypes = [
+        ctypes.c_void_p, ctypes.POINTER(ctypes.c_char_p), ctypes.c_int,
+        ctypes.c_int, ctypes.POINTER(ctypes.c_float)]
+    if lib.d3d_version() != 1:
+        return None, "PIL (libd3dnative.so reports an unknown version)"
+    return lib, ("native libd3dnative.so ("
+                 + ("built now" if stale else "found built") + ")")
 
 
 def available() -> bool:

@@ -9,15 +9,17 @@ the rules are now stated once:
   * ``'xla'``    — the plain XLA composition, always available.  The
     default everywhere: CPU-mesh tests, the analysis pillars' lowering
     passes and converted-checkpoint parity all run it.
-  * ``'pallas'`` — the hand-tiled TPU kernel, IF the registered
-    ``supports`` predicate accepts the operands; otherwise fall back to
-    xla (never an error: an odd shape must not crash a model that merely
-    asked for the fast path).  Off-TPU the kernels run in Pallas
-    interpret mode, so 'pallas' is still honoured there — that is how
-    the CPU tests exercise the exact TPU tile program.
-  * ``'auto'``   — pallas only on a TPU-default-backend process AND when
-    the impl's ``auto`` policy (a measured heuristic, e.g. attention's
-    D>64/L>=4096 rule) says the kernel wins; else xla.
+  * ``'pallas'`` — the hand-tiled TPU kernel.  An explicit request is
+    honoured or it raises: operands the registered ``supports``
+    predicate rejects are a ``ValueError`` naming op and shapes, never a
+    silent swap to xla.  On a CPU process the kernels run in Pallas
+    interpret mode (:func:`interpret_default`) — that is how the CPU
+    tests exercise the exact TPU tile program; on a TPU process they are
+    compiled, always.
+  * ``'auto'``   — the only request that may choose: pallas on a
+    TPU-default-backend process when the impl's ``auto`` policy (a
+    heuristic, e.g. attention's D>64/L>=4096 rule) and ``supports`` both
+    accept the operands; else xla.
 
 Resolution happens at TRACE time from static shapes/dtypes and the
 process-default backend, so dispatch can never introduce a retrace
@@ -71,14 +73,25 @@ def implementations(op: str) -> Dict[str, KernelImpl]:
 
 
 def default_backend() -> str:
-    """Process-default jax backend, 'cpu' when no backend exists yet
-    (conservative: trace-time resolution must never raise)."""
+    """Process-default jax backend.  A backend that fails to come up
+    raises here — it is never read as 'cpu'."""
     import jax
 
-    try:
-        return jax.default_backend()
-    except RuntimeError:  # pragma: no cover - no backend at trace time
-        return "cpu"
+    return jax.default_backend()
+
+
+def interpret_default() -> bool:
+    """Whether a Pallas kernel called without an explicit ``interpret``
+    runs in interpret mode: on a CPU process only.  A TPU process
+    compiles its kernels or the call raises."""
+    return default_backend() != "tpu"
+
+
+def _describe(args, kwargs) -> str:
+    parts = [f"{getattr(a, 'dtype', type(a).__name__)}"
+             f"{list(getattr(a, 'shape', ()))}" for a in args]
+    parts += [f"{k}={v!r}" for k, v in kwargs.items()]
+    return ", ".join(parts)
 
 
 def resolve(op: str, requested: str, *args, **kwargs) -> KernelImpl:
@@ -97,8 +110,14 @@ def resolve(op: str, requested: str, *args, **kwargs) -> KernelImpl:
             f"op {op!r}: requested impl {requested!r} not in "
             "('auto', 'pallas', 'xla')")
     pallas = impls.get("pallas")
-    if requested == "pallas" and pallas is not None \
-            and pallas.supports(*args, **kwargs):
+    if requested == "pallas":
+        if pallas is None:
+            raise KeyError(f"op {op!r} has no 'pallas' implementation")
+        if not pallas.supports(*args, **kwargs):
+            raise ValueError(
+                f"op {op!r}: 'pallas' was requested explicitly but the "
+                f"kernel does not support ({_describe(args, kwargs)}); "
+                "request 'auto' to let dispatch choose, or 'xla'")
         return pallas
     if requested == "auto" and pallas is not None \
             and default_backend() == "tpu" \

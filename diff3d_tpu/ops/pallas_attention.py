@@ -29,8 +29,9 @@ columns contribute nothing to QK^T and stay zero through PV.  All
 accumulation is float32 regardless of input dtype (bf16 inputs still use
 the MXU with f32 accumulation via ``preferred_element_type``).
 
-On non-TPU backends the kernels run in Pallas interpret mode (tests); the
-dispatcher in :mod:`diff3d_tpu.ops.attention` only routes here on TPU.
+On a CPU process the kernels run in Pallas interpret mode (tests); on a
+TPU process they are compiled or the call raises
+(:func:`diff3d_tpu.ops.dispatch.interpret_default`).
 """
 
 from __future__ import annotations
@@ -42,11 +43,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pltpu is importable without TPU; used for CompilerParams only
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from diff3d_tpu.ops import dispatch
 
 LANE = 128          # TPU lane width: head dim is padded to a multiple
 MAX_D = 512         # supported head-dim cap (4 lane tiles in VMEM)
@@ -68,11 +67,7 @@ def _out_struct(shape, dtype, like) -> jax.ShapeDtypeStruct:
     """ShapeDtypeStruct carrying ``like``'s varying-manual-axes set, so the
     kernels work inside ``shard_map`` with its default ``check_vma=True``
     (the ring-attention engine path)."""
-    try:
-        vma = jax.typeof(like).vma
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    except (AttributeError, TypeError):  # pragma: no cover - older jax
-        return jax.ShapeDtypeStruct(shape, dtype)
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
 def supports(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray) -> bool:
@@ -100,16 +95,14 @@ def _key_mask(ki: jax.Array, block_k: int, Lk: int) -> jnp.ndarray:
 
 
 def _compiler_params(interpret: bool):
-    if pltpu is None or interpret:
+    if interpret:
         return None
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 def _vmem(shape):
-    if pltpu is not None:
-        return pltpu.VMEM(shape, jnp.float32)
-    return pl.ANY  # pragma: no cover
+    return pltpu.VMEM(shape, jnp.float32)
 
 
 # --------------------------------------------------------------------------
@@ -420,18 +413,15 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     """Flash attention over ``[B, L, H, D]`` (jax.nn layout).
 
     ``scale`` defaults to ``1/sqrt(D)`` (matching
-    ``jax.nn.dot_product_attention``).  ``interpret`` defaults to True off
-    TPU so the same kernel runs everywhere (tests exercise the exact tile
-    program the TPU executes).
+    ``jax.nn.dot_product_attention``).  ``interpret`` defaults to True on
+    a CPU process only (tests exercise the exact tile program the TPU
+    compiles).
     """
     assert supports(q, k, v), (q.shape, k.shape, v.shape, q.dtype)
     if scale is None:
         scale = float(1.0 / np.sqrt(q.shape[-1]))
     if interpret is None:
-        try:
-            interpret = jax.devices()[0].platform != "tpu"
-        except RuntimeError:  # pragma: no cover
-            interpret = True
+        interpret = dispatch.interpret_default()
     return _flash(q, k, v, scale, bool(interpret))
 
 
@@ -452,8 +442,5 @@ def flash_attention_lse(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     if scale is None:
         scale = float(1.0 / np.sqrt(q.shape[-1]))
     if interpret is None:
-        try:
-            interpret = jax.devices()[0].platform != "tpu"
-        except RuntimeError:  # pragma: no cover
-            interpret = True
+        interpret = dispatch.interpret_default()
     return _flash_lse(q, k, v, scale, bool(interpret))

@@ -36,7 +36,9 @@ out-of-range mask groups, so they never pollute real statistics.  All
 accumulation is float32 regardless of input dtype (bf16 inputs use the
 MXU mask matmuls with f32 ``preferred_element_type``).
 
-On non-TPU backends the kernels run in Pallas interpret mode (tests);
+On a CPU process the kernels run in Pallas interpret mode (tests); on a
+TPU process they are compiled or the call raises
+(:func:`diff3d_tpu.ops.dispatch.interpret_default`).
 :mod:`diff3d_tpu.ops.dispatch` only routes here when asked ('pallas')
 or on TPU ('auto').
 """
@@ -49,13 +51,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from diff3d_tpu.ops import dispatch
-
-try:  # pltpu imports without TPU; used for CompilerParams / VMEM only
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
 
 LANE = 128          # TPU lane width: channels padded to a multiple
 MAX_C = 4096        # padded-channel cap (srn128 up-path concat is 2048)
@@ -95,11 +93,7 @@ def _out_struct(shape, dtype, like) -> jax.ShapeDtypeStruct:
     """ShapeDtypeStruct carrying ``like``'s varying-manual-axes set so
     the kernels work inside ``shard_map`` (same contract as
     pallas_attention)."""
-    try:
-        vma = jax.typeof(like).vma
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    except (AttributeError, TypeError):  # pragma: no cover - older jax
-        return jax.ShapeDtypeStruct(shape, dtype)
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
 def supports(x: jnp.ndarray, *args, num_groups: int = 32,
@@ -124,16 +118,14 @@ def _auto(x: jnp.ndarray, *args, **kwargs) -> bool:
 
 
 def _compiler_params(interpret: bool):
-    if pltpu is None or interpret:
+    if interpret:
         return None
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "arbitrary", "arbitrary"))
 
 
 def _vmem(shape):
-    if pltpu is not None:
-        return pltpu.VMEM(shape, jnp.float32)
-    return pl.ANY  # pragma: no cover
+    return pltpu.VMEM(shape, jnp.float32)
 
 
 def _group_masks(C: int, C_pad: int, G_pad: int, group_size: int):
@@ -483,9 +475,8 @@ def fused_groupnorm(x: jnp.ndarray, gamma: jnp.ndarray, beta: jnp.ndarray,
     (float32, like Flax keeps them); ``scale`` / ``shift`` — both or
     neither — are per-pixel FiLM tensors shaped like ``x`` and the
     epilogue becomes ``y * (1 + scale) + shift``.  ``silu`` appends the
-    activation.  ``interpret`` defaults to True off TPU so the same
-    tile program runs everywhere (the CPU tests exercise exactly what
-    the TPU executes).  Epsilon is the torch-parity 1e-5.
+    activation.  ``interpret`` defaults to True on a CPU process only
+    (the CPU tests exercise exactly the tile program the TPU compiles).  Epsilon is the torch-parity 1e-5.
     """
     assert supports(x, num_groups=num_groups), \
         (x.shape, x.dtype, num_groups)
@@ -498,10 +489,7 @@ def fused_groupnorm(x: jnp.ndarray, gamma: jnp.ndarray, beta: jnp.ndarray,
         scale = jnp.zeros((), x.dtype)
         shift = jnp.zeros((), x.dtype)
     if interpret is None:
-        try:
-            interpret = jax.devices()[0].platform != "tpu"
-        except RuntimeError:  # pragma: no cover
-            interpret = True
+        interpret = dispatch.interpret_default()
     return _fused(x, gamma, beta, scale, shift, int(num_groups), film,
                   bool(silu), bool(interpret))
 

@@ -1,33 +1,10 @@
-import inspect
-
-try:                                     # jax >= 0.5: top-level export
-    from jax import shard_map as _shard_map
-except ImportError:                      # jax 0.4.x: experimental home
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map
 
 from diff3d_tpu.parallel.mesh import (MeshEnv, batch_sharding, make_mesh,
                                       param_sharding, replicated_sharding,
                                       tp_param_sharding)
 from diff3d_tpu.parallel.multihost import maybe_initialize_distributed
 from diff3d_tpu.parallel.ring_attention import ring_sdpa, ulysses_sdpa
-
-_SHARD_MAP_PARAMS = frozenset(inspect.signature(_shard_map).parameters)
-
-
-def shard_map(f, **kw):
-    """Version-stable ``shard_map``: one import site for the whole repo.
-
-    jax moved ``shard_map`` out of ``jax.experimental`` and renamed its
-    replication check ``check_rep`` -> ``check_vma`` across the 0.4/0.5
-    boundary; this wrapper resolves the import and translates the kwarg
-    either way so callers write the modern spelling everywhere.
-    """
-    if "check_vma" in kw and "check_vma" not in _SHARD_MAP_PARAMS:
-        kw["check_rep"] = kw.pop("check_vma")
-    elif "check_rep" in kw and "check_rep" not in _SHARD_MAP_PARAMS:
-        kw["check_vma"] = kw.pop("check_rep")
-    return _shard_map(f, **kw)
-
 
 __all__ = [
     "MeshEnv", "make_mesh", "batch_sharding", "param_sharding",

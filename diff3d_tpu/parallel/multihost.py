@@ -2,15 +2,17 @@
 
 The reference hardcodes ``MASTER_ADDR=localhost`` and spawns one process
 per GPU with gloo TCP rendezvous (``/root/reference/train.py:181-187``) —
-single-node only.  On TPU pods, ``jax.distributed.initialize()`` picks up
-the coordinator from the TPU runtime environment automatically; after it,
-``jax.devices()`` spans every host and the mesh layer (``mesh.py``) scales
-unchanged from 1 chip to a full pod.
+single-node only.  Here ONE process drives every chip of its host, so a
+single-host start never touches ``jax.distributed``; a multi-process job
+names its coordinator (``JAX_COORDINATOR_ADDRESS``, which JAX itself
+reads, or explicit arguments) and after the rendezvous ``jax.devices()``
+spans every host and the mesh layer (``mesh.py``) scales unchanged.
 """
 
 from __future__ import annotations
 
 import logging
+import os
 
 import jax
 
@@ -32,16 +34,28 @@ def maybe_initialize_distributed(coordinator_address: str | None = None,
                                  num_processes: int | None = None,
                                  process_id: int | None = None,
                                  retry: RetryPolicy | None = None) -> bool:
-    """Initialise JAX's multi-host runtime if we're in a multi-process job.
+    """Initialise JAX's multi-host runtime if a multi-process job was
+    configured; returns whether this process is part of one.
 
     MUST run before any other JAX call (``jax.distributed.initialize``
     refuses once a backend exists) — call it first thing in ``main``.
-    Single-process environments (no coordinator configured) fall through
-    and return False; an already-initialised runtime returns True.
-    Transient coordinator-dial faults (workers racing the coordinator at
-    pod bring-up) are retried under ``retry`` (default: 4 attempts with
-    5-30 s backoff) before surfacing.
+
+    A job is configured when a coordinator is named: the
+    ``coordinator_address`` argument or ``JAX_COORDINATOR_ADDRESS`` in
+    the environment.  Without one this is a single-host start and NO call
+    is made — a bare ``jax.distributed.initialize()`` would go looking
+    for a cluster (metadata-server lookups that hang or retry for minutes
+    on a machine without a network).  With one, what is still unset
+    (``num_processes``, ``process_id``) is left to JAX's cluster
+    detection, transient coordinator-dial faults (workers racing the
+    coordinator at bring-up) are retried under ``retry`` (default: 4
+    attempts with 5-30 s backoff), and any other failure propagates: a
+    configured multi-process start that fails, fails.
     """
+    if not (coordinator_address
+            or os.environ.get("JAX_COORDINATOR_ADDRESS")):
+        log.debug("single-host start: jax.distributed not initialised")
+        return False
     policy = retry or _INIT_RETRY
     try:
         policy.call(
@@ -50,20 +64,12 @@ def maybe_initialize_distributed(coordinator_address: str | None = None,
                 num_processes=num_processes, process_id=process_id),
             describe="jax.distributed.initialize")
     except RuntimeError as e:
-        # Either already initialised (fine) or initialise-after-backend-use
-        # (a real bug in the caller's ordering) — distinguish loudly.
-        if "already" in str(e).lower():
-            return jax.process_count() > 1
-        log.warning("jax.distributed.initialize failed: %s", e)
-        return jax.process_count() > 1
-    except ValueError as e:
-        # No coordinator available: single-process run (CPU dev box or
-        # single-host TPU without a pod runtime).
-        log.debug("single-process run (no coordinator): %s", e)
-        return False
+        if "only be called once" not in str(e):
+            raise
+        log.debug("jax.distributed already initialised")
     log.info("jax.distributed up: process %d/%d, %d global devices",
              jax.process_index(), jax.process_count(), jax.device_count())
-    return True
+    return jax.process_count() > 1
 
 
 def shutdown_distributed() -> bool:
@@ -103,7 +109,7 @@ def reinitialize_distributed(coordinator_address: str | None = None,
     :func:`maybe_initialize_distributed` re-dials under the usual
     bring-up retry policy (workers race the restarted coordinator exactly
     as at first launch).  Returns the new multi-process status.
-    Single-process runs (no coordinator) are a cheap no-op returning
+    Single-host runs (no coordinator named) are a cheap no-op returning
     False, so the supervisor can call this unconditionally.
     """
     shutdown_distributed()

@@ -64,12 +64,11 @@ def _pick_engine(q, k, v, impl: str):
     if impl == "pallas":
         assert supports(q, k, v), (q.shape, q.dtype)
         return _block_olse_pallas
-    # 'auto': flash kernel wherever it lowers (TPU) and shapes qualify
-    try:
-        on_tpu = jax.devices()[0].platform == "tpu"
-    except RuntimeError:  # pragma: no cover
-        on_tpu = False
-    return (_block_olse_pallas if on_tpu and supports(q, k, v)
+    # 'auto': flash kernel on a TPU process where shapes qualify
+    from diff3d_tpu.ops import dispatch
+
+    return (_block_olse_pallas
+            if dispatch.default_backend() == "tpu" and supports(q, k, v)
             else _block_olse_einsum)
 
 

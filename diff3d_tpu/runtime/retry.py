@@ -1,19 +1,18 @@
 """Typed retry policy for transient backend and IO faults.
 
-One policy object answers three questions the trainer, the serving
-engine and ``bench.py`` used to answer independently (and differently):
+One policy object answers three questions the trainer and the serving
+engine used to answer independently (and differently):
 
 * **Is this error worth retrying?**  Typed classification: anything
-  deriving from :class:`RetryableError` is, a :class:`BackendDialTimeout`
-  (the runtime *hung* rather than failed — re-dialing just hangs again)
-  is not, and for everything else a small set of transport-level message
-  markers ("UNAVAILABLE", "DEADLINE_EXCEEDED", ...) decides.
+  deriving from :class:`RetryableError` is, and for everything else a
+  small set of transport-level message markers ("UNAVAILABLE",
+  "DEADLINE_EXCEEDED", ...) decides.
 * **How long do we wait?**  Exponential backoff with a cap and
   deterministic seeded jitter, so chaos tests replay exactly and a fleet
   of preempted workers does not re-dial in lockstep.
 * **What happened?**  ``call(..., attempts_log=...)`` records every
-  failed attempt and its backoff so callers (bench's structured failure
-  JSON, the checkpoint writer log) can report what the policy did.
+  failed attempt and its backoff so callers (the checkpoint writer log)
+  can report what the policy did.
 
 This module deliberately imports no JAX at module scope — classifying
 errors and sleeping must stay cheap and importable everywhere, including
@@ -45,20 +44,8 @@ class RetryableError(RuntimeError):
         self.retry_after_s = retry_after_s
 
 
-class BackendDialTimeout(TimeoutError):
-    """The accelerator runtime hung during initialization.
-
-    Distinct from an ordinary dial *failure*: a hang past the alarm
-    deadline means the runtime is wedged (dead dev tunnel, stuck
-    coordinator) and re-dialing in-process tends to hang again, so the
-    classifier treats this as non-retryable and callers fail fast with
-    a structured record instead of burning the retry budget.
-    """
-
-
 #: Lower-cased substrings that mark an exception as a transient
-#: transport/backend fault.  Sourced from gRPC status names plus the
-#: failure strings seen in real bench rounds (RESULTS.md r04-r05).
+#: transport/backend fault.  Sourced from gRPC status names.
 _TRANSIENT_MARKERS = (
     "unavailable",
     "deadline_exceeded",
@@ -75,8 +62,6 @@ _TRANSIENT_MARKERS = (
 
 def is_transient_backend_error(exc: BaseException) -> bool:
     """True if ``exc`` looks like a transient backend/transport fault."""
-    if isinstance(exc, BackendDialTimeout):
-        return False  # a hang, not a blip: fail fast
     if isinstance(exc, RetryableError):
         return True
     if isinstance(exc, ConnectionError):
@@ -197,69 +182,3 @@ class RetryBudget:
     @property
     def remaining(self) -> int:
         return max(0, self.max_failures - self.spent)
-
-
-def acquire_backend(attempts: int = 6, wait_s: float = 75.0, *,
-                    dial_timeout_s: int = 180,
-                    attempts_log: Optional[List[dict]] = None,
-                    on_retry: Optional[Callable[[int, BaseException, float], None]] = None):
-    """Initialize the JAX backend, surviving transient dial failures.
-
-    Each attempt runs under a SIGALRM deadline of ``dial_timeout_s``
-    seconds: exceeding it raises :class:`BackendDialTimeout`, which is
-    *not* retried (a hung runtime stays hung — callers should emit a
-    structured failure and exit).  Any other dial error is retried up to
-    ``attempts`` times with constant ``wait_s`` backoff, clearing the
-    partially-initialized backend between attempts.
-
-    Returns ``jax.devices()`` on success.
-    """
-    import signal
-
-    import jax
-
-    def _dial():
-        def _on_alarm(signum, frame):
-            raise BackendDialTimeout(
-                f"jax backend initialization exceeded {dial_timeout_s}s")
-
-        prev = signal.signal(signal.SIGALRM, _on_alarm)
-        signal.alarm(dial_timeout_s)
-        try:
-            return jax.devices()
-        finally:
-            signal.alarm(0)
-            signal.signal(signal.SIGALRM, prev)
-
-    def _reset_and_notify(attempt: int, exc: BaseException, delay: float):
-        # Drop the poisoned registry state so the next jax.devices()
-        # re-dials the backend instead of returning the cached failure
-        # (private API; guarded so an API move degrades to plain retry).
-        # ONLY when no client was ever constructed: a cached *failed*
-        # initialization is the one state a clear helps with, and the one
-        # state it is safe in.  Tearing down a live client is a native
-        # use-after-free — buffers, compiled-executable caches and
-        # jax-internal globals keep raw references to it, and the freed
-        # heap chunks get rewritten by the next dial (observed as
-        # ``cpu_client.cc CHECK`` failures / malloc aborts in whatever
-        # large computation runs next).  If a client exists, the dial
-        # error was transient and plain retry suffices.
-        try:
-            from jax._src import xla_bridge
-            if not xla_bridge._backends:
-                xla_bridge._clear_backends()
-        except Exception:  # pragma: no cover - best effort
-            pass
-        if on_retry is not None:
-            on_retry(attempt, exc, delay)
-
-    policy = RetryPolicy(
-        max_attempts=attempts,
-        base_delay_s=wait_s,
-        max_delay_s=max(wait_s, 1e-9),
-        growth=1.0,  # constant: the TPU runtime needs a fixed settle time
-        jitter=0.0,
-        classify=lambda exc: not isinstance(exc, BackendDialTimeout),
-    )
-    return policy.call(_dial, describe="backend dial",
-                       attempts_log=attempts_log, on_retry=_reset_and_notify)

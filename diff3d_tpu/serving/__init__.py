@@ -42,8 +42,7 @@ from diff3d_tpu.serving.server import (ServingService, build_request,
 from diff3d_tpu.serving.transport import (FrameGarbage, FrameTooLarge,
                                           FrameTruncated, RemoteReplica,
                                           TransportError)
-from diff3d_tpu.serving.worker import (HbmAdmission, Worker, boot_worker,
-                                       configure_compile_cache)
+from diff3d_tpu.serving.worker import HbmAdmission, Worker, boot_worker
 
 __all__ = [
     "Bucket", "Engine", "EngineDraining", "EngineOverloaded",
@@ -57,6 +56,5 @@ __all__ = [
     "ServingService", "SessionLost", "TransportError",
     "TrajectoryRequest", "UnsupportedSchedule", "ViewRequest",
     "Worker", "boot_worker", "build_fleet", "build_request",
-    "build_trajectory_request", "configure_compile_cache",
-    "make_http_server",
+    "build_trajectory_request", "make_http_server",
 ]

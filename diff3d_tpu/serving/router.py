@@ -15,7 +15,7 @@ requests (DESIGN.md §14):
   replica and may fail over.
 * **Admission control & backpressure** — per-replica queue depth and
   health (``ok|degraded|draining|dead``) feed typed rejections
-  composing the RetryableError taxonomy:
+  composing the RetryableError hierarchy:
   :class:`~diff3d_tpu.serving.scheduler.FleetOverloaded` (capacity,
   retry same request), :class:`~diff3d_tpu.serving.scheduler.ReplicaDraining`
   (owner mid-rollout, retry same session) and

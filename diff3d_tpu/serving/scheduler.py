@@ -114,7 +114,7 @@ class UnsupportedSchedule(RetryableError):
         self.supported = list(supported or [])
 
 
-# Fleet-level typed rejections (serving/router.py).  Same taxonomy, one
+# Fleet-level typed rejections (serving/router.py).  Same hierarchy, one
 # level up: the *fleet*, not a single replica, could not place the
 # request right now.
 

@@ -24,8 +24,8 @@ typed error, never a hung socket: a declared length past the cap is
 a body that isn't a JSON object is :class:`FrameGarbage`, and every
 socket op runs under a timeout (:class:`TransportError` on expiry).
 
-**Error taxonomy over the wire**: the server encodes the typed
-retryable taxonomy (scheduler.py) by class name + payload fields;
+**Error hierarchy over the wire**: the server encodes the typed
+retryable hierarchy (scheduler.py) by class name + payload fields;
 :func:`decode_error` rehydrates the same class client-side, so
 ``RemoteReplica.submit`` raises exactly what ``Replica.submit`` would
 — the router's placement logic needs zero changes.
@@ -203,7 +203,7 @@ def recv_frame(sock: socket.socket,
 
 
 # ---------------------------------------------------------------------------
-# Error codec: typed taxonomy across the wire
+# Error codec: typed hierarchy across the wire
 # ---------------------------------------------------------------------------
 
 #: Classes that cross the wire by name.  Anything else degrades to a
@@ -594,7 +594,7 @@ class RemoteReplica:
 
     def submit(self, req: ViewRequest) -> ViewRequest:
         """Wire submit + poller registration.  Raises the same typed
-        taxonomy as the in-process submit (rehydrated from the wire);
+        hierarchy as the in-process submit (rehydrated from the wire);
         the returned request resolves asynchronously when the poller
         streams the worker's result back."""
         with self._lock:

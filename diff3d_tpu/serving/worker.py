@@ -27,16 +27,20 @@ program.  Budget, resident and headroom surface on the ``state`` RPC,
 ``health()`` and ``GET /stats`` so the router and operators see the
 same arithmetic that rejected the request.
 
-**Persistent compile cache.**  :func:`configure_compile_cache` points
-``jax_compilation_cache_dir`` at a shared directory before the first
+**Persistent compile cache.**  ``worker_cli`` places it through
+:func:`diff3d_tpu.runtime.configure_compile_cache` before the first
 trace, so replica scale-out and blue/green worker restarts reuse each
 other's XLA compilations instead of paying a cold compile per process.
 
 **Replica×mesh-slice placement.**  :func:`boot_worker` builds the
 replica's :class:`~diff3d_tpu.parallel.mesh.MeshEnv` over an explicit
-*device subset* (``jax.devices()[lo:hi]``), so N workers on one host
-pin to disjoint slices instead of sharing one default device set —
-the CPU tests split the 8-virtual-device mesh 2×4.
+*device subset* (``jax.devices()[lo:hi]``).  The CPU tests split the
+8-virtual-device mesh 2×4 between two worker processes; that layout is
+for the virtual CPU mesh only.  A TPU belongs to one process at a time:
+on a real host the second worker process cannot open the device, so ONE
+process drives all local chips and in-process
+:class:`~diff3d_tpu.serving.fleet.Replica` objects are the supported
+single-host layout.
 """
 
 from __future__ import annotations
@@ -82,22 +86,6 @@ def program_for_schedule(sampler_kind: Optional[str],
     if sampler_kind in (None, "ancestral"):
         return "step_many"
     return f"step_many_{sampler_kind}"
-
-
-def configure_compile_cache(cache_dir: str) -> str:
-    """Point jax's persistent compilation cache at ``cache_dir`` (must
-    run before the first trace).  Every worker sharing the directory
-    reuses each other's XLA compilations — replica scale-out and
-    blue/green restarts skip the cold compile."""
-    import jax
-
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    # Serving programs are exactly the long-compile artifacts the cache
-    # exists for; cache everything, however small.
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    return cache_dir
 
 
 class HbmAdmission:
@@ -615,13 +603,10 @@ def boot_worker(cfg: Config, *, name: str, devices: List[int],
                 host: str = "127.0.0.1", port: int = 0,
                 hbm_budget_bytes: int = 0,
                 memcheck_dir: str = membudgets.DEFAULT_MANIFEST_DIR,
-                compile_cache: Optional[str] = None,
                 scan_chunks: int = 1) -> Worker:
     """Build a worker: mesh over the device slice, model + samplers,
     replica, admission gate, socket server.  ``params=None`` draws
     random init params (the test/dev path)."""
-    if compile_cache:
-        configure_compile_cache(compile_cache)
     import jax
 
     from diff3d_tpu.models import XUNet

@@ -1,0 +1,182 @@
+"""The chip's compiler, asked here (no chip attached) for every Pallas
+kernel site of srn64 and srn128, forward and backward, ``interpret=False``.
+
+Interpret-mode tests prove the tile programs compute the right numbers;
+they cannot see what Mosaic refuses (unaligned slices, too much VMEM, a
+dtype it will not load).  These compiles can, at about two seconds a case
+and no chip time.  A compile that passes is not a chip run.
+
+Sites are the distinct ``(L, C, dtype, film, silu)`` GroupNorm operands
+and ``(L, heads, D, dtype)`` attention operands that one forward of
+``XUNet(srn64_config())`` / ``XUNet(srn128_config())`` hands the
+dispatcher with ``kernels='pallas'`` / ``attn_impl='pallas'`` (enumerated
+by tracing the model with recording stand-ins, PR 21).  The residual
+stream is float32 (``/ np.sqrt(2.0)`` promotes it), so most GroupNorm
+inputs are float32 while FiLM sites see the bf16 conv output.  N (frames
+x batch) only scales the kernels' outer grid; the tests use N = 2.
+
+Fixture rules (on-chip-measurement guide, section 2): the topology is
+described inside a module-scoped, non-autouse fixture of THIS file, which
+skips where it cannot be described — never at import, in ``skipif``, in
+``parametrize`` arguments or in ``conftest.py``; compiles run in the
+test's own process (the worker that loads libtpu keeps its lock); the
+persistent cache is off around them (a TPU executable written from a CPU
+process cannot be read back).
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from diff3d_tpu.ops.pallas_attention import flash_attention
+from diff3d_tpu.ops.pallas_film import fused_groupnorm
+
+F32, BF16 = "float32", "bfloat16"
+
+# (L, C, dtype, film, silu); 32 groups everywhere.
+GN_SITES = {
+    "srn64": [
+        (4096, 128, BF16, False, True), (4096, 128, BF16, True, False),
+        (4096, 128, F32, False, True), (4096, 256, F32, False, True),
+        (4096, 384, F32, False, True),
+        (1024, 128, F32, False, True), (1024, 256, BF16, True, False),
+        (1024, 256, F32, False, True), (1024, 384, F32, False, True),
+        (1024, 512, F32, False, True),
+        (256, 256, BF16, True, False), (256, 256, F32, False, False),
+        (256, 256, F32, False, True), (256, 512, F32, False, True),
+        (256, 768, F32, False, True),
+        (64, 256, F32, False, True), (64, 512, BF16, True, False),
+        (64, 512, F32, False, False), (64, 512, F32, False, True),
+        (64, 768, F32, False, True), (64, 1024, F32, False, True),
+    ],
+    "srn128": [
+        (16384, 256, BF16, False, True), (16384, 256, BF16, True, False),
+        (16384, 256, F32, False, True), (16384, 512, F32, False, True),
+        (16384, 768, F32, False, True),
+        (4096, 256, F32, False, True), (4096, 512, BF16, True, False),
+        (4096, 512, F32, False, True), (4096, 768, F32, False, True),
+        (4096, 1024, F32, False, True),
+        (1024, 512, BF16, True, False), (1024, 512, F32, False, False),
+        (1024, 512, F32, False, True), (1024, 1024, F32, False, True),
+        (1024, 1536, F32, False, True),
+        (256, 512, F32, False, True), (256, 1024, BF16, True, False),
+        (256, 1024, F32, False, False), (256, 1024, F32, False, True),
+        (256, 1536, F32, False, True), (256, 2048, F32, False, True),
+    ],
+}
+# (L, heads, D, dtype) — self- and cross-attention share Lq == Lk.
+ATTN_SITES = {
+    "srn64": [(256, 4, 64, BF16), (64, 4, 128, BF16)],
+    "srn128": [(1024, 4, 128, BF16), (256, 4, 256, BF16)],
+}
+N = 2
+GROUPS = 32
+
+
+def _cases(table):
+    return [pytest.param(*site, id=f"{cfg}-" + "-".join(map(str, site)))
+            for cfg, sites in table.items() for site in sites]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A TPU executable compiled from this CPU process is written to the
+    persistent cache but cannot be read back without a chip: the next
+    compile would warn and compile again.  Off around these tests."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _compile_for_chip(fn, *args):
+    """Lower + compile ``fn`` for the described chip; the kernel must be
+    in the program as a Mosaic custom call, not as interpreter ops."""
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _gn_operands(one_chip, L, C, dtype, film):
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=one_chip)
+
+    x = sds((N, L, C), dtype)
+    affine = (sds((C,), F32), sds((C,), F32))
+    mod = (sds((N, L, C), dtype), sds((N, L, C), dtype)) if film else ()
+    return (x, *affine, *mod)
+
+
+def _gn(silu):
+    def fn(x, gamma, beta, *mod):
+        kw = dict(scale=mod[0], shift=mod[1]) if mod else {}
+        return fused_groupnorm(x, gamma, beta, num_groups=GROUPS,
+                               silu=silu, interpret=False, **kw)
+    return fn
+
+
+@pytest.mark.parametrize("L,C,dtype,film,silu", _cases(GN_SITES))
+def test_fused_groupnorm_forward_compiles_for_v5e(
+        one_chip, no_persistent_cache, L, C, dtype, film, silu):
+    _compile_for_chip(_gn(silu), *_gn_operands(one_chip, L, C, dtype, film))
+
+
+@pytest.mark.parametrize("L,C,dtype,film,silu", _cases(GN_SITES))
+def test_fused_groupnorm_backward_compiles_for_v5e(
+        one_chip, no_persistent_cache, L, C, dtype, film, silu):
+    """The ``custom_vjp`` pair the train step runs: the stats-saving
+    forward and the fused dx/dgamma/dbeta(/dscale/dshift) backward."""
+    args = _gn_operands(one_chip, L, C, dtype, film)
+    fn = _gn(silu)
+
+    def loss(*a):
+        return jnp.sum(fn(*a).astype(jnp.float32))
+
+    _compile_for_chip(jax.grad(loss, argnums=tuple(range(len(args)))),
+                      *args)
+
+
+def _qkv(one_chip, L, heads, D, dtype):
+    s = jax.ShapeDtypeStruct((N, L, heads, D), jnp.dtype(dtype),
+                             sharding=one_chip)
+    return s, s, s
+
+
+def _flash(q, k, v):
+    return flash_attention(q, k, v, interpret=False)
+
+
+@pytest.mark.parametrize("L,heads,D,dtype", _cases(ATTN_SITES))
+def test_flash_attention_forward_compiles_for_v5e(
+        one_chip, no_persistent_cache, L, heads, D, dtype):
+    _compile_for_chip(_flash, *_qkv(one_chip, L, heads, D, dtype))
+
+
+@pytest.mark.parametrize("L,heads,D,dtype", _cases(ATTN_SITES))
+def test_flash_attention_backward_compiles_for_v5e(
+        one_chip, no_persistent_cache, L, heads, D, dtype):
+    def loss(q, k, v):
+        return jnp.sum(_flash(q, k, v).astype(jnp.float32))
+
+    _compile_for_chip(jax.grad(loss, argnums=(0, 1, 2)),
+                      *_qkv(one_chip, L, heads, D, dtype))

@@ -119,12 +119,7 @@ def test_pinhole_rays_match_visu3d_oracle(position):
     'hard part #1'): a convention slip (pixel corner vs center, K^T,
     row-vs-column camera axes, unnormalized dirs) shifts every ray and
     fails here, independently of diff3d_tpu's own derivation."""
-    import jax
-
-    # jax < 0.5 only ships the scoped x64 switch under jax.experimental.
-    enable_x64 = getattr(jax, "enable_x64", None)
-    if enable_x64 is None:
-        from jax.experimental import enable_x64
+    from jax import enable_x64
 
     R, t = _srn_lookat_pose(position)
     oracle_pos, oracle_dir = _visu3d_rays_oracle(R, t, _SRN_K, 128, 128)

@@ -35,11 +35,11 @@ def _run_workers(port: int, tmp_path) -> tuple[list, list]:
         os.environ,
         XLA_FLAGS="--xla_force_host_platform_device_count=2",
         JAX_PLATFORMS="cpu",
-        # Fresh per-run cache: if one worker AOT-loads a cached executable
-        # while the other compiles, they create different gloo-context
-        # sequences and the collective rendezvous times out.  An empty
-        # shared dir keeps both workers symmetric (both compile).
-        JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jax_cache"),
+        # No persistent cache in the workers: if one AOT-loads a cached
+        # executable while the other compiles, they create different
+        # gloo-context sequences and the collective rendezvous times
+        # out.  With the cache off both workers compile, symmetrically.
+        JAX_ENABLE_COMPILATION_CACHE="false",
     )
     # Workers write straight to files: PIPE capture with sequential
     # communicate() can deadlock (a worker blocking on a full unread pipe

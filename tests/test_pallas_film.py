@@ -184,9 +184,14 @@ def test_resolve_rules(monkeypatch):
     # explicit pallas: honoured when supported...
     assert dispatch.resolve("groupnorm", "pallas", x,
                             num_groups=32).name == "pallas"
-    # ...and falls back to xla (never an error) when not.
+    # ...and raises, naming op and shape, when not: an explicit request
+    # is never swapped for xla behind the caller's back.
     bad = jax.ShapeDtypeStruct((2, 256, 128), jnp.float16)
-    assert dispatch.resolve("groupnorm", "pallas", bad,
+    with pytest.raises(ValueError, match=r"groupnorm.*float16\[2, 256, 128\]"):
+        dispatch.resolve("groupnorm", "pallas", bad, num_groups=32)
+    # 'auto' may choose: the same operands resolve to xla, even on TPU.
+    monkeypatch.setattr(dispatch, "default_backend", lambda: "tpu")
+    assert dispatch.resolve("groupnorm", "auto", bad,
                             num_groups=32).name == "xla"
     assert dispatch.resolve("groupnorm", "xla", x,
                             num_groups=32).name == "xla"

@@ -24,7 +24,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
-from diff3d_tpu.parallel import shard_map  # noqa: F401  (version-compat wrapper)
+from jax import shard_map
 from diff3d_tpu.config import MeshConfig
 from diff3d_tpu.config import test_config as make_tiny_config
 from diff3d_tpu.data import InfiniteLoader, SyntheticDataset

@@ -5,14 +5,13 @@ These are the contracts every fault-tolerant layer leans on: the trainer
 and serving engine wrap dispatches in :class:`RetryPolicy`, the async
 checkpoint writer retries commits under it, and the chaos tests drive
 all of them through :class:`FaultInjector`.  A behavioral drift here
-(e.g. retrying a BackendDialTimeout, or a nondeterministic backoff
+(e.g. retrying a config error, or a nondeterministic backoff
 sequence) silently changes every one of those layers at once.
 """
 
 import pytest
 
-from diff3d_tpu.runtime.retry import (BackendDialTimeout, RetryPolicy,
-                                      RetryableError,
+from diff3d_tpu.runtime.retry import (RetryPolicy, RetryableError,
                                       is_transient_backend_error,
                                       is_transient_io_error)
 from diff3d_tpu.testing.faults import (FaultInjected, FaultInjector,
@@ -35,7 +34,6 @@ def _policy(**kw):
     (ConnectionResetError("connection reset by peer"), True),
     (RetryableError("typed transient"), True),
     (FaultInjected("injected"), True),           # injected == real transient
-    (BackendDialTimeout("dial exceeded 180s"), False),  # a hang, not a blip
     (ValueError("bad shape"), False),
     (RuntimeError("XlaRuntimeError: INVALID_ARGUMENT"), False),
 ])

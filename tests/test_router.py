@@ -7,7 +7,7 @@ Two layers, mirroring the router's own split:
   :class:`~diff3d_tpu.serving.fleet.Replica` surface and compiles
   nothing, so the placement/affinity/backpressure logic is testable
   with zero device work): rendezvous stability under churn, sticky vs
-  sessionless failover, claim release, the typed rejection taxonomy,
+  sessionless failover, claim release, the typed rejection hierarchy,
   and the blue/green rollout state machine.
 * **Fleet integration tests** run real 3-replica fleets on the tiny
   shallow config — bit-parity through the router, schedule-aware

@@ -83,8 +83,8 @@ def test_sampler_autoregressive_record_grows(setup):
 def test_sampler_chunked_scan_matches_single(setup):
     """scan_chunks splits the reverse diffusion into several device
     executions; the carried rng makes the result BIT-identical to the
-    one-scan path (the property that lets tunnel-deadline-bound setups
-    chunk the full-width 128^2 sampler without changing the protocol)."""
+    one-scan path (the property that lets a caller chunk the full-width
+    128^2 sampler without changing the protocol)."""
     cfg, model, params, ds = setup
     views = ds.all_views(0)
     one = Sampler(model, params, cfg).synthesize(

@@ -124,7 +124,7 @@ _SHLO = textwrap.dedent("""\
     module @fixture {
       func.func public @main(%arg0: tensor<16x8xbf16>) -> tensor<16x8xf32> {
         %0 = stablehlo.convert %arg0 : (tensor<16x8xbf16>) -> tensor<16x8xf32>
-        %1 = stablehlo.custom_call @Sharding(%0) {mhlo.sharding = "{devices=[8,1]<=[8]}"} : (tensor<16x8xf32>) -> tensor<16x8xf32>
+        %1 = sdy.sharding_constraint %0 <@mesh, [{"data"}, {}]> : tensor<16x8xf32>
         %2 = stablehlo.convert %1 : (tensor<16x8xf32>) -> tensor<16x8xbf16>
         %3 = stablehlo.custom_call @xla_python_cpu_callback(%2) {api_version = 2 : i32} : (tensor<16x8xbf16>) -> tensor<16x8xf32>
         return %3 : tensor<16x8xf32>
@@ -137,7 +137,7 @@ def test_parse_stablehlo_extracts_all_three_facts():
     facts = ir.parse_stablehlo(_SHLO)
     assert facts["dtype_upcasts"] == {"bf16->f32": 1}
     (site,) = facts["resharding_sites"]
-    assert "devices=[8,1]" in site.sharding
+    assert site.sharding == '<@mesh, [{"data"}, {}]>'
     assert facts["host_callbacks"] == ["xla_python_cpu_callback"]
 
 

@@ -5,7 +5,7 @@ Four layers, cheapest first:
 * **Framing + codec unit tests** — length-prefixed JSON frames over a
   socketpair: bit-exact ndarray round-trips, and every malformed input
   (oversized declared length, EOF mid-frame, non-JSON body) is a typed
-  error, never a hung socket.  The typed retryable taxonomy crosses the
+  error, never a hung socket.  The typed retryable hierarchy crosses the
   wire by class name and comes back as the same class with the same
   payload fields.
 * **RemoteReplica over an in-process Worker wrapping test_router.py's
@@ -207,7 +207,7 @@ def test_all_frame_faults_are_retryable():
 
 
 # ---------------------------------------------------------------------------
-# Error codec: the typed taxonomy crosses the wire intact
+# Error codec: the typed hierarchy crosses the wire intact
 # ---------------------------------------------------------------------------
 
 

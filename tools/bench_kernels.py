@@ -38,9 +38,10 @@ _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if _REPO_ROOT not in sys.path:
     sys.path.insert(0, _REPO_ROOT)
 
-# v5e datasheet HBM bandwidth; quoted (not measured) — the denominator
-# for pct_of_hbm_peak on TPU.  Non-TPU platforms get null.
-TPU_V5E_HBM_GBPS = 819.0
+# Datasheet HBM bandwidth (GB/s) by ``device_kind``; quoted (not
+# measured) — the denominator for pct_of_hbm_peak on TPU.  A TPU that is
+# not in the table is an error, not a v5e.  Non-TPU platforms get null.
+HBM_GBPS_BY_KIND = {"TPU v5 lite": 819.0}   # Google Cloud docs, "TPU v5e"
 
 ROOFLINE_PATH = "runs/roofline_r4.json"
 
@@ -177,6 +178,10 @@ def main(argv=None) -> int:
 
     import jax
 
+    from diff3d_tpu.runtime import configure_compile_cache
+
+    configure_compile_cache()
+
     dev = jax.devices()[0]
     interpret = args.interpret or dev.platform != "tpu"
     # Interpret mode at the real 4096/16384-token sites is minutes per
@@ -202,7 +207,14 @@ def main(argv=None) -> int:
                       f"pallas {pt['pallas_ms']}ms xla {pt['xla_ms']}ms "
                       f"({pt['speedup_vs_xla']}x)", file=sys.stderr)
 
-    hbm = TPU_V5E_HBM_GBPS if dev.platform == "tpu" else None
+    hbm = None
+    if dev.platform == "tpu":
+        if dev.device_kind not in HBM_GBPS_BY_KIND:
+            raise SystemExit(
+                f"bench_kernels: no HBM peak on file for device_kind "
+                f"{dev.device_kind!r}; add it to HBM_GBPS_BY_KIND with "
+                "its source")
+        hbm = HBM_GBPS_BY_KIND[dev.device_kind]
     for pt in points:
         pt["pct_of_hbm_peak"] = (round(100 * pt["achieved_gbps"] / hbm, 1)
                                  if hbm else None)

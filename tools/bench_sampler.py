@@ -22,10 +22,9 @@ def main() -> None:
 
     import jax
 
-    try:  # persistent compile cache across runs
-        jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
-    except Exception:  # pragma: no cover
-        pass
+    from diff3d_tpu.runtime import configure_compile_cache
+
+    configure_compile_cache()
     import numpy as np
 
     from diff3d_tpu.config import srn64_config

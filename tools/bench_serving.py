@@ -579,6 +579,10 @@ def main(argv=None) -> int:
     p.add_argument("--out", default="runs/bench_serving.json")
     args = p.parse_args(argv)
 
+    from diff3d_tpu.runtime import configure_compile_cache
+
+    configure_compile_cache()
+
     traj_lens = [int(v) for v in args.trajectory_lens.split(",")
                  if v.strip()]
     if traj_lens:

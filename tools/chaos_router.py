@@ -127,6 +127,9 @@ def _build_remote_fleet(args, cfg):
             f"(one device each on the {host_devices}-virtual-device "
             "CPU backend)")
     per = host_devices // n
+    # The children are pinned to the CPU on purpose, whatever this
+    # process runs on: a TPU belongs to one process at a time, so worker
+    # processes with device slices exist on the virtual CPU mesh only.
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)   # workers pick their own device count
     procs = {}

@@ -69,6 +69,10 @@ def run_variant(global_batch: int, accum: int, remat: bool, policy: str,
 
 def main() -> None:
     global CONFIG
+    from diff3d_tpu.runtime import configure_compile_cache
+
+    configure_compile_cache()
+
     if len(sys.argv) > 2 and sys.argv[1] == "--config":
         CONFIG = sys.argv[2]
         del sys.argv[1:3]
