@@ -63,46 +63,6 @@ GN_ATOL = 2.0 ** -3
 ATTN_ATOL = 2.0 ** -5
 
 
-class CompileClock:
-    """Sums JAX's own compile-pipeline durations (trace, lower, backend
-    compile incl. persistent-cache reads) so a phase's wall time splits
-    into compile and run without guessing."""
-
-    _EVENTS = {
-        "/jax/core/compile/jaxpr_trace_duration": "trace_s",
-        "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
-        "/jax/core/compile/backend_compile_duration": "backend_compile_s",
-    }
-
-    def __init__(self):
-        import jax
-
-        self._lock = threading.Lock()
-        self.totals = {"trace_s": 0.0, "lower_s": 0.0,
-                       "backend_compile_s": 0.0, "backend_compiles": 0,
-                       "cache_hits": 0}
-        jax.monitoring.register_event_duration_secs_listener(self._dur)
-        jax.monitoring.register_event_listener(self._evt)
-
-    def _dur(self, event, secs, **_):
-        key = self._EVENTS.get(event)
-        if key is None:
-            return
-        with self._lock:
-            self.totals[key] += secs
-            if key == "backend_compile_s":
-                self.totals["backend_compiles"] += 1
-
-    def _evt(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            with self._lock:
-                self.totals["cache_hits"] += 1
-
-    def snapshot(self) -> dict:
-        with self._lock:
-            return dict(self.totals)
-
-
 def emit(obj: dict) -> None:
     line = json.dumps(obj)
     print(line, flush=True)
@@ -120,7 +80,7 @@ def check(cond, msg: str) -> None:
 
 
 @contextlib.contextmanager
-def phase(name: str, clock: CompileClock):
+def phase(name: str, clock):
     """Time one phase, print its JSON line, and end the run on failure."""
     details: dict = {}
     before, t0 = clock.snapshot(), time.perf_counter()
@@ -597,7 +557,9 @@ def main(argv=None) -> int:
               f"{len(devices)} devices", file=sys.stderr)
         return 1
     os.makedirs(os.path.dirname(LOG_PATH), exist_ok=True)
-    clock = CompileClock()
+    # the program's one compile clock (registered when the package's
+    # profiling module is imported, which is before anything compiles)
+    from diff3d_tpu.utils.profiling import COMPILE_CLOCK as clock
     emit({"phase": "start", "device": dev, "compile_cache": cache_dir,
           "jax": jax.__version__})
 

@@ -23,6 +23,19 @@ from diff3d_tpu.cli._common import (add_model_width_args,
                                     apply_model_width_overrides)
 
 
+def _step_range(text: str) -> tuple:
+    """``"START:STOP"`` -> ``(start, stop)``, ``1 <= start < stop``."""
+    try:
+        start, stop = (int(x) for x in text.split(":"))
+    except ValueError:
+        raise argparse.ArgumentTypeError(
+            f"{text!r} is not START:STOP (two integers)")
+    if not 1 <= start < stop:
+        raise argparse.ArgumentTypeError(
+            f"need 1 <= START < STOP, got {start}:{stop}")
+    return start, stop
+
+
 def build_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--transfer", action="store_true",
@@ -49,6 +62,13 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--accum", type=int, default=None,
                    help="gradient-accumulation microbatches per step")
     p.add_argument("--workdir", default=".")
+    p.add_argument("--profile_steps", type=_step_range, default=None,
+                   metavar="START:STOP",
+                   help="capture a jax.profiler trace of optimizer steps "
+                        "START+1..STOP into <workdir>/profile and reduce "
+                        "it to profile/by_scope.json (device seconds by "
+                        "block class, idle by host span); START >= 1 so "
+                        "that the compile is not traced")
     p.add_argument("--param_sharding",
                    choices=["replicated", "fsdp", "tp", "fsdp+tp"],
                    default=None)
@@ -358,7 +378,7 @@ def main(argv=None) -> None:
             val_ds, per_host, seed=cfg.train.seed + 1,
             host_id=jax.process_index(), num_hosts=jax.process_count(),
             num_workers=0, sample_mode="permute")
-    trainer.train()
+    trainer.train(profile_steps=args.profile_steps)
 
 
 if __name__ == "__main__":

@@ -30,6 +30,7 @@ from typing import Dict, Iterator, Optional
 import numpy as np
 
 from diff3d_tpu.data.images import quantize_uint8
+from diff3d_tpu.utils.profiling import count, span
 
 
 def _collate(samples) -> Dict[str, np.ndarray]:
@@ -150,7 +151,8 @@ class InfiniteLoader:
         return self
 
     def __next__(self) -> Dict[str, np.ndarray]:
-        batch = self._batch(self._step)
+        with span("loader.batch", id=self._step):
+            batch = self._batch(self._step)
         self._step += 1
         return batch
 
@@ -160,7 +162,12 @@ def prefetch_to_device(it: Iterator, sharding=None, depth: int = 2,
     """Runs ``it`` in a background thread, keeping ``depth`` batches ahead;
     each batch is ``jax.device_put`` with ``sharding`` (a NamedSharding with
     the batch axis on the mesh's data axis) so the global array lands
-    already sharded."""
+    already sharded.
+
+    Spans (``utils/profiling``), each with the batch's count ``k`` as id:
+    ``prefetch.put`` around the producer's upload of batch ``k``,
+    ``prefetch.wait`` around the consumer's ``get`` of it; the counter
+    ``prefetch.starved`` counts the gets that found the queue empty."""
     import jax
 
     q: "queue.Queue" = queue.Queue(maxsize=depth)
@@ -172,14 +179,15 @@ def prefetch_to_device(it: Iterator, sharding=None, depth: int = 2,
         try:
             from diff3d_tpu.parallel.multihost import shard_host_local
 
-            for batch in it:
+            for k, batch in enumerate(it):
                 if stop.is_set():
                     return
                 if to_device:
                     # Multi-host: each host's local slice becomes its
                     # shards of ONE global array (make_array_from_
                     # process_local_data); single-host: plain device_put.
-                    batch = shard_host_local(batch, sharding)
+                    with span("prefetch.put", id=k):
+                        batch = shard_host_local(batch, sharding)
                 q.put(batch)
         except BaseException as e:  # surface on the consumer side
             error.append(e)
@@ -190,11 +198,17 @@ def prefetch_to_device(it: Iterator, sharding=None, depth: int = 2,
     t.start()
 
     class _Prefetcher:
+        taken = 0               # batches handed out: the consumer's only
+
         def __iter__(self):
             return self
 
         def __next__(self):
-            item = q.get()
+            if q.empty():
+                count("prefetch.starved")
+            with span("prefetch.wait", id=self.taken):
+                item = q.get()
+            self.taken += 1
             if item is _SENTINEL:
                 if error:
                     raise error[0]

@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from diff3d_tpu.utils.profiling import scope
+
 # A denoiser: (batch dict, cond_mask [B] bool) -> eps_hat [B, H, W, 3].
 # Dropout/other rngs are expected to be bound by the caller (closure over
 # model.apply with its `rngs=`).
@@ -88,30 +90,32 @@ def p_losses(denoise_fn: DenoiseFn, imgs: jnp.ndarray, R: jnp.ndarray,
     False (the "max noise level" CFG variant, ``lightning/diff3d.py:13-16``).
     """
     B = imgs.shape[0]
-    x, z = imgs[:, 0], imgs[:, 1]
+    with scope("loss"):
+        x, z = imgs[:, 0], imgs[:, 1]
 
-    k_t, k_noise, k_mask, k_xnoise = jax.random.split(rng, 4)
-    logsnr = logsnr_schedule_cosine(
-        jax.random.uniform(k_t, (B,)), logsnr_min=logsnr_min,
-        logsnr_max=logsnr_max)
-    noise = jax.random.normal(k_noise, z.shape, z.dtype)
-    z_noisy = q_sample(z, logsnr, noise)
+        k_t, k_noise, k_mask, k_xnoise = jax.random.split(rng, 4)
+        logsnr = logsnr_schedule_cosine(
+            jax.random.uniform(k_t, (B,)), logsnr_min=logsnr_min,
+            logsnr_max=logsnr_max)
+        noise = jax.random.normal(k_noise, z.shape, z.dtype)
+        z_noisy = q_sample(z, logsnr, noise)
 
-    cond_mask = jax.random.uniform(k_mask, (B,)) > cond_prob
-    x_cond = jnp.where(cond_mask[:, None, None, None], x,
-                       jax.random.normal(k_xnoise, x.shape, x.dtype))
-    batch = make_model_batch(x_cond, z_noisy, logsnr, R, T, K,
-                             logsnr_max=logsnr_max)
+        cond_mask = jax.random.uniform(k_mask, (B,)) > cond_prob
+        x_cond = jnp.where(cond_mask[:, None, None, None], x,
+                           jax.random.normal(k_xnoise, x.shape, x.dtype))
+        batch = make_model_batch(x_cond, z_noisy, logsnr, R, T, K,
+                                 logsnr_max=logsnr_max)
     eps_hat = denoise_fn(batch, cond_mask)
 
-    if loss_type == "l1":
-        return jnp.mean(jnp.abs(noise - eps_hat))
-    if loss_type == "l2":
-        return jnp.mean(jnp.square(noise - eps_hat))
-    if loss_type == "huber":
-        # torch smooth_l1 with beta=1 (reference train.py:109).
-        d = jnp.abs(noise - eps_hat)
-        return jnp.mean(jnp.where(d < 1.0, 0.5 * d * d, d - 0.5))
+    with scope("loss"):
+        if loss_type == "l1":
+            return jnp.mean(jnp.abs(noise - eps_hat))
+        if loss_type == "l2":
+            return jnp.mean(jnp.square(noise - eps_hat))
+        if loss_type == "huber":
+            # torch smooth_l1 with beta=1 (reference train.py:109).
+            d = jnp.abs(noise - eps_hat)
+            return jnp.mean(jnp.where(d < 1.0, 0.5 * d * d, d - 0.5))
     raise NotImplementedError(loss_type)
 
 
@@ -321,11 +325,14 @@ def sample_view(denoise_fn: DenoiseFn, *, record_imgs: jnp.ndarray,
     ``[B, H, W, 3]`` — a pure carry update; the host feeds the returned
     buffers straight into the next call.
     """
-    rng, k = jax.random.split(rng)
+    with scope("sampler"):
+        rng, k = jax.random.split(rng)
+    with scope("record"):
+        target_R, target_T = record_R[record_len], record_T[record_len]
     out = sample_loop(
         denoise_fn, record_imgs=record_imgs, record_R=record_R,
         record_T=record_T, record_len=record_len,
-        target_R=record_R[record_len], target_T=record_T[record_len],
+        target_R=target_R, target_T=target_T,
         K=K, w=w, rng=k, timesteps=timesteps, logsnr_min=logsnr_min,
         logsnr_max=logsnr_max, clip_x0=clip_x0, steps=steps,
         sampler_kind=sampler_kind, start_t=start_t, draft=draft)
@@ -340,10 +347,11 @@ def sample_view_commit(record_imgs: jnp.ndarray, record_len: jnp.ndarray,
     device-resident tail of :func:`sample_view`, split out so chunked
     callers can commit after their last :func:`sample_loop_scan` chunk).
     Returns ``(img, record_imgs, record_len + 1)``."""
-    start = (record_len,) + (0,) * (record_imgs.ndim - 1)
-    record_imgs = jax.lax.dynamic_update_slice(
-        record_imgs, img[None].astype(record_imgs.dtype), start)
-    return img, record_imgs, record_len + 1
+    with scope("record"):
+        start = (record_len,) + (0,) * (record_imgs.ndim - 1)
+        record_imgs = jax.lax.dynamic_update_slice(
+            record_imgs, img[None].astype(record_imgs.dtype), start)
+        return img, record_imgs, record_len + 1
 
 
 def sample_loop_prepare(*, record_len: jnp.ndarray, rng: jax.Array,
@@ -377,27 +385,28 @@ def sample_loop_prepare(*, record_len: jnp.ndarray, rng: jax.Array,
     ignored and the init is the untruncated path's noise bit-for-bit: a
     stride-1-from-t=max cascade run equals the ancestral dense oracle.
     """
-    ts = sample_schedule_ts(steps, timesteps=timesteps, start_t=start_t)
-    n_steps = ts.shape[0] - 1
-    logsnrs = logsnr_schedule_cosine(ts[:-1], logsnr_min=logsnr_min,
-                                     logsnr_max=logsnr_max)
-    logsnr_nexts = logsnr_schedule_cosine(ts[1:], logsnr_min=logsnr_min,
-                                          logsnr_max=logsnr_max)
-    rng, k_init, k_idx = jax.random.split(rng, 3)
-    noise = jax.random.normal(k_init, shape)
-    if draft is None or start_t is None or float(start_t) >= 1.0:
-        init_img = noise
-    else:
-        logsnr_start = logsnr_schedule_cosine(
-            jnp.asarray(start_t), logsnr_min=logsnr_min,
-            logsnr_max=logsnr_max)
-        init_img = q_sample(draft.astype(noise.dtype),
-                            jnp.full((shape[0],), logsnr_start), noise)
-    # Pre-sampled stochastic-conditioning indices (reference
-    # `random.choice(record)`, sampling.py:138) — computed up front so the
-    # scan body is trace-static.
-    cond_idx = jax.random.randint(k_idx, (n_steps,), 0, record_len)
-    return SampleState(init_img, rng), (logsnrs, logsnr_nexts, cond_idx)
+    with scope("sampler"):
+        ts = sample_schedule_ts(steps, timesteps=timesteps, start_t=start_t)
+        n_steps = ts.shape[0] - 1
+        logsnrs = logsnr_schedule_cosine(ts[:-1], logsnr_min=logsnr_min,
+                                         logsnr_max=logsnr_max)
+        logsnr_nexts = logsnr_schedule_cosine(ts[1:], logsnr_min=logsnr_min,
+                                              logsnr_max=logsnr_max)
+        rng, k_init, k_idx = jax.random.split(rng, 3)
+        noise = jax.random.normal(k_init, shape)
+        if draft is None or start_t is None or float(start_t) >= 1.0:
+            init_img = noise
+        else:
+            logsnr_start = logsnr_schedule_cosine(
+                jnp.asarray(start_t), logsnr_min=logsnr_min,
+                logsnr_max=logsnr_max)
+            init_img = q_sample(draft.astype(noise.dtype),
+                                jnp.full((shape[0],), logsnr_start), noise)
+        # Pre-sampled stochastic-conditioning indices (reference
+        # `random.choice(record)`, sampling.py:138) — computed up front so
+        # the scan body is trace-static.
+        cond_idx = jax.random.randint(k_idx, (n_steps,), 0, record_len)
+        return SampleState(init_img, rng), (logsnrs, logsnr_nexts, cond_idx)
 
 
 def sample_loop_scan(denoise_fn: DenoiseFn, state: SampleState, xs, *,
@@ -426,61 +435,69 @@ def sample_loop_scan(denoise_fn: DenoiseFn, state: SampleState, xs, *,
     """
     B = w.shape[0]
 
-    Kb = jnp.broadcast_to(K[None], (B, 3, 3))
-    w_mask_2b = jnp.concatenate(
-        [jnp.ones((B,), bool), jnp.zeros((B,), bool)])
+    with scope("sampler"):
+        Kb = jnp.broadcast_to(K[None], (B, 3, 3))
+        w_mask_2b = jnp.concatenate(
+            [jnp.ones((B,), bool), jnp.zeros((B,), bool)])
 
     cam_dirs = None
     if hoist_cond:
         from diff3d_tpu.geometry import pinhole_rays_cam
 
         H, W = record_imgs.shape[-3:-1]
-        K2 = jnp.concatenate([Kb, Kb])                 # [2B, 3, 3]
-        cam_dirs = pinhole_rays_cam(
-            K2[:, None].astype(jnp.float32), H, W)     # [2B, 1, H, W, 3]
+        with scope("conditioning"):
+            K2 = jnp.concatenate([Kb, Kb])                 # [2B, 3, 3]
+            cam_dirs = pinhole_rays_cam(
+                K2[:, None].astype(jnp.float32), H, W)     # [2B, 1, H, W, 3]
 
     def step(state: SampleState, xs):
         logsnr, logsnr_next, idx, = xs
-        rng, k_x, k_noise = jax.random.split(state.rng, 3)
+        with scope("sampler"):
+            rng, k_x, k_noise = jax.random.split(state.rng, 3)
+        with scope("record"):
+            cond_img = record_imgs[idx]                     # [B, H, W, 3]
+            R = jnp.stack([record_R[idx], target_R])        # [2, 3, 3]
+            T = jnp.stack([record_T[idx], target_T])        # [2, 3]
+            Rb = jnp.broadcast_to(R[None], (B, 2, 3, 3))
+            Tb = jnp.broadcast_to(T[None], (B, 2, 3))
 
-        cond_img = record_imgs[idx]                     # [B, H, W, 3]
-        R = jnp.stack([record_R[idx], target_R])        # [2, 3, 3]
-        T = jnp.stack([record_T[idx], target_T])        # [2, 3]
-        Rb = jnp.broadcast_to(R[None], (B, 2, 3, 3))
-        Tb = jnp.broadcast_to(T[None], (B, 2, 3))
-
-        # Fold CFG cond + uncond passes into one 2B model call.
-        x_uncond = jax.random.normal(k_x, cond_img.shape, cond_img.dtype)
-        logsnr_b = jnp.full((2 * B,), logsnr)
-        batch = make_model_batch(
-            jnp.concatenate([cond_img, x_uncond]),
-            jnp.concatenate([state.img, state.img]),
-            logsnr_b,
-            jnp.concatenate([Rb, Rb]),
-            jnp.concatenate([Tb, Tb]),
-            jnp.concatenate([Kb, Kb]),
-            logsnr_max=logsnr_max)
-        if cam_dirs is not None:
-            batch = dict(batch, cam_dirs=cam_dirs)     # scan constant
+        with scope("sampler"):
+            # Fold CFG cond + uncond passes into one 2B model call.
+            x_uncond = jax.random.normal(k_x, cond_img.shape,
+                                         cond_img.dtype)
+            logsnr_b = jnp.full((2 * B,), logsnr)
+            batch = make_model_batch(
+                jnp.concatenate([cond_img, x_uncond]),
+                jnp.concatenate([state.img, state.img]),
+                logsnr_b,
+                jnp.concatenate([Rb, Rb]),
+                jnp.concatenate([Tb, Tb]),
+                jnp.concatenate([Kb, Kb]),
+                logsnr_max=logsnr_max)
+            if cam_dirs is not None:
+                batch = dict(batch, cam_dirs=cam_dirs)     # scan constant
         eps = denoise_fn(batch, w_mask_2b)
-        eps_cond, eps_uncond = eps[:B], eps[B:]
 
-        if deterministic:
-            img = ddim_step(
-                eps_cond, eps_uncond, state.img, logsnr, logsnr_next,
-                w.astype(state.img.dtype), clip_x0=clip_x0)
-        else:
-            mean, var = p_mean_variance(
-                eps_cond, eps_uncond, state.img, logsnr, logsnr_next,
-                w.astype(state.img.dtype), clip_x0=clip_x0)
-            noise = jax.random.normal(
-                k_noise, state.img.shape, state.img.dtype)
-            # Reference guard `if logsnr_next == 0: return mean`
-            # (train.py:125-126) — kept for parity even though the
-            # schedule's min logsnr is -20, so it never fires there.
-            img = jnp.where(logsnr_next == 0.0, mean,
-                            mean + jnp.sqrt(var) * noise)
+        with scope("sampler"):
+            eps_cond, eps_uncond = eps[:B], eps[B:]
+            if deterministic:
+                img = ddim_step(
+                    eps_cond, eps_uncond, state.img, logsnr, logsnr_next,
+                    w.astype(state.img.dtype), clip_x0=clip_x0)
+            else:
+                mean, var = p_mean_variance(
+                    eps_cond, eps_uncond, state.img, logsnr, logsnr_next,
+                    w.astype(state.img.dtype), clip_x0=clip_x0)
+                noise = jax.random.normal(
+                    k_noise, state.img.shape, state.img.dtype)
+                # Reference guard `if logsnr_next == 0: return mean`
+                # (train.py:125-126) — kept for parity even though the
+                # schedule's min logsnr is -20, so it never fires there.
+                img = jnp.where(logsnr_next == 0.0, mean,
+                                mean + jnp.sqrt(var) * noise)
         return SampleState(img, rng), None
 
-    state, _ = jax.lax.scan(step, state, xs)
+    # the loop itself (the `while` op and its counter) is the sampler's
+    with scope("sampler"):
+        state, _ = jax.lax.scan(step, state, xs)
     return state

@@ -18,6 +18,7 @@ import numpy as np
 from diff3d_tpu.geometry import (pinhole_rays_cam, pinhole_rays_world,
                                  posenc_ddpm, posenc_nerf)
 from diff3d_tpu.geometry.posenc import posenc_nerf_channels
+from diff3d_tpu.utils.profiling import scope
 
 # 93 (pos, degrees 0..15) + 51 (dir, degrees 0..8) = 144 channels,
 # reference xunet.py:317-320.
@@ -55,74 +56,77 @@ class ConditioningProcessor(nn.Module):
     @nn.compact
     def __call__(self, batch: dict, cond_mask: jnp.ndarray
                  ) -> Tuple[jnp.ndarray, List[jnp.ndarray]]:
-        B = batch["x"].shape[0]
-        H, W = self.H, self.W
-        D = POSE_EMB_CH
+        with scope("conditioning"):
+            B = batch["x"].shape[0]
+            H, W = self.H, self.W
+            D = POSE_EMB_CH
 
-        logsnr = jnp.clip(batch["logsnr"], -self.logsnr_clip,
-                          self.logsnr_clip)                      # [B, F]
-        # Encodings stay float32: their sinusoid arguments reach ~2e4
-        # (posenc_ddpm's x1000 scaling) and 2^14 (NeRF degree 15), far past
-        # bf16's mantissa — bf16 here destroys all phase information.  The
-        # Dense/Conv layers below cast to the compute dtype themselves.
-        logsnr_emb = posenc_ddpm(logsnr, emb_ch=self.emb_ch, max_time=1.0,
-                                 dtype=jnp.float32)              # [B, F, emb_ch]
-        logsnr_emb = nn.Dense(self.emb_ch, dtype=self.dtype)(logsnr_emb)
-        logsnr_emb = nn.Dense(self.emb_ch, dtype=self.dtype)(
-            nn.silu(logsnr_emb))
+            logsnr = jnp.clip(batch["logsnr"], -self.logsnr_clip,
+                              self.logsnr_clip)                      # [B, F]
+            # Encodings stay float32: their sinusoid arguments reach ~2e4
+            # (posenc_ddpm's x1000 scaling) and 2^14 (NeRF degree 15), far past
+            # bf16's mantissa — bf16 here destroys all phase information.
+            # The Dense/Conv layers below cast to the compute dtype
+            # themselves.
+            logsnr_emb = posenc_ddpm(logsnr, emb_ch=self.emb_ch, max_time=1.0,
+                                     dtype=jnp.float32)  # [B, F, emb_ch]
+            logsnr_emb = nn.Dense(self.emb_ch, dtype=self.dtype)(logsnr_emb)
+            logsnr_emb = nn.Dense(self.emb_ch, dtype=self.dtype)(
+                nn.silu(logsnr_emb))
 
-        # [B, F, H, W, 3] each; K broadcast over the frame axis
-        # (reference unsqueezes K at xunet.py:312).  The intrinsics-only
-        # half (K_inv @ pixel grid) may arrive precomputed as
-        # batch['cam_dirs'] — the sampler's scan hoists it once per
-        # trajectory (diffusion/core.py) instead of recomputing it every
-        # denoise step; both branches are bit-identical by construction
-        # (pinhole_rays is the composition of the two stages).
-        cam_dirs = batch.get("cam_dirs")
-        if cam_dirs is None:
-            cam_dirs = pinhole_rays_cam(
-                batch["K"][:, None].astype(jnp.float32), H, W)
-        pos, dirs = pinhole_rays_world(batch["R"].astype(jnp.float32),
-                                       batch["t"].astype(jnp.float32),
-                                       cam_dirs)
-        pose_emb = jnp.concatenate(
-            [posenc_nerf(pos, 0, POS_DEG), posenc_nerf(dirs, 0, DIR_DEG)],
-            axis=-1)                                             # [B, F, H, W, 144]
+            # [B, F, H, W, 3] each; K broadcast over the frame axis
+            # (reference unsqueezes K at xunet.py:312).  The intrinsics-only
+            # half (K_inv @ pixel grid) may arrive precomputed as
+            # batch['cam_dirs'] — the sampler's scan hoists it once per
+            # trajectory (diffusion/core.py) instead of recomputing it every
+            # denoise step; both branches are bit-identical by construction
+            # (pinhole_rays is the composition of the two stages).
+            cam_dirs = batch.get("cam_dirs")
+            if cam_dirs is None:
+                cam_dirs = pinhole_rays_cam(
+                    batch["K"][:, None].astype(jnp.float32), H, W)
+            pos, dirs = pinhole_rays_world(batch["R"].astype(jnp.float32),
+                                           batch["t"].astype(jnp.float32),
+                                           cam_dirs)
+            pose_emb = jnp.concatenate(
+                [posenc_nerf(pos, 0, POS_DEG), posenc_nerf(dirs, 0, DIR_DEG)],
+                axis=-1)                             # [B, F, H, W, 144]
 
-        pose_emb = jnp.where(cond_mask[:, None, None, None, None], pose_emb,
-                             jnp.zeros_like(pose_emb))
+            pose_emb = jnp.where(cond_mask[:, None, None, None, None],
+                                 pose_emb, jnp.zeros_like(pose_emb))
 
-        if self.use_pos_emb:
-            pos_emb = self.param(
-                "pos_emb", nn.initializers.normal(1.0 / np.sqrt(D)),
-                (H, W, D))
-            pose_emb = pose_emb + pos_emb[None, None]
-        if self.use_ref_pose_emb:
-            first_emb = self.param(
-                "first_emb", nn.initializers.normal(1.0 / np.sqrt(D)),
-                (1, 1, 1, 1, D))
-            other_emb = self.param(
-                "other_emb", nn.initializers.normal(1.0 / np.sqrt(D)),
-                (1, 1, 1, 1, D))
-            # frame 0 = reference view, frames 1.. = others
-            # (reference concat at xunet.py:336 assumes F=2).
-            F = pose_emb.shape[1]
-            ref_emb = jnp.concatenate(
-                [first_emb] + [other_emb] * (F - 1), axis=1)
-            pose_emb = pose_emb + ref_emb
+            if self.use_pos_emb:
+                pos_emb = self.param(
+                    "pos_emb", nn.initializers.normal(1.0 / np.sqrt(D)),
+                    (H, W, D))
+                pose_emb = pose_emb + pos_emb[None, None]
+            if self.use_ref_pose_emb:
+                first_emb = self.param(
+                    "first_emb", nn.initializers.normal(1.0 / np.sqrt(D)),
+                    (1, 1, 1, 1, D))
+                other_emb = self.param(
+                    "other_emb", nn.initializers.normal(1.0 / np.sqrt(D)),
+                    (1, 1, 1, 1, D))
+                # frame 0 = reference view, frames 1.. = others
+                # (reference concat at xunet.py:336 assumes F=2).
+                F = pose_emb.shape[1]
+                ref_emb = jnp.concatenate(
+                    [first_emb] + [other_emb] * (F - 1), axis=1)
+                pose_emb = pose_emb + ref_emb
 
-        Bf, F = pose_emb.shape[:2]
-        flat = pose_emb.reshape(Bf * F, H, W, D)
-        pose_embs = []
-        for i_level in range(self.num_resolutions):
-            s = 2 ** i_level
-            # Explicit (1, 1) padding = torch's padding=1 (reference
-            # xunet.py:292-299).  NOT "SAME": at stride >= 2 SAME aligns
-            # the sampling grid differently, which silently breaks
-            # converted-checkpoint parity at every level below the first.
-            lvl = nn.Conv(self.emb_ch, (3, 3), strides=(s, s),
-                          padding=((1, 1), (1, 1)), dtype=self.dtype,
-                          name=f"level_conv_{i_level}")(flat)
-            pose_embs.append(lvl.reshape(Bf, F, H // s, W // s, self.emb_ch))
+            Bf, F = pose_emb.shape[:2]
+            flat = pose_emb.reshape(Bf * F, H, W, D)
+            pose_embs = []
+            for i_level in range(self.num_resolutions):
+                s = 2 ** i_level
+                # Explicit (1, 1) padding = torch's padding=1 (reference
+                # xunet.py:292-299).  NOT "SAME": at stride >= 2 SAME aligns
+                # the sampling grid differently, which silently breaks
+                # converted-checkpoint parity at every level below the first.
+                lvl = nn.Conv(self.emb_ch, (3, 3), strides=(s, s),
+                              padding=((1, 1), (1, 1)), dtype=self.dtype,
+                              name=f"level_conv_{i_level}")(flat)
+                pose_embs.append(
+                    lvl.reshape(Bf, F, H // s, W // s, self.emb_ch))
 
-        return logsnr_emb, pose_embs
+            return logsnr_emb, pose_embs

@@ -23,6 +23,7 @@ import numpy as np
 from diff3d_tpu.ops import dispatch
 from diff3d_tpu.ops import pallas_film  # noqa: F401 - registers 'groupnorm'
 from diff3d_tpu.ops.attention import multi_head_attention
+from diff3d_tpu.utils.profiling import scope
 
 
 def nearest_neighbor_upsample(h: jnp.ndarray) -> jnp.ndarray:
@@ -89,31 +90,33 @@ class FrameGroupNorm(nn.Module):
     def __call__(self, h: jnp.ndarray,
                  scale: Optional[jnp.ndarray] = None,
                  shift: Optional[jnp.ndarray] = None) -> jnp.ndarray:
-        B, F, H, W, C = h.shape
-        groups = _num_groups(C, self.num_groups)
-        flat = jax.ShapeDtypeStruct((B * F, H * W, C), h.dtype)
-        impl = dispatch.resolve("groupnorm", self.kernels, flat,
-                                num_groups=groups)
-        if impl.name == "pallas":
-            gamma, beta = _GroupNormParams(C, name="GroupNorm_0")()
-            kw = {}
+        # one tag for every backend: the fused Pallas path keeps it
+        with scope("groupnorm"):
+            B, F, H, W, C = h.shape
+            groups = _num_groups(C, self.num_groups)
+            flat = jax.ShapeDtypeStruct((B * F, H * W, C), h.dtype)
+            impl = dispatch.resolve("groupnorm", self.kernels, flat,
+                                    num_groups=groups)
+            if impl.name == "pallas":
+                gamma, beta = _GroupNormParams(C, name="GroupNorm_0")()
+                kw = {}
+                if scale is not None:
+                    kw = dict(scale=scale.reshape(B * F, H * W, C),
+                              shift=shift.reshape(B * F, H * W, C))
+                out = impl.fn(h.reshape(B * F, H * W, C), gamma, beta,
+                              num_groups=groups, silu=self.silu, **kw)
+                return out.reshape(B, F, H, W, C)
+            # epsilon matches torch.nn.GroupNorm's 1e-5 (reference
+            # xunet.py:66); Flax's default 1e-6 drifts ~1e-5/application
+            # across the ~40 GNs of a converted checkpoint's forward.
+            out = nn.GroupNorm(num_groups=groups, epsilon=1e-5,
+                               dtype=self.dtype)(h.reshape(B * F, H, W, C))
+            out = out.reshape(B, F, H, W, C)
             if scale is not None:
-                kw = dict(scale=scale.reshape(B * F, H * W, C),
-                          shift=shift.reshape(B * F, H * W, C))
-            out = impl.fn(h.reshape(B * F, H * W, C), gamma, beta,
-                          num_groups=groups, silu=self.silu, **kw)
-            return out.reshape(B, F, H, W, C)
-        # epsilon matches torch.nn.GroupNorm's 1e-5 (reference xunet.py:66);
-        # Flax's default 1e-6 drifts ~1e-5/application across the ~40 GNs of
-        # a converted checkpoint's forward.
-        out = nn.GroupNorm(num_groups=groups, epsilon=1e-5,
-                           dtype=self.dtype)(h.reshape(B * F, H, W, C))
-        out = out.reshape(B, F, H, W, C)
-        if scale is not None:
-            out = out * (1.0 + scale) + shift
-        if self.silu:
-            out = nn.silu(out)
-        return out
+                out = out * (1.0 + scale) + shift
+            if self.silu:
+                out = nn.silu(out)
+            return out
 
 
 class FiLM(nn.Module):
@@ -134,11 +137,12 @@ class FiLM(nn.Module):
     @nn.compact
     def __call__(self, h: Optional[jnp.ndarray], emb: jnp.ndarray
                  ) -> Union[jnp.ndarray, Tuple[jnp.ndarray, jnp.ndarray]]:
-        emb = nn.Dense(2 * self.features, dtype=self.dtype)(nn.silu(emb))
-        scale, shift = jnp.split(emb, 2, axis=-1)
-        if h is None:
-            return scale, shift
-        return h * (1.0 + scale) + shift
+        with scope("film"):
+            emb = nn.Dense(2 * self.features, dtype=self.dtype)(nn.silu(emb))
+            scale, shift = jnp.split(emb, 2, axis=-1)
+            if h is None:
+                return scale, shift
+            return h * (1.0 + scale) + shift
 
 
 class ResnetBlock(nn.Module):
@@ -171,36 +175,42 @@ class ResnetBlock(nn.Module):
 
         h = FrameGroupNorm(dtype=self.dtype, kernels=self.kernels,
                            silu=True)(h_in)
-        h = nn.Conv(self.features, (3, 3), dtype=self.dtype,
-                    name="conv1")(h.reshape(B * F, H, W, C))
-        h = h.reshape(B, F, H, W, self.features)
+        with scope("conv"):
+            h = nn.Conv(self.features, (3, 3), dtype=self.dtype,
+                        name="conv1")(h.reshape(B * F, H, W, C))
+            h = h.reshape(B, F, H, W, self.features)
         if use_fused:
             scale, shift = FiLM(self.features, dtype=self.dtype)(None, emb)
-            scale = jnp.broadcast_to(scale, h.shape)
-            shift = jnp.broadcast_to(shift, h.shape)
+            with scope("film"):
+                scale = jnp.broadcast_to(scale, h.shape)
+                shift = jnp.broadcast_to(shift, h.shape)
             h = FrameGroupNorm(dtype=self.dtype, kernels=self.kernels)(
                 h, scale=scale, shift=shift)
         else:
             h = FrameGroupNorm(dtype=self.dtype, kernels=self.kernels)(h)
             h = FiLM(self.features, dtype=self.dtype)(h, emb)
-        h = nn.Dropout(self.dropout)(h, deterministic=deterministic)
+        with scope("dropout"):
+            h = nn.Dropout(self.dropout)(h, deterministic=deterministic)
         # Zero-init final conv (reference xunet.py:131) so the block starts
         # as (scaled) identity.
-        h = nn.Conv(self.features, (3, 3), dtype=self.dtype,
-                    kernel_init=nn.initializers.zeros,
-                    name="conv2")(h.reshape(B * F, H, W, self.features))
-        h = h.reshape(B, F, H, W, self.features)
+        with scope("conv"):
+            h = nn.Conv(self.features, (3, 3), dtype=self.dtype,
+                        kernel_init=nn.initializers.zeros,
+                        name="conv2")(h.reshape(B * F, H, W, self.features))
+            h = h.reshape(B, F, H, W, self.features)
 
-        if C != self.features:
-            h_in = nn.Conv(self.features, (1, 1), dtype=self.dtype,
-                           name="skip_proj")(h_in.reshape(B * F, H, W, C))
-            h_in = h_in.reshape(B, F, H, W, self.features)
+            if C != self.features:
+                h_in = nn.Conv(self.features, (1, 1), dtype=self.dtype,
+                               name="skip_proj")(
+                                   h_in.reshape(B * F, H, W, C))
+                h_in = h_in.reshape(B, F, H, W, self.features)
 
-        out = (h + h_in) / np.sqrt(2.0)
-        if self.resample == "up":
-            out = nearest_neighbor_upsample(out)
-        elif self.resample == "down":
-            out = avgpool_downsample(out)
+        with scope("residual"):
+            out = (h + h_in) / np.sqrt(2.0)
+            if self.resample == "up":
+                out = nearest_neighbor_upsample(out)
+            elif self.resample == "down":
+                out = avgpool_downsample(out)
         return out
 
 
@@ -216,12 +226,13 @@ class AttnLayer(nn.Module):
     @nn.compact
     def __call__(self, q: jnp.ndarray, kv: jnp.ndarray) -> jnp.ndarray:
         C = q.shape[-1]
-        qp = nn.Dense(C, dtype=self.dtype, name="q_proj")(q)
-        kp = nn.Dense(C, dtype=self.dtype, name="k_proj")(kv)
-        vp = nn.Dense(C, dtype=self.dtype, name="v_proj")(kv)
-        out = multi_head_attention(qp, kp, vp, self.num_heads,
-                                   impl=self.attn_impl)
-        return nn.Dense(C, dtype=self.dtype, name="out_proj")(out)
+        with scope("attention"):
+            qp = nn.Dense(C, dtype=self.dtype, name="q_proj")(q)
+            kp = nn.Dense(C, dtype=self.dtype, name="k_proj")(kv)
+            vp = nn.Dense(C, dtype=self.dtype, name="v_proj")(kv)
+            out = multi_head_attention(qp, kp, vp, self.num_heads,
+                                       impl=self.attn_impl)
+            return nn.Dense(C, dtype=self.dtype, name="out_proj")(out)
 
 
 class AttnBlock(nn.Module):
@@ -242,25 +253,30 @@ class AttnBlock(nn.Module):
     def __call__(self, h_in: jnp.ndarray) -> jnp.ndarray:
         B, F, H, W, C = h_in.shape
         h = FrameGroupNorm(dtype=self.dtype, kernels=self.kernels)(h_in)
-        tokens = h.reshape(B, F, H * W, C)
-
-        q = tokens.reshape(B * F, H * W, C)
-        if self.attn_type == "self":
-            kv = q
-        elif self.attn_type == "cross":
-            # Each frame attends to the other (reference xunet.py:206-211;
-            # generalised beyond F=2 as "next frame, cyclically").
-            kv = jnp.roll(tokens, shift=-1, axis=1).reshape(B * F, H * W, C)
-        else:
-            raise NotImplementedError(self.attn_type)
+        with scope("attention"):
+            tokens = h.reshape(B, F, H * W, C)
+            q = tokens.reshape(B * F, H * W, C)
+            if self.attn_type == "self":
+                kv = q
+            elif self.attn_type == "cross":
+                # Each frame attends to the other (reference
+                # xunet.py:206-211; generalised beyond F=2 as "next frame,
+                # cyclically").
+                kv = jnp.roll(tokens, shift=-1, axis=1).reshape(
+                    B * F, H * W, C)
+            else:
+                raise NotImplementedError(self.attn_type)
 
         h = AttnLayer(self.num_heads, self.attn_impl, self.dtype,
                       name="attn")(q, kv)
-        h = h.reshape(B * F, H, W, C)
-        h = nn.Conv(C, (1, 1), dtype=self.dtype,
-                    kernel_init=nn.initializers.zeros, name="out_conv")(h)
-        h = h.reshape(B, F, H, W, C)
-        return (h + h_in) / np.sqrt(2.0)
+        with scope("conv"):
+            h = h.reshape(B * F, H, W, C)
+            h = nn.Conv(C, (1, 1), dtype=self.dtype,
+                        kernel_init=nn.initializers.zeros,
+                        name="out_conv")(h)
+            h = h.reshape(B, F, H, W, C)
+        with scope("residual"):
+            return (h + h_in) / np.sqrt(2.0)
 
 
 class XUNetBlock(nn.Module):

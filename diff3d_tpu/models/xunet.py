@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from diff3d_tpu.config import ModelConfig
 from diff3d_tpu.models.conditioning import ConditioningProcessor
 from diff3d_tpu.models.layers import FrameGroupNorm, ResnetBlock, XUNetBlock
+from diff3d_tpu.utils.profiling import scope
 
 
 class XUNet(nn.Module):
@@ -77,14 +78,16 @@ class XUNet(nn.Module):
 
         def level_emb(i):
             # [B, F, 1, 1, emb_ch] + [B, F, h, w, emb_ch]
-            return logsnr_emb[:, :, None, None, :] + pose_embs[i]
+            with scope("conditioning"):
+                return logsnr_emb[:, :, None, None, :] + pose_embs[i]
 
         # Stem: both frames through one 3x3 conv (reference xunet.py:493-495).
-        h = jnp.stack([batch["x"], batch["z"]], axis=1).astype(dtype)
-        F = h.shape[1]
-        h = nn.Conv(cfg.ch, (3, 3), dtype=dtype,
-                    name="stem_conv")(h.reshape(B * F, H, W, C))
-        h = constrain(h.reshape(B, F, H, W, cfg.ch))
+        with scope("conv"):
+            h = jnp.stack([batch["x"], batch["z"]], axis=1).astype(dtype)
+            F = h.shape[1]
+            h = nn.Conv(cfg.ch, (3, 3), dtype=dtype,
+                        name="stem_conv")(h.reshape(B * F, H, W, C))
+            h = constrain(h.reshape(B, F, H, W, cfg.ch))
 
         # Down path (reference xunet.py:498-512).
         hs = [h]
@@ -120,7 +123,8 @@ class XUNet(nn.Module):
             emb = level_emb(i_level)
             use_attn = i_level in cfg.attn_levels
             for i_block in range(cfg.num_res_blocks + 1):
-                h = jnp.concatenate([h, hs.pop()], axis=-1)
+                with scope("residual"):
+                    h = jnp.concatenate([h, hs.pop()], axis=-1)
                 h = constrain(block_cls(
                     features=dim_out[i_level], use_attn=use_attn,
                     num_heads=cfg.attn_heads, dropout=cfg.dropout,
@@ -138,8 +142,9 @@ class XUNet(nn.Module):
         # (reference xunet.py:472-474,535-536).
         h = FrameGroupNorm(dtype=dtype, kernels=cfg.kernels, silu=True,
                            name="last_gn")(h)
-        h = nn.Conv(3, (3, 3), dtype=dtype,
-                    kernel_init=nn.initializers.zeros,
-                    name="last_conv")(h.reshape(B * F, H, W, dim_out[0]))
-        h = h.reshape(B, F, H, W, 3)
-        return h[:, 1].astype(jnp.float32)
+        with scope("conv"):
+            h = nn.Conv(3, (3, 3), dtype=dtype,
+                        kernel_init=nn.initializers.zeros,
+                        name="last_conv")(h.reshape(B * F, H, W, dim_out[0]))
+            h = h.reshape(B, F, H, W, 3)
+            return h[:, 1].astype(jnp.float32)

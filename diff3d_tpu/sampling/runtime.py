@@ -62,6 +62,7 @@ from diff3d_tpu.diffusion import (SAMPLER_KINDS, sample_loop_prepare,
                                   sample_loop_scan, sample_view,
                                   sample_view_commit, schedule_start_index)
 from diff3d_tpu.models import XUNet
+from diff3d_tpu.utils.profiling import scope, span
 
 
 def to_uint8(img: np.ndarray) -> np.ndarray:
@@ -146,6 +147,7 @@ class Sampler:
         self.model = model
         self.cfg = cfg
         self.mesh = mesh
+        self._calls = 0     # synthesize* calls so far: the spans' id
         self.w = jnp.asarray(cfg.diffusion.guidance_weights, jnp.float32)
 
         d = cfg.diffusion
@@ -535,6 +537,18 @@ class Sampler:
     def _put(self, x, sharding):
         return self._owned(x, sharding)
 
+    def _fetch(self, rec_i, index, call: int) -> np.ndarray:
+        """The offline loops' one device->host fetch: wait for the last
+        view step (``sampler.wait``: the device's time, not the host's),
+        then slice the generated views on device and copy them
+        (``sampler.fetch``)."""
+        with span("sampler.wait", id=call):
+            rec_i = jax.block_until_ready(rec_i)
+        with span("sampler.fetch", id=call):
+            with scope("record"):
+                out = rec_i[index]
+            return np.asarray(out)
+
     def _check_no_truncation(self, entry: str) -> None:
         if self.start_t is not None:
             raise ValueError(
@@ -570,23 +584,28 @@ class Sampler:
         if n_views < 2:
             return np.zeros((0, B, H, W, 3), np.float32)
 
-        record_imgs, record_R, record_T = self._record_init(
-            imgs[0], R, T, n_views)
+        self._calls += 1
+        call = self._calls
+        with span("sampler.stage", id=call):
+            record_imgs, record_R, record_T = self._record_init(
+                imgs[0], R, T, n_views)
 
-        # One-time upload of the carry; after this the loop only threads
-        # returned device handles (rec_i is donated each step and written
-        # in place).
-        rec_i = self._put(record_imgs, self._rep)
-        rec_R = self._put(record_R, self._rep)
-        rec_T = self._put(record_T, self._rep)
-        K_d = self._put(K, self._rep)
-        step_d = self._put(np.asarray(1, np.int32), self._rep)
-        rng_d = self._put(np.asarray(rng), self._rep)
+            # One-time upload of the carry; after this the loop only
+            # threads returned device handles (rec_i is donated each step
+            # and written in place).
+            rec_i = self._put(record_imgs, self._rep)
+            rec_R = self._put(record_R, self._rep)
+            rec_T = self._put(record_T, self._rep)
+            K_d = self._put(K, self._rep)
+            step_d = self._put(np.asarray(1, np.int32), self._rep)
+            rng_d = self._put(np.asarray(rng), self._rep)
         for _ in range(1, n_views):
-            _, rec_i, step_d, rng_d = self._run_view(
-                self.params, rec_i, rec_R, rec_T, step_d, K_d, rng_d)
+            # the host's cost of one view's enqueue, timed as one on purpose
+            with span("sampler.dispatch", id=call):
+                _, rec_i, step_d, rng_d = self._run_view(
+                    self.params, rec_i, rec_R, rec_T, step_d, K_d, rng_d)
         # Single fetch: slice the generated views on device, pull once.
-        outs = np.asarray(jax.block_until_ready(rec_i[1:n_views]))
+        outs = self._fetch(rec_i, (slice(1, n_views),), call)
 
         if out_dir is not None:
             for step in range(1, n_views):
@@ -631,29 +650,34 @@ class Sampler:
         if n_views < 2:
             return np.zeros((N, 0, B, H, W, 3), np.float32)
 
-        mult = self.lane_multiple
-        pad_idx = list(range(N)) + [0] * (-N % mult)
-        recs = [self._record_init(
-                    np.asarray(views_list[i]["imgs"][0], np.float32),
-                    np.asarray(views_list[i]["R"], np.float32),
-                    np.asarray(views_list[i]["T"], np.float32), n_views)
-                for i in pad_idx]
-        record_imgs = np.stack([r[0] for r in recs])
-        record_R = np.stack([r[1] for r in recs])
-        record_T = np.stack([r[2] for r in recs])
-        Ks = np.stack([np.asarray(views_list[i]["K"], np.float32)
-                       for i in pad_idx])
-        keys = np.stack([np.asarray(rngs[i]) for i in pad_idx])
-        steps = np.full((len(pad_idx),), 1, np.int32)
+        self._calls += 1
+        call = self._calls
+        with span("sampler.stage", id=call):
+            mult = self.lane_multiple
+            pad_idx = list(range(N)) + [0] * (-N % mult)
+            recs = [self._record_init(
+                        np.asarray(views_list[i]["imgs"][0], np.float32),
+                        np.asarray(views_list[i]["R"], np.float32),
+                        np.asarray(views_list[i]["T"], np.float32), n_views)
+                    for i in pad_idx]
+            record_imgs = np.stack([r[0] for r in recs])
+            record_R = np.stack([r[1] for r in recs])
+            record_T = np.stack([r[2] for r in recs])
+            Ks = np.stack([np.asarray(views_list[i]["K"], np.float32)
+                           for i in pad_idx])
+            keys = np.stack([np.asarray(rngs[i]) for i in pad_idx])
+            steps = np.full((len(pad_idx),), 1, np.int32)
 
-        rec_i = self._put(record_imgs, self._obj)
-        rec_R = self._put(record_R, self._obj)
-        rec_T = self._put(record_T, self._obj)
-        Ks_d = self._put(Ks, self._obj)
-        steps_d = self._put(steps, self._obj)
-        keys_d = self._put(keys, self._obj)
+            rec_i = self._put(record_imgs, self._obj)
+            rec_R = self._put(record_R, self._obj)
+            rec_T = self._put(record_T, self._obj)
+            Ks_d = self._put(Ks, self._obj)
+            steps_d = self._put(steps, self._obj)
+            keys_d = self._put(keys, self._obj)
         for _ in range(1, n_views):
-            _, rec_i, steps_d, keys_d = self._run_view_many(
-                self.params, rec_i, rec_R, rec_T, steps_d, Ks_d, keys_d)
+            # the host's cost of one view's enqueue, timed as one on purpose
+            with span("sampler.dispatch", id=call):
+                _, rec_i, steps_d, keys_d = self._run_view_many(
+                    self.params, rec_i, rec_R, rec_T, steps_d, Ks_d, keys_d)
         # Single fetch: drop padding lanes + the seeded view 0 on device.
-        return np.asarray(jax.block_until_ready(rec_i[:N, 1:n_views]))
+        return self._fetch(rec_i, (slice(None, N), slice(1, n_views)), call)

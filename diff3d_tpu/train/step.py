@@ -23,6 +23,7 @@ from diff3d_tpu.diffusion import p_losses
 from diff3d_tpu.parallel import MeshEnv
 from diff3d_tpu.train.state import (TrainState, ema_decay_per_step,
                                     make_optimizer, warmup_schedule)
+from diff3d_tpu.utils.profiling import scope, span
 
 TrainStepFn = Callable[[TrainState, Dict[str, jnp.ndarray], jax.Array],
                        Tuple[TrainState, Dict[str, jnp.ndarray]]]
@@ -52,7 +53,8 @@ def make_train_step(model, cfg: Config, env: MeshEnv | None = None,
                  if env is not None and cfg.mesh.context_parallel else None)
 
     def loss_and_grad(params, batch, rng):
-        rng, k_drop = jax.random.split(rng)
+        with scope("loss"):
+            rng, k_drop = jax.random.split(rng)
 
         def loss_fn(params):
             def denoise(model_batch, cond_mask):
@@ -62,8 +64,10 @@ def make_train_step(model, cfg: Config, env: MeshEnv | None = None,
                                    constrain=constrain)
             # Loader batches arrive as uint8 (data/images.py); the cast
             # to [-1, 1] f32 happens here on device, fused by XLA.
+            with scope("loss"):
+                imgs = dequantize(batch["imgs"])
             return p_losses(
-                denoise, dequantize(batch["imgs"]), batch["R"], batch["T"],
+                denoise, imgs, batch["R"], batch["T"],
                 batch["K"], rng, cond_prob=dcfg.cond_prob,
                 loss_type=dcfg.loss_type, logsnr_min=dcfg.logsnr_min,
                 logsnr_max=dcfg.logsnr_max)
@@ -72,43 +76,53 @@ def make_train_step(model, cfg: Config, env: MeshEnv | None = None,
 
     def step_fn(state: TrainState, batch: Dict[str, jnp.ndarray],
                 rng: jax.Array) -> Tuple[TrainState, Dict[str, jnp.ndarray]]:
-        rng = jax.random.fold_in(rng, state.step)
+        with scope("loss"):
+            rng = jax.random.fold_in(rng, state.step)
 
         if accum == 1:
             loss, grads = loss_and_grad(state.params, batch, rng)
         else:
             # Scan over `accum` microbatches; only one microbatch's
-            # activations are live at a time, grads averaged.
-            micro = jax.tree.map(
-                lambda x: x.reshape(accum, x.shape[0] // accum,
-                                    *x.shape[1:]), batch)
+            # activations are live at a time, grads averaged.  The scope
+            # is the loop's and the accumulation's; every op of the loss
+            # and the model inside it carries its own, inner tag.
+            with scope("grad_accum"):
+                micro = jax.tree.map(
+                    lambda x: x.reshape(accum, x.shape[0] // accum,
+                                        *x.shape[1:]), batch)
 
-            def body(carry, inp):
-                i, mb = inp
-                l, g = loss_and_grad(state.params, mb,
-                                     jax.random.fold_in(rng, i))
-                loss_acc, grads_acc = carry
-                return (loss_acc + l,
-                        jax.tree.map(jnp.add, grads_acc, g)), None
+                def body(carry, inp):
+                    i, mb = inp
+                    l, g = loss_and_grad(state.params, mb,
+                                         jax.random.fold_in(rng, i))
+                    loss_acc, grads_acc = carry
+                    return (loss_acc + l,
+                            jax.tree.map(jnp.add, grads_acc, g)), None
 
-            init = (jnp.zeros(()),
-                    jax.tree.map(jnp.zeros_like, state.params))
-            (loss, grads), _ = jax.lax.scan(
-                body, init, (jnp.arange(accum), micro))
-            loss = loss / accum
-            grads = jax.tree.map(lambda g: g / accum, grads)
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
-        ema_params = jax.tree.map(
-            lambda e, p: ema_decay * e + (1.0 - ema_decay) * p,
-            state.ema_params, params)
-        new_state = TrainState(step=state.step + 1, params=params,
-                               opt_state=opt_state, ema_params=ema_params)
-        metrics = {
-            "loss": loss,
-            "lr": sched(state.step),
-            "grad_norm": optax.global_norm(grads),
-        }
+                init = (jnp.zeros(()),
+                        jax.tree.map(jnp.zeros_like, state.params))
+                (loss, grads), _ = jax.lax.scan(
+                    body, init, (jnp.arange(accum), micro))
+                loss = loss / accum
+                grads = jax.tree.map(lambda g: g / accum, grads)
+        with scope("optimizer"):
+            updates, opt_state = tx.update(grads, state.opt_state,
+                                           state.params)
+            params = optax.apply_updates(state.params, updates)
+        with scope("ema"):
+            ema_params = jax.tree.map(
+                lambda e, p: ema_decay * e + (1.0 - ema_decay) * p,
+                state.ema_params, params)
+        with scope("optimizer"):
+            new_state = TrainState(step=state.step + 1, params=params,
+                                   opt_state=opt_state,
+                                   ema_params=ema_params)
+        with scope("metrics"):
+            metrics = {
+                "loss": loss,
+                "lr": sched(state.step),
+                "grad_norm": optax.global_norm(grads),
+            }
         return new_state, metrics
 
     if env is None:
@@ -131,8 +145,15 @@ def make_train_step(model, cfg: Config, env: MeshEnv | None = None,
                 donate_argnums=(0,) if donate else ())
         return jitted
 
+    calls = 0
+
     def sharded_step(state, batch, rng):
-        return _jitted(state, batch)(state, batch, rng)
+        # the host's cost of handing the state's leaves to the compiled
+        # step: an enqueue, timed as one on purpose
+        nonlocal calls
+        calls += 1
+        with span("train.dispatch", id=calls):
+            return _jitted(state, batch)(state, batch, rng)
 
     # The sharded path jits lazily inside this closure; expose the same
     # ``.lower`` the env=None jit has so analysis tooling (shardcheck,

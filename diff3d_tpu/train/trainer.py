@@ -13,6 +13,7 @@ steps-per-sec / examples-per-sec), optional ``jax.profiler`` traces.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import logging
@@ -37,6 +38,7 @@ from diff3d_tpu.runtime.retry import (RetryBudget, RetryPolicy,
 from diff3d_tpu.train.checkpoint import CheckpointManager
 from diff3d_tpu.train.state import TrainState, create_train_state
 from diff3d_tpu.train.step import make_train_step
+from diff3d_tpu.utils.profiling import RECORDER, profile_window
 
 log = logging.getLogger(__name__)
 
@@ -48,6 +50,13 @@ log = logging.getLogger(__name__)
 _STEP_RETRY = RetryPolicy(max_attempts=3, base_delay_s=5.0,
                           max_delay_s=30.0,
                           classify=is_transient_backend_error)
+
+
+def _input_wait() -> tuple:
+    """``(seconds waited for batches, times the prefetch queue was found
+    empty)`` so far, from ``prefetch_to_device``'s span and counter."""
+    return (RECORDER.totals().get("prefetch.wait", (0, 0.0))[1],
+            int(RECORDER.counters().get("prefetch.starved", 0)))
 
 
 def init_params(model: XUNet, cfg: Config, rng: jax.Array):
@@ -297,8 +306,16 @@ class Trainer:
         """Run the training loop.
 
         ``profile_steps=(start, stop)`` captures a ``jax.profiler`` device
-        trace of those steps into ``<workdir>/profile`` (start after the
-        first step so the compile isn't traced).
+        trace of those steps into ``<workdir>/profile`` through
+        :func:`~diff3d_tpu.utils.profiling.profile_window`, which leaves
+        ``by_scope.json`` (device seconds by block class, idle by host
+        span) beside it (start after the first step so the compile isn't
+        traced).
+
+        Each ``metrics.jsonl`` record carries ``input_wait_s`` (seconds of
+        its log window the loop waited for a batch) and ``starved`` (how
+        often the prefetch queue was empty when asked), read from the
+        loader's own spans and counter.
 
         Failure handling the reference lacks (SURVEY.md §5.3): a non-finite
         loss halts with a checkpoint-preserving ``FloatingPointError``
@@ -315,14 +332,14 @@ class Trainer:
         # jitted step runs async; we only block at log boundaries).
         step = int(self.state.step)
         window_start, window_t = step, t0
-        profiling = False
+        window_wait, window_starved = _input_wait()
+        profile = contextlib.ExitStack()     # holds the open profile window
 
         try:
             while step < max_steps:
                 if profile_steps and step == profile_steps[0]:
-                    jax.profiler.start_trace(
-                        os.path.join(self.workdir, "profile"))
-                    profiling = True
+                    profile.enter_context(profile_window(
+                        os.path.join(self.workdir, "profile")))
 
                 batch = next(self.loader)
                 batch = {"imgs": batch["imgs"], "R": batch["R"],
@@ -336,10 +353,9 @@ class Trainer:
                     describe=f"train step {step + 1}")
                 step += 1
 
-                if profiling and step >= profile_steps[1]:
+                if profile_steps and step == profile_steps[1]:
                     jax.block_until_ready(metrics["loss"])
-                    jax.profiler.stop_trace()
-                    profiling = False
+                    profile.close()
 
                 if ((cfg.log_every > 0 and step % cfg.log_every == 0)
                         or step >= max_steps):
@@ -348,6 +364,7 @@ class Trainer:
                     dt = max(now - window_t, 1e-9)
                     sps = (step - window_start) / dt
                     window_start, window_t = step, now
+                    wait, starved = _input_wait()
                     loss = float(metrics["loss"])
                     rec = {
                         "step": step,
@@ -357,7 +374,10 @@ class Trainer:
                         "steps_per_sec": sps,
                         "examples_per_sec": sps * cfg.global_batch,
                         "wall_s": now - t0,
+                        "input_wait_s": wait - window_wait,
+                        "starved": starved - window_starved,
                     }
+                    window_wait, window_starved = wait, starved
                     self._log(rec)
                     log.info("step %d loss %.4f (%.2f steps/s)",
                              step, rec["loss"], sps)
@@ -445,8 +465,7 @@ class Trainer:
                 log.exception("emergency checkpoint failed")
             raise
         finally:
-            if profiling:  # pragma: no cover - only on mid-window exit
-                jax.profiler.stop_trace()
+            profile.close()      # a window still open on a mid-window exit
 
         self.ckpt.wait()
         return self.state

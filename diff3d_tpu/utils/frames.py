@@ -14,8 +14,6 @@ from typing import Optional
 
 import numpy as np
 
-from diff3d_tpu.sampling.runtime import save_image, to_uint8
-
 __all__ = ["save_frame_sequence"]
 
 
@@ -33,6 +31,10 @@ def save_frame_sequence(out_dir: str, frames: np.ndarray,
     Returns ``{"dir", "frames", "contact_sheet"}`` with the paths
     written, so CLI callers can report artefact locations.
     """
+    # imported here: sampling imports the model, whose layers import
+    # this package's profiling module
+    from diff3d_tpu.sampling.runtime import save_image, to_uint8
+
     frames = np.asarray(frames, np.float32)
     if frames.ndim == 5:
         frames = frames[:, 0]

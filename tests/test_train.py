@@ -496,6 +496,12 @@ def test_step_timer_and_profile_window(tmp_path):
     with profile_window(str(tmp_path / "prof")):
         jnp.zeros(8).block_until_ready()
     assert os.path.isdir(tmp_path / "prof")
+    # ... and its reduction to seconds by block class beside it
+    import json
+
+    by_scope = json.loads((tmp_path / "prof" / "by_scope.json").read_text())
+    assert set(by_scope) == {"by_class", "unscoped_s", "unscoped_top",
+                             "idle_by_span", "busy_s", "window_s"}
 
 
 def test_trainer_halts_on_nonfinite_loss(tmp_path):
