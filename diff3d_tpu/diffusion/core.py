@@ -19,7 +19,9 @@ import numpy as np
 
 from diff3d_tpu.utils.profiling import scope
 
-# A denoiser: (batch dict, cond_mask [B] bool) -> eps_hat [B, H, W, 3].
+# A denoiser: (batch dict, cond_mask [G] bool) -> eps_hat [B, H, W, 3], with
+# the batch's conditioning inputs at G rows too (G divides B, group-major:
+# the model's forward contract, models/xunet.py).
 # Dropout/other rngs are expected to be bound by the caller (closure over
 # model.apply with its `rngs=`).
 DenoiseFn = Callable[[dict, jnp.ndarray], jnp.ndarray]
@@ -435,10 +437,14 @@ def sample_loop_scan(denoise_fn: DenoiseFn, state: SampleState, xs, *,
     """
     B = w.shape[0]
 
+    # The B guidance weights of a view share one pose and one logSNR, so
+    # the model gets its conditioning at 2 rows — the conditional one and
+    # the unconditional one, in the order of the `[cond x B, uncond x B]`
+    # examples — and computes that branch twice, not 2B times (the
+    # group-major rule of the model's forward contract).
     with scope("sampler"):
-        Kb = jnp.broadcast_to(K[None], (B, 3, 3))
-        w_mask_2b = jnp.concatenate(
-            [jnp.ones((B,), bool), jnp.zeros((B,), bool)])
+        K2 = jnp.broadcast_to(K[None], (2, 3, 3))
+        w_mask_2 = jnp.array([True, False])
 
     cam_dirs = None
     if hoist_cond:
@@ -446,9 +452,8 @@ def sample_loop_scan(denoise_fn: DenoiseFn, state: SampleState, xs, *,
 
         H, W = record_imgs.shape[-3:-1]
         with scope("conditioning"):
-            K2 = jnp.concatenate([Kb, Kb])                 # [2B, 3, 3]
             cam_dirs = pinhole_rays_cam(
-                K2[:, None].astype(jnp.float32), H, W)     # [2B, 1, H, W, 3]
+                K2[:, None].astype(jnp.float32), H, W)     # [2, 1, H, W, 3]
 
     def step(state: SampleState, xs):
         logsnr, logsnr_next, idx, = xs
@@ -458,25 +463,22 @@ def sample_loop_scan(denoise_fn: DenoiseFn, state: SampleState, xs, *,
             cond_img = record_imgs[idx]                     # [B, H, W, 3]
             R = jnp.stack([record_R[idx], target_R])        # [2, 3, 3]
             T = jnp.stack([record_T[idx], target_T])        # [2, 3]
-            Rb = jnp.broadcast_to(R[None], (B, 2, 3, 3))
-            Tb = jnp.broadcast_to(T[None], (B, 2, 3))
 
         with scope("sampler"):
             # Fold CFG cond + uncond passes into one 2B model call.
             x_uncond = jax.random.normal(k_x, cond_img.shape,
                                          cond_img.dtype)
-            logsnr_b = jnp.full((2 * B,), logsnr)
             batch = make_model_batch(
                 jnp.concatenate([cond_img, x_uncond]),
                 jnp.concatenate([state.img, state.img]),
-                logsnr_b,
-                jnp.concatenate([Rb, Rb]),
-                jnp.concatenate([Tb, Tb]),
-                jnp.concatenate([Kb, Kb]),
+                jnp.full((2,), logsnr),
+                jnp.broadcast_to(R[None], (2, 2, 3, 3)),
+                jnp.broadcast_to(T[None], (2, 2, 3)),
+                K2,
                 logsnr_max=logsnr_max)
             if cam_dirs is not None:
                 batch = dict(batch, cam_dirs=cam_dirs)     # scan constant
-        eps = denoise_fn(batch, w_mask_2b)
+        eps = denoise_fn(batch, w_mask_2)
 
         with scope("sampler"):
             eps_cond, eps_uncond = eps[:B], eps[B:]

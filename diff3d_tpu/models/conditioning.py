@@ -28,7 +28,15 @@ POSE_EMB_CH = posenc_nerf_channels(0, POS_DEG) + posenc_nerf_channels(0, DIR_DEG
 
 
 class ConditioningProcessor(nn.Module):
-    """Produces ``(logsnr_emb [B,F,emb_ch], pose_embs[level])`` for the UNet.
+    """Produces ``(logsnr_emb [G,F,emb_ch], pose_embs[level])`` for the UNet.
+
+    Everything here runs at ``G``, the leading dimension of the
+    conditioning inputs (``logsnr``, ``R``, ``t``, ``K``, the optional
+    ``cam_dirs``) and of ``cond_mask`` — never at the leading dimension
+    ``B`` of ``x`` / ``z``.  ``G`` divides ``B`` and example ``b`` uses row
+    ``b // (B // G)`` (group-major; :class:`XUNet` checks it, and
+    :class:`~diff3d_tpu.models.layers.FiLM` is where the rows meet the
+    examples).  ``G == B`` is one row per example, as in training.
 
     Mechanism (parity with reference ``xunet.py:301-352``):
       1. clip logsnr to the schedule bounds; DDPM-posenc it with
@@ -57,24 +65,23 @@ class ConditioningProcessor(nn.Module):
     def __call__(self, batch: dict, cond_mask: jnp.ndarray
                  ) -> Tuple[jnp.ndarray, List[jnp.ndarray]]:
         with scope("conditioning"):
-            B = batch["x"].shape[0]
             H, W = self.H, self.W
             D = POSE_EMB_CH
 
             logsnr = jnp.clip(batch["logsnr"], -self.logsnr_clip,
-                              self.logsnr_clip)                      # [B, F]
+                              self.logsnr_clip)                      # [G, F]
             # Encodings stay float32: their sinusoid arguments reach ~2e4
             # (posenc_ddpm's x1000 scaling) and 2^14 (NeRF degree 15), far past
             # bf16's mantissa — bf16 here destroys all phase information.
             # The Dense/Conv layers below cast to the compute dtype
             # themselves.
             logsnr_emb = posenc_ddpm(logsnr, emb_ch=self.emb_ch, max_time=1.0,
-                                     dtype=jnp.float32)  # [B, F, emb_ch]
+                                     dtype=jnp.float32)  # [G, F, emb_ch]
             logsnr_emb = nn.Dense(self.emb_ch, dtype=self.dtype)(logsnr_emb)
             logsnr_emb = nn.Dense(self.emb_ch, dtype=self.dtype)(
                 nn.silu(logsnr_emb))
 
-            # [B, F, H, W, 3] each; K broadcast over the frame axis
+            # [G, F, H, W, 3] each; K broadcast over the frame axis
             # (reference unsqueezes K at xunet.py:312).  The intrinsics-only
             # half (K_inv @ pixel grid) may arrive precomputed as
             # batch['cam_dirs'] — the sampler's scan hoists it once per
@@ -90,7 +97,7 @@ class ConditioningProcessor(nn.Module):
                                            cam_dirs)
             pose_emb = jnp.concatenate(
                 [posenc_nerf(pos, 0, POS_DEG), posenc_nerf(dirs, 0, DIR_DEG)],
-                axis=-1)                             # [B, F, H, W, 144]
+                axis=-1)                             # [G, F, H, W, 144]
 
             pose_emb = jnp.where(cond_mask[:, None, None, None, None],
                                  pose_emb, jnp.zeros_like(pose_emb))
@@ -114,8 +121,8 @@ class ConditioningProcessor(nn.Module):
                     [first_emb] + [other_emb] * (F - 1), axis=1)
                 pose_emb = pose_emb + ref_emb
 
-            Bf, F = pose_emb.shape[:2]
-            flat = pose_emb.reshape(Bf * F, H, W, D)
+            G, F = pose_emb.shape[:2]
+            flat = pose_emb.reshape(G * F, H, W, D)
             pose_embs = []
             for i_level in range(self.num_resolutions):
                 s = 2 ** i_level
@@ -127,6 +134,6 @@ class ConditioningProcessor(nn.Module):
                               padding=((1, 1), (1, 1)), dtype=self.dtype,
                               name=f"level_conv_{i_level}")(flat)
                 pose_embs.append(
-                    lvl.reshape(Bf, F, H // s, W // s, self.emb_ch))
+                    lvl.reshape(G, F, H // s, W // s, self.emb_ch))
 
             return logsnr_emb, pose_embs

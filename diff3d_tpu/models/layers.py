@@ -119,15 +119,34 @@ class FrameGroupNorm(nn.Module):
             return out
 
 
+def per_example(a: jnp.ndarray, B: int) -> jnp.ndarray:
+    """``[G, ...]`` conditioning rows viewed at the ``B`` examples they
+    serve, group-major: example ``b`` reads row ``b // (B // G)``.  At
+    ``G == B`` (one row per example) this is ``a`` itself: no op is added."""
+    G = a.shape[0]
+    if G == B:
+        return a
+    a = jnp.broadcast_to(a[:, None], (G, B // G) + a.shape[1:])
+    return a.reshape((B,) + a.shape[2:])
+
+
 class FiLM(nn.Module):
     """Feature-wise linear modulation (reference ``xunet.py:74-87``):
     ``Dense(emb_ch -> 2*features)`` on SiLU(emb), split into scale/shift,
-    ``h * (1 + scale) + shift``.  ``emb`` is ``[B, F, h, w, emb_ch]`` —
+    ``h * (1 + scale) + shift``.  ``emb`` is ``[G, F, h, w, emb_ch]`` —
     channels-last, so no transposes are needed (the reference transposes
     twice around its Linear).
 
-    With ``h=None`` the module only *emits* ``(scale, shift)`` — the
-    fused-kernel path hands them to :class:`FrameGroupNorm`'s epilogue
+    ``G`` divides the leading dimension ``B`` of ``h`` (the model's forward
+    contract, :mod:`diff3d_tpu.models.xunet`): the dense runs once per
+    conditioning row, and only the modulation meets ``h`` at ``B``, with
+    scale/shift broadcast over the ``B // G`` examples of a group — ``h``
+    is viewed as ``[G, B // G, F, h, w, C]``, nothing of the conditioning
+    branch exists at ``B`` rows.  At ``G == B`` there is no such view:
+    the ops are the per-example ones.
+
+    With ``h=None`` the module only *emits* ``(scale, shift)``, at ``G`` —
+    the fused-kernel path hands them to :class:`FrameGroupNorm`'s epilogue
     instead of applying them here.  The parameter tree (``Dense_0``) is
     unchanged either way."""
 
@@ -142,7 +161,20 @@ class FiLM(nn.Module):
             scale, shift = jnp.split(emb, 2, axis=-1)
             if h is None:
                 return scale, shift
-            return h * (1.0 + scale) + shift
+            G, B = emb.shape[0], h.shape[0]
+            if G == B:
+                return h * (1.0 + scale) + shift
+            hg = h.reshape((G, B // G) + h.shape[1:])
+            hg = hg * (1.0 + scale[:, None]) + shift[:, None]
+            # The barrier keeps the product in this 6-D view, where the
+            # broadcast over a group's examples is a whole dimension and
+            # fuses into the GroupNorm pass that streams `h`.  Without it
+            # XLA moves the reshape above the product and fuses that into
+            # the next conv, whose batch dimension cannot express a
+            # broadcast over its middle: it then writes scale and shift
+            # out at B rows first (PERF.md, PR 25: 3.0 ms of a 59.3 ms
+            # model call at srn64).
+            return jax.lax.optimization_barrier(hg).reshape(h.shape)
 
 
 class ResnetBlock(nn.Module):
@@ -182,8 +214,8 @@ class ResnetBlock(nn.Module):
         if use_fused:
             scale, shift = FiLM(self.features, dtype=self.dtype)(None, emb)
             with scope("film"):
-                scale = jnp.broadcast_to(scale, h.shape)
-                shift = jnp.broadcast_to(shift, h.shape)
+                scale = jnp.broadcast_to(per_example(scale, B), h.shape)
+                shift = jnp.broadcast_to(per_example(shift, B), h.shape)
             h = FrameGroupNorm(dtype=self.dtype, kernels=self.kernels)(
                 h, scale=scale, shift=shift)
         else:

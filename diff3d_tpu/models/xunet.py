@@ -15,9 +15,20 @@ differ only in device handling).  Differences by design, not omission:
     config that OOMs the reference's GPUs (README.md:39).
 
 Forward contract (reference ``xunet.py:477-536``): batch dict with
-``x [B,H,W,3]``, ``z [B,H,W,3]``, ``logsnr [B,2]``, ``R [B,2,3,3]``,
-``t [B,2,3]``, ``K [B,3,3]`` plus ``cond_mask [B] bool``; returns the
-predicted noise for the target frame, ``[B, H, W, 3]``.
+``x [B,H,W,3]``, ``z [B,H,W,3]``, ``logsnr [G,2]``, ``R [G,2,3,3]``,
+``t [G,2,3]``, ``K [G,3,3]`` (and the optional ``cam_dirs [G,1,H,W,3]``)
+plus ``cond_mask [G] bool``; returns the predicted noise for the target
+frame, ``[B, H, W, 3]``.
+
+``G`` divides ``B``, and example ``b`` uses conditioning row
+``b // (B // G)`` (group-major): the conditioning branch — pose
+embedding, its level convs, every FiLM dense — is computed once per
+distinct conditioning, not once per example.  The rule is read from the
+shapes alone.  ``G == B`` is one row per example (training, distillation,
+evaluation); the sampler's scan passes ``G = 2`` (a conditional and an
+unconditional row) for its ``2 x guidance weights`` examples.  Each trace
+adds ``G`` and ``B`` to the recorder's ``conditioning.groups`` /
+``conditioning.examples`` counters (:mod:`diff3d_tpu.utils.profiling`).
 """
 
 from __future__ import annotations
@@ -28,7 +39,7 @@ import jax.numpy as jnp
 from diff3d_tpu.config import ModelConfig
 from diff3d_tpu.models.conditioning import ConditioningProcessor
 from diff3d_tpu.models.layers import FrameGroupNorm, ResnetBlock, XUNetBlock
-from diff3d_tpu.utils.profiling import scope
+from diff3d_tpu.utils.profiling import count, scope
 
 
 class XUNet(nn.Module):
@@ -49,7 +60,20 @@ class XUNet(nn.Module):
         dtype = jnp.dtype(cfg.dtype)
         B, H, W, C = batch["x"].shape
         assert (H, W) == (cfg.H, cfg.W), ((H, W), (cfg.H, cfg.W))
-        assert cond_mask.shape == (B,), (cond_mask.shape, B)
+        if cond_mask.ndim != 1 or B % cond_mask.shape[0]:
+            raise ValueError(
+                f"cond_mask {cond_mask.shape}: the conditioning rows must "
+                f"divide the {B} examples of x / z")
+        G = cond_mask.shape[0]
+        for k in ("logsnr", "R", "t", "K", "cam_dirs"):
+            if k in batch and batch[k].shape[0] != G:
+                raise ValueError(
+                    f"batch[{k!r}] has {batch[k].shape[0]} rows, cond_mask "
+                    f"has {G}: conditioning inputs share one leading "
+                    "dimension")
+        # once per trace, like the compile.* events
+        count("conditioning.groups", G)
+        count("conditioning.examples", B)
 
         num_res = cfg.num_resolutions
         dim_out = [cfg.ch * m for m in cfg.ch_mult]
@@ -77,7 +101,7 @@ class XUNet(nn.Module):
             name="conditioningprocessor")(batch, cond_mask)
 
         def level_emb(i):
-            # [B, F, 1, 1, emb_ch] + [B, F, h, w, emb_ch]
+            # [G, F, 1, 1, emb_ch] + [G, F, h, w, emb_ch]
             with scope("conditioning"):
                 return logsnr_emb[:, :, None, None, :] + pose_embs[i]
 

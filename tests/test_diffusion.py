@@ -1,6 +1,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from diff3d_tpu.diffusion import (alpha_sigma, logsnr_schedule_cosine,
                                   make_model_batch, p_losses,
@@ -167,3 +168,42 @@ def test_sample_loop_jits():
         w=jnp.arange(B, dtype=jnp.float32), rng=rng, timesteps=3))
     out = f(jax.random.PRNGKey(1))
     assert out.shape == (B, H, W, 3)
+
+
+@pytest.mark.parametrize("hoist", [True, False])
+def test_sample_loop_scan_passes_two_conditioning_rows(hoist):
+    """The guidance weights of a view share one pose and one logSNR: the
+    scan hands the model `x`, `z` at 2B rows and every conditioning input
+    at 2 (the conditional row, then the unconditional one), un-broadcast —
+    the model's G-divides-B contract does the sharing."""
+    from diff3d_tpu.diffusion.core import (sample_loop_prepare,
+                                           sample_loop_scan)
+
+    B, H, W, N = 3, 8, 8, 4
+    seen = {}
+
+    def spy(batch, cond_mask):
+        seen.update({k: v.shape for k, v in batch.items()},
+                    cond_mask=cond_mask.shape, mask=cond_mask)
+        return jnp.zeros_like(batch["z"])
+
+    rec_R = jnp.stack([jnp.eye(3) * (i + 1) for i in range(N)])
+    state, xs = sample_loop_prepare(
+        record_len=jnp.array(1), rng=jax.random.PRNGKey(0), timesteps=2,
+        shape=(B, H, W, 3), logsnr_min=-20.0, logsnr_max=20.0)
+    out = sample_loop_scan(
+        spy, state, xs, record_imgs=jnp.zeros((N, B, H, W, 3)),
+        record_R=rec_R, record_T=jnp.zeros((N, 3)),
+        target_R=jnp.eye(3) * 7, target_T=jnp.ones(3), K=jnp.eye(3),
+        w=jnp.arange(B, dtype=jnp.float32), logsnr_max=20.0, clip_x0=True,
+        hoist_cond=hoist)
+    assert out.img.shape == (B, H, W, 3)
+    assert seen["x"] == seen["z"] == (2 * B, H, W, 3)
+    assert seen["logsnr"] == (2, 2)
+    assert seen["R"] == (2, 2, 3, 3)
+    assert seen["t"] == (2, 2, 3)
+    assert seen["K"] == (2, 3, 3)
+    assert seen["cond_mask"] == (2,)
+    assert seen.get("cam_dirs") == ((2, 1, H, W, 3) if hoist else None)
+    # the mask is a trace-time constant: row 0 conditional, row 1 not
+    assert np.asarray(seen["mask"]).tolist() == [True, False]

@@ -310,3 +310,103 @@ def test_attn_impl_levels_override():
     with pytest.raises(ValueError, match="invalid"):
         tiny_cfg(attn_impl_levels=("xla", "bogus", "xla",
                                    "xla")).validate()
+
+
+# --------------------------------------------------------------------------
+# grouped conditioning: G conditioning rows for B examples, G | B
+# --------------------------------------------------------------------------
+
+_COND_KEYS = ("logsnr", "R", "t", "K")
+
+
+@functools.lru_cache(maxsize=None)
+def _grouped_model():
+    """The `test`-config model with non-trivial weights (zero-init head
+    and second convs would make every output 0), and its jitted apply."""
+    from diff3d_tpu.config import test_config as make_tiny_config
+
+    cfg = make_tiny_config().model
+    model = XUNet(cfg)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0),
+                                 make_batch(1, cfg.H, cfg.W),
+                                 cond_mask=jnp.ones(1, bool))["params"]
+    leaves, tree = jax.tree_util.tree_flatten(params)
+    keys = jax.random.split(jax.random.PRNGKey(1), len(leaves))
+    params = jax.tree_util.tree_unflatten(tree, [
+        p + 0.02 * jax.random.normal(k, p.shape, p.dtype)
+        for p, k in zip(leaves, keys)])
+    return model, params
+
+
+def _apply(model, params, batch, mask):
+    return jax.jit(lambda p, b, m: model.apply({"params": p}, b,
+                                               cond_mask=m))(
+        params, batch, mask)
+
+
+def _grouped_case(G, n):
+    """A batch whose conditioning has G rows for B = G*n examples, and the
+    same batch with those rows repeated to B (group-major)."""
+    model, params = _grouped_model()
+    B = G * n
+    full = make_batch(B, model.cfg.H, model.cfg.W, key=3)
+    rows = make_batch(G, model.cfg.H, model.cfg.W, key=4)
+    # a per-row rotation, so that R is not the same in every row either
+    ang = jnp.linspace(0.1, 1.0, G * 2).reshape(G, 2)
+    c, s, o, l = jnp.cos(ang), jnp.sin(ang), jnp.zeros_like(ang), \
+        jnp.ones_like(ang)
+    rows["R"] = jnp.stack([c, -s, o, s, c, o, o, o, l], -1).reshape(
+        G, 2, 3, 3)
+    grouped = dict(full, **{k: rows[k] for k in _COND_KEYS})
+    repeated = dict(full, **{k: jnp.repeat(rows[k], n, axis=0)
+                             for k in _COND_KEYS})
+    mask = jnp.arange(G) % 2 == 0          # mixed wherever G > 1
+    return model, params, grouped, repeated, mask
+
+
+@pytest.mark.parametrize("G,n", [(1, 4), (2, 4), (4, 1)])
+def test_grouped_conditioning_equals_repeated_rows(G, n):
+    """Conditioning at G rows == the same rows repeated to B = G*n
+    (example b reads row b // n), with a mixed cond_mask."""
+    model, params, grouped, repeated, mask = _grouped_case(G, n)
+    out_g = _apply(model, params, grouped, mask)
+    out_r = _apply(model, params, repeated, jnp.repeat(mask, n))
+    assert out_g.shape == (G * n, 16, 16, 3)
+    assert float(jnp.max(jnp.abs(out_r))) > 1e-2        # not vacuous
+    # Not bitwise: the FiLM dense and the level convs run at another
+    # batch size, where the CPU's matmul orders its sums differently, and
+    # 19 blocks of GroupNorm carry that rounding to the output (read:
+    # 1.3e-6 on outputs of 0.5-0.7; computed in float64 the two agree to
+    # the last float32 bit).  1e-5 is eight times that and far below any
+    # wrong row (the swap below moves the output by > 1e-3).
+    np.testing.assert_allclose(np.asarray(out_g), np.asarray(out_r),
+                               atol=1e-5, rtol=1e-5)
+    if G > 1:   # and the rows do differ: row order matters
+        swapped = dict(grouped, **{k: grouped[k][::-1] for k in _COND_KEYS})
+        out_s = _apply(model, params, swapped, mask[::-1])
+        assert float(jnp.max(jnp.abs(out_s - out_g))) > 1e-3
+
+
+def test_grouped_conditioning_rejects_indivisible_batch():
+    model, params, grouped, _, mask = _grouped_case(2, 4)
+    odd = dict(grouped, x=grouped["x"][:7], z=grouped["z"][:7])
+    with pytest.raises(ValueError, match="divide"):
+        model.apply({"params": params}, odd, cond_mask=mask)
+    # conditioning inputs that disagree on G among themselves
+    with pytest.raises(ValueError, match="rows"):
+        model.apply({"params": params},
+                    dict(grouped, K=jnp.repeat(grouped["K"], 4, axis=0)),
+                    cond_mask=mask)
+
+
+def test_grouped_conditioning_pallas_interpret_agrees():
+    """The fused GroupNorm->FiLM kernel (interpret mode on the CPU) gets
+    scale/shift broadcast from G rows and agrees with the XLA path."""
+    import dataclasses
+
+    model_x, params, grouped, _, mask = _grouped_case(2, 4)
+    model_p = XUNet(dataclasses.replace(model_x.cfg, kernels="pallas"))
+    out_x = _apply(model_x, params, grouped, mask)
+    out_p = _apply(model_p, params, grouped, mask)
+    np.testing.assert_allclose(np.asarray(out_p), np.asarray(out_x),
+                               atol=1e-5, rtol=1e-5)

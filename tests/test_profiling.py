@@ -235,13 +235,17 @@ def programs():
              "R": jnp.zeros((B, 2, 3, 3)), "T": jnp.zeros((B, 2, 3)),
              "K": jnp.zeros((B, 3, 3))}
 
-    def lower():
+    def lower_train():
         step = make_train_step(model, cfg, env)
         state = create_train_state(params, cfg.train)
-        return (step.lower(state, batch, jax.random.PRNGKey(0)),
-                Sampler(model, params, cfg).lower_step_many(2, 2))
+        return step.lower(state, batch, jax.random.PRNGKey(0))
 
-    return {"cfg": cfg, "model": model, "params": params, "lower": lower}
+    def lower_view():
+        return Sampler(model, params, cfg).lower_step_many(2, 2)
+
+    return {"cfg": cfg, "model": model, "params": params,
+            "lower_train": lower_train, "lower_view": lower_view,
+            "lower": lambda: (lower_train(), lower_view())}
 
 
 def test_every_class_is_in_the_compiled_programs_and_few_ops_have_none(
@@ -309,6 +313,26 @@ def test_scopes_are_metadata_only(programs, monkeypatch):
         out_off = forward()
     assert with_scopes == without
     assert out.tobytes() == out_off.tobytes()
+
+
+def test_conditioning_counters_read_the_sharing_of_each_program(programs):
+    """`conditioning.groups` / `.examples` are added once per trace of the
+    model: 8 guidance weights share a row in the sampler's view program
+    (G = 2 rows for 16 examples per object), nothing is shared in the
+    train step (a pose, a logSNR and a mask draw per example)."""
+    def traced(lower):
+        before = RECORDER.counters()
+        lower()
+        after = RECORDER.counters()
+        return tuple(after.get(k, 0) - before.get(k, 0)
+                     for k in ("conditioning.groups",
+                               "conditioning.examples"))
+
+    groups, examples = traced(programs["lower_train"])
+    assert groups > 0 and examples == groups            # 1 example a row
+    n_w = len(programs["cfg"].diffusion.guidance_weights)
+    groups, examples = traced(programs["lower_view"])
+    assert (groups, examples) == (2, 2 * n_w) and examples // groups == 8
 
 
 # ------------------------------------------------- spans of the two loops
