@@ -99,7 +99,7 @@ def _run(global_batch: int, n_steps: int, accum: int = 1,
 
     from diff3d_tpu.config import srn64_config, srn128_config
     from diff3d_tpu.data import InfiniteLoader, SyntheticDataset
-    from diff3d_tpu.models import XUNet
+    from diff3d_tpu.models import build_model
     from diff3d_tpu.parallel import make_mesh
     from diff3d_tpu.train import create_train_state, make_train_step
     from diff3d_tpu.train.trainer import init_params
@@ -115,7 +115,7 @@ def _run(global_batch: int, n_steps: int, accum: int = 1,
                                   accum_steps=accum))
 
     env = make_mesh(cfg.mesh)
-    model = XUNet(cfg.model)
+    model = build_model(cfg)
     rng = jax.random.PRNGKey(0)
     state = create_train_state(init_params(model, cfg, rng), cfg.train)
     state = jax.device_put(state, env.state_shardings(state))
@@ -297,7 +297,7 @@ def _sampler_bench(config: str = "srn64", n_views: int = 4,
     import numpy as np
 
     from diff3d_tpu.config import srn64_config, srn128_config
-    from diff3d_tpu.models import XUNet
+    from diff3d_tpu.models import build_model
     from diff3d_tpu.parallel import make_mesh
     from diff3d_tpu.sampling.runtime import Sampler
     from diff3d_tpu.train.trainer import init_params
@@ -306,7 +306,7 @@ def _sampler_bench(config: str = "srn64", n_views: int = 4,
     if kernels is not None:
         cfg = dataclasses.replace(
             cfg, model=dataclasses.replace(cfg.model, kernels=kernels))
-    model = XUNet(cfg.model)
+    model = build_model(cfg)
     rng = jax.random.PRNGKey(0)
     # srn128 full width: the 256-step scan is split into 4 device
     # executions (bit-identical result, test_sampling pins it; chunks=1
@@ -449,7 +449,7 @@ def _cascade_bench(config: str = "srn128", n_views: int = 2,
 
     from diff3d_tpu.cascade import CascadePlan, CascadeSampler
     from diff3d_tpu.config import srn64_config, srn128_config
-    from diff3d_tpu.models import XUNet
+    from diff3d_tpu.models import build_model
     from diff3d_tpu.sampling.runtime import Sampler
     from diff3d_tpu.train.trainer import init_params
 
@@ -460,7 +460,7 @@ def _cascade_bench(config: str = "srn128", n_views: int = 2,
                      f"refine={H}:ancestral:64@t0.5")
     plan = CascadePlan.parse(plan_spec)
     rng = jax.random.PRNGKey(0)
-    model = XUNet(cfg.model)
+    model = build_model(cfg)
     params = init_params(model, cfg, rng)
     cascade = CascadeSampler(model, params, cfg, plan)
     single = Sampler(model, params, cfg)

@@ -195,12 +195,12 @@ def phase_sample(clock, params) -> None:
     import numpy as np
 
     from diff3d_tpu.config import srn64_config
-    from diff3d_tpu.models import XUNet
+    from diff3d_tpu.models import build_model
     from diff3d_tpu.sampling import Sampler
 
     with phase("sample", clock) as out:
         cfg = srn64_config()
-        sampler = Sampler(XUNet(cfg.model), params, cfg)
+        sampler = Sampler(build_model(cfg), params, cfg)
         views = synthetic_views(obj=0, n_views=2)
         imgs = sampler.synthesize(views, jax.random.PRNGKey(SEED),
                                   max_views=2)
@@ -403,14 +403,14 @@ def phase_fsdp_train(clock, devices) -> None:
     import numpy as np
 
     from diff3d_tpu.data import InfiniteLoader, SyntheticDataset
-    from diff3d_tpu.models import XUNet
+    from diff3d_tpu.models import build_model
     from diff3d_tpu.parallel import make_mesh
     from diff3d_tpu.train import create_train_state, make_train_step
     from diff3d_tpu.train.trainer import init_params
 
     with phase("fsdp_train_x4", clock) as out:
         cfg = train_cfg(train_argv(FOUR_BATCH, "--param_sharding", "fsdp"))
-        model = XUNet(cfg.model)
+        model = build_model(cfg)
         rng = jax.random.PRNGKey(cfg.train.seed)
         ds = SyntheticDataset(num_objects=64, num_views=32, imgsize=64)
         loader = InfiniteLoader(ds, FOUR_BATCH, seed=SEED, num_workers=0)
@@ -465,14 +465,14 @@ def phase_sharded_sampler(clock, devices) -> None:
     import numpy as np
 
     from diff3d_tpu.config import srn64_config
-    from diff3d_tpu.models import XUNet
+    from diff3d_tpu.models import build_model
     from diff3d_tpu.parallel import make_mesh
     from diff3d_tpu.sampling import Sampler, record_capacity
     from diff3d_tpu.train.trainer import init_params
 
     with phase("sharded_sampler_x4", clock) as out:
         cfg = srn64_config()
-        model = XUNet(cfg.model)
+        model = build_model(cfg)
         params = init_params(model, cfg, jax.random.PRNGKey(SEED))
         n_w = len(cfg.diffusion.guidance_weights)
         cap = record_capacity(2)

@@ -579,12 +579,12 @@ def build_train_step_events() -> List[str]:
     import jax.numpy as jnp
 
     from diff3d_tpu.analysis import shardcheck
-    from diff3d_tpu.models import XUNet
+    from diff3d_tpu.models import build_model
     from diff3d_tpu.train import make_train_step
 
     cfg = shardcheck._train_cfg()
     env = shardcheck._fsdp_mesh()
-    model = XUNet(cfg.model)
+    model = build_model(cfg)
     state = shardcheck._abstract_state(model, cfg)
     batch = shardcheck._abstract_batch(cfg)
     rng = jax.ShapeDtypeStruct((2,), jnp.uint32)
@@ -597,12 +597,12 @@ def build_distill_step_events() -> List[str]:
     import jax.numpy as jnp
 
     from diff3d_tpu.analysis import shardcheck
-    from diff3d_tpu.models import XUNet
+    from diff3d_tpu.models import build_model
     from diff3d_tpu.train.distill import make_distill_step
 
     cfg = shardcheck._train_cfg()
     env = shardcheck._fsdp_mesh()
-    model = XUNet(cfg.model)
+    model = build_model(cfg)
     state = shardcheck._abstract_state(model, cfg)
     batch = shardcheck._abstract_batch(cfg)
     rng = jax.ShapeDtypeStruct((2,), jnp.uint32)

@@ -124,12 +124,12 @@ def build_train_step_report(name: str = "train_step") -> "ir.ProgramReport":
     import jax
     import jax.numpy as jnp
 
-    from diff3d_tpu.models import XUNet
+    from diff3d_tpu.models import build_model
     from diff3d_tpu.train import make_train_step
 
     cfg = _train_cfg()
     env = _fsdp_mesh()
-    model = XUNet(cfg.model)
+    model = build_model(cfg)
     state = _abstract_state(model, cfg)
     batch = _abstract_batch(cfg)
     rng = jax.ShapeDtypeStruct((2,), jnp.uint32)
@@ -146,12 +146,12 @@ def build_distill_step_report(
     import jax
     import jax.numpy as jnp
 
-    from diff3d_tpu.models import XUNet
+    from diff3d_tpu.models import build_model
     from diff3d_tpu.train.distill import make_distill_step
 
     cfg = _train_cfg()
     env = _fsdp_mesh()
-    model = XUNet(cfg.model)
+    model = build_model(cfg)
     state = _abstract_state(model, cfg)
     batch = _abstract_batch(cfg)
     rng = jax.ShapeDtypeStruct((2,), jnp.uint32)
@@ -172,7 +172,7 @@ def _sampler(sampler_kind: str = "ancestral",
     import jax
 
     from diff3d_tpu.config import test_config
-    from diff3d_tpu.models import XUNet
+    from diff3d_tpu.models import build_model
     from diff3d_tpu.sampling import Sampler
     from diff3d_tpu.train.trainer import init_params
 
@@ -181,7 +181,7 @@ def _sampler(sampler_kind: str = "ancestral",
         cfg = dataclasses.replace(
             cfg, model=dataclasses.replace(cfg.model, kernels=kernels))
     env = _fsdp_mesh()
-    model = XUNet(cfg.model)
+    model = build_model(cfg)
     params = init_params(model, cfg, jax.random.PRNGKey(0))
     return Sampler(model, params, cfg, mesh=env,
                    sampler_kind=sampler_kind, steps=steps), env
@@ -243,12 +243,12 @@ def _cascade():
 
     from diff3d_tpu.cascade import CascadePlan, CascadeSampler
     from diff3d_tpu.config import test_config
-    from diff3d_tpu.models import XUNet
+    from diff3d_tpu.models import build_model
     from diff3d_tpu.train.trainer import init_params
 
     cfg = test_config(imgsize=16, ch=8)
     env = _fsdp_mesh()
-    model = XUNet(cfg.model)
+    model = build_model(cfg)
     params = init_params(model, cfg, jax.random.PRNGKey(0))
     plan = CascadePlan.parse("draft=8:ddim:2,refine=16:ancestral:2@t0.5")
     return CascadeSampler(model, params, cfg, plan, mesh=env), env

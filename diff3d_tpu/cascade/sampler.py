@@ -31,7 +31,7 @@ import numpy as np
 from diff3d_tpu.cascade.plan import CascadePlan
 from diff3d_tpu.config import Config
 from diff3d_tpu.convert.progressive import adapt_params_resolution
-from diff3d_tpu.models import XUNet
+from diff3d_tpu.models import XUNet, build_xunet
 from diff3d_tpu.sampling import Sampler
 
 
@@ -80,6 +80,9 @@ class CascadeSampler:
 
     def __init__(self, model: XUNet, params, cfg: Config,
                  plan: CascadePlan, *, mesh=None, draft_params=None):
+        # the draft is the same X-UNet at another resolution
+        # (adapt_params_resolution): no other denoiser has that
+        build_xunet(cfg, "CascadeSampler")
         if (cfg.model.H, cfg.model.W) != (plan.refine.resolution,) * 2:
             raise ValueError(
                 f"cfg.model is {cfg.model.H}x{cfg.model.W} but the plan "
@@ -93,7 +96,7 @@ class CascadeSampler:
         if draft_params is None:
             draft_params = adapt_params_resolution(params, (dr, dr))
         self.draft = Sampler(
-            XUNet(self.draft_cfg.model), draft_params, self.draft_cfg,
+            build_xunet(self.draft_cfg, "CascadeSampler"), draft_params, self.draft_cfg,
             mesh=mesh, sampler_kind=plan.draft.sampler_kind,
             steps=plan.draft.steps)
         self.refine = Sampler(

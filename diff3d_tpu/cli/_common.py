@@ -45,16 +45,16 @@ def apply_model_width_overrides(cfg, args):
 
 def build_abstract_state(cfg):
     """Abstract TrainState template (ShapeDtypeStructs, nothing
-    materialised) for ``XUNet(cfg.model)`` — the restore target every
+    materialised) for ``build_model(cfg)`` — the restore target every
     checkpoint-consuming CLI needs.  ``jax.eval_shape`` means no params,
     moments, or EMA are ever allocated just to describe the tree."""
     import jax
 
-    from diff3d_tpu.models import XUNet
+    from diff3d_tpu.models import build_model
     from diff3d_tpu.train import create_train_state
     from diff3d_tpu.train.trainer import init_params
 
-    model = XUNet(cfg.model)
+    model = build_model(cfg)
     return jax.eval_shape(lambda: create_train_state(
         init_params(model, cfg, jax.random.PRNGKey(0)), cfg.train))
 

@@ -25,7 +25,8 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--torch_ckpt", required=True, help="reference .pt file")
     p.add_argument("--out", required=True,
                    help="Orbax checkpoint root to write")
-    p.add_argument("--config", choices=["srn64", "srn128", "test"],
+    p.add_argument("--config",
+                   choices=["srn64", "srn128", "test", "token_test"],
                    default="srn64")
     p.add_argument("--step", type=int, default=None,
                    help="override the step recorded in the checkpoint")
@@ -53,9 +54,10 @@ def main(argv=None) -> None:
     from diff3d_tpu.train import CheckpointManager, create_train_state
     from diff3d_tpu.train.state import advance_schedule
 
-    cfg = {"srn64": config_lib.srn64_config,
-           "srn128": config_lib.srn128_config,
-           "test": config_lib.test_config}[args.config]()
+    cfg = config_lib.named_config(args.config)
+    # the reference's checkpoints are X-UNets: refuse another denoiser
+    from diff3d_tpu.models import build_xunet
+    model = build_xunet(cfg, "convert_cli")
 
     # Verify the INPUT key set first (torch keys + shapes reconstructed
     # from config): the real published .pt deserves a complete report of
@@ -94,10 +96,9 @@ def main(argv=None) -> None:
     # Fail fast on config/checkpoint mismatch (e.g. a 64px .pt converted
     # with --config srn128): compare against the model's expected tree
     # BEFORE writing a checkpoint that would only blow up at restore time.
-    from diff3d_tpu.models import XUNet
     from diff3d_tpu.train.trainer import init_params as _init_params
     expected = jax.eval_shape(
-        lambda: _init_params(XUNet(cfg.model), cfg, jax.random.PRNGKey(0)))
+        lambda: _init_params(model, cfg, jax.random.PRNGKey(0)))
     exp_flat = dict(jax.tree_util.tree_flatten_with_path(expected)[0])
     got_flat = dict(jax.tree_util.tree_flatten_with_path(params)[0])
     missing = exp_flat.keys() - got_flat.keys()

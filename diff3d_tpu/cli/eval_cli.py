@@ -88,7 +88,8 @@ def build_parser() -> argparse.ArgumentParser:
                         "--object_batch rounds up to the data-axis size")
     add_model_width_args(p)
     p.add_argument("--picklefile", default=None)
-    p.add_argument("--config", choices=["srn64", "srn128", "test"],
+    p.add_argument("--config",
+                   choices=["srn64", "srn128", "test", "token_test"],
                    default="srn64")
     p.add_argument("--objects", type=int, default=8,
                    help="number of val objects to evaluate")
@@ -239,12 +240,10 @@ def main(argv=None) -> None:
     from diff3d_tpu.evaluation import (fid_from_stats, gaussian_stats, psnr,
                                        ssim)
     from diff3d_tpu.evaluation.features import resolve_feature_fn
-    from diff3d_tpu.models import XUNet
+    from diff3d_tpu.models import build_model
     from diff3d_tpu.sampling import Sampler
 
-    cfg = {"srn64": config_lib.srn64_config,
-           "srn128": config_lib.srn128_config,
-           "test": config_lib.test_config}[args.config]()
+    cfg = config_lib.named_config(args.config)
     if args.steps:
         cfg = dataclasses.replace(
             cfg, diffusion=dataclasses.replace(cfg.diffusion,
@@ -257,7 +256,7 @@ def main(argv=None) -> None:
     feature_fn, fid_key = resolve_feature_fn(args.feature_weights)
     feature_fn = jax.jit(feature_fn)
 
-    model = XUNet(cfg.model)
+    model = build_model(cfg)
     try:
         step, params = load_eval_params(args.model,
                                         build_abstract_state(cfg),

@@ -29,7 +29,8 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--target", required=True,
                    help="SRN object dir with rgb/ pose/ intrinsics/")
     p.add_argument("--out", default="sampling")
-    p.add_argument("--config", choices=["srn64", "srn128", "test"],
+    p.add_argument("--config",
+                   choices=["srn64", "srn128", "test", "token_test"],
                    default="srn64")
     p.add_argument("--steps", type=int, default=None,
                    help="diffusion steps (reference: 256)")
@@ -59,19 +60,17 @@ def main(argv=None) -> None:
 
     from diff3d_tpu import config as config_lib
     from diff3d_tpu.data.srn import load_object_views
-    from diff3d_tpu.models import XUNet
+    from diff3d_tpu.models import build_model
     from diff3d_tpu.sampling import Sampler
 
-    cfg = {"srn64": config_lib.srn64_config,
-           "srn128": config_lib.srn128_config,
-           "test": config_lib.test_config}[args.config]()
+    cfg = config_lib.named_config(args.config)
     if args.steps:
         cfg = dataclasses.replace(
             cfg, diffusion=dataclasses.replace(cfg.diffusion,
                                                timesteps=args.steps))
     cfg = apply_model_width_overrides(cfg, args)
 
-    model = XUNet(cfg.model)
+    model = build_model(cfg)
     try:
         step, params = load_eval_params(args.model,
                                         build_abstract_state(cfg),

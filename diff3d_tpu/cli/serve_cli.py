@@ -41,7 +41,8 @@ def build_parser() -> argparse.ArgumentParser:
                    help="'random' serves freshly initialised params — "
                         "for smoke tests and load benches, no --model "
                         "needed")
-    p.add_argument("--config", choices=["srn64", "srn128", "test"],
+    p.add_argument("--config",
+                   choices=["srn64", "srn128", "test", "token_test"],
                    default="srn64")
     p.add_argument("--host", default=None,
                    help="bind address (default: config, 127.0.0.1)")
@@ -147,14 +148,14 @@ def build_service(args):
     import jax
 
     from diff3d_tpu import config as config_lib
-    from diff3d_tpu.models import XUNet
+    from diff3d_tpu.models import build_xunet
     from diff3d_tpu.sampling import Sampler, record_capacity
     from diff3d_tpu.serving import FleetService, ServingService
     from diff3d_tpu.serving.fleet import build_fleet
 
-    cfg = {"srn64": config_lib.srn64_config,
-           "srn128": config_lib.srn128_config,
-           "test": config_lib.test_config}[args.config]()
+    cfg = config_lib.named_config(args.config)
+    # serving is the X-UNet's: refuse another denoiser before anything loads
+    build_xunet(cfg, "serve_cli")
     if args.steps:
         cfg = dataclasses.replace(
             cfg, diffusion=dataclasses.replace(cfg.diffusion,
@@ -223,7 +224,7 @@ def build_service(args):
                      len(worker_addrs))
         return FleetService(_remotes(), cfg)
 
-    model = XUNet(cfg.model)
+    model = build_xunet(cfg, "serve_cli")
     if args.init == "random":
         from diff3d_tpu.train.trainer import init_params
 

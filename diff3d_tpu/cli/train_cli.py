@@ -51,7 +51,8 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--picklefile", default=None,
                    help="object index pickle; regenerated by globbing when "
                         "absent")
-    p.add_argument("--config", choices=["srn64", "srn128", "test"],
+    p.add_argument("--config",
+                   choices=["srn64", "srn128", "test", "token_test"],
                    default="srn64")
     p.add_argument("--batch", type=int, default=None,
                    help="global batch (default from config; reference runs "
@@ -177,9 +178,7 @@ def build_config(args):
     this CLI wrote (``Trainer(..., transfer=True)``) rebuilds it here."""
     from diff3d_tpu import config as config_lib
 
-    cfg = {"srn64": config_lib.srn64_config,
-           "srn128": config_lib.srn128_config,
-           "test": config_lib.test_config}[args.config]()
+    cfg = config_lib.named_config(args.config)
     train_over = {}
     if args.batch is not None:
         train_over["global_batch"] = args.batch

@@ -14,7 +14,7 @@ Adam betas (0.9, 0.99), EMA half-life 500K examples.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Sequence
+from typing import Optional, Sequence, Tuple, Union
 
 
 @dataclasses.dataclass(frozen=True)
@@ -112,6 +112,91 @@ class ModelConfig:
                 if not _impl_ok(impl):
                     raise ValueError(
                         f"attn_impl_levels entry {impl!r} invalid")
+
+
+@dataclasses.dataclass(frozen=True)
+class TokenModelConfig:
+    """The token denoiser (``models/token_denoiser.py``): both frames as
+    one sequence of ``patch x patch`` patches through pre-norm decoder
+    layers of grouped-query attention over the keys a lightning indexer
+    selects, and routed experts.  Field names follow the public language
+    model configs such blocks come from (``hidden_size``, ``num_experts``,
+    ...), so a benchmark configuration file maps onto this one key for
+    key; the defaults are the widths of ``benchmark/configs/
+    keye_vl2_tok128.json``.
+
+    ``experts_held`` is ``(first, count)``: the router scores all
+    ``num_experts``, the layer holds the weights of experts ``first ..
+    first + count - 1`` and computes their part of the result (all of
+    them on one chip; a share of them where experts are spread over
+    chips)."""
+
+    H: int = 128
+    W: int = 128
+    patch: int = 2
+    hidden_size: int = 2048
+    num_hidden_layers: int = 4
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 4
+    head_dim: int = 128
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 1e7
+    # frequency pairs of a head rotated by (frame, patch row, patch column)
+    mrope_section: Sequence[int] = (16, 24, 24)
+    num_experts: int = 128
+    num_experts_per_tok: int = 8
+    moe_intermediate_size: int = 768
+    experts_held: Tuple[int, int] = (0, 128)
+    indexer_num_heads: int = 16
+    indexer_head_dim: int = 64
+    indexer_topk: int = 2048
+    # Tile sizes, not semantics: queries of one example attended at a
+    # time; tokens routed at a time; rows of one expert's matmul.
+    q_chunk: int = 512
+    expert_token_chunk: int = 8192
+    expert_block: int = 256
+    emb_ch: int = 256              # width of the logSNR sinusoid
+    logsnr_clip: float = 20.0      # as ModelConfig.logsnr_clip
+    dtype: str = "bfloat16"        # compute dtype; params stay float32
+
+    @property
+    def tokens(self) -> int:
+        return 2 * (self.H // self.patch) * (self.W // self.patch)
+
+    def validate(self) -> None:
+        if self.H % self.patch or self.W % self.patch:
+            raise ValueError(
+                f"H={self.H}, W={self.W} must be divisible by "
+                f"patch={self.patch}")
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError(
+                f"num_key_value_heads={self.num_key_value_heads} must "
+                f"divide num_attention_heads={self.num_attention_heads}")
+        sec = tuple(self.mrope_section)
+        if len(sec) != 3 or 2 * sum(sec) != self.head_dim:
+            raise ValueError(
+                f"mrope_section={sec} must be three counts of frequency "
+                f"pairs summing to head_dim/2 = {self.head_dim // 2}")
+        if any(s % 2 for s in sec) or 2 * self.indexer_head_dim != self.head_dim:
+            raise ValueError(
+                "the indexer rotates by half the section sizes: "
+                f"mrope_section={sec} must be even and indexer_head_dim="
+                f"{self.indexer_head_dim} half of head_dim={self.head_dim}")
+        first, count = self.experts_held
+        if first < 0 or count < 1 or first + count > self.num_experts:
+            raise ValueError(
+                f"experts_held={self.experts_held} is not a range of the "
+                f"{self.num_experts} experts")
+        if not 1 <= self.num_experts_per_tok <= self.num_experts:
+            raise ValueError(
+                f"num_experts_per_tok={self.num_experts_per_tok} not in "
+                f"1..{self.num_experts}")
+        if self.indexer_topk < 1:
+            raise ValueError(f"indexer_topk={self.indexer_topk} must be >= 1")
+        if self.tokens % min(self.q_chunk, self.tokens):
+            raise ValueError(
+                f"q_chunk={self.q_chunk} must divide the {self.tokens} "
+                "tokens of an example (or exceed them)")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -335,7 +420,10 @@ class ServingConfig:
 
 @dataclasses.dataclass(frozen=True)
 class Config:
-    model: ModelConfig = dataclasses.field(default_factory=ModelConfig)
+    # The kind of denoiser is the type of this field, read in one place:
+    # diff3d_tpu.models.build_model.
+    model: Union[ModelConfig, TokenModelConfig] = dataclasses.field(
+        default_factory=ModelConfig)
     diffusion: DiffusionConfig = dataclasses.field(default_factory=DiffusionConfig)
     train: TrainConfig = dataclasses.field(default_factory=TrainConfig)
     data: DataConfig = dataclasses.field(default_factory=DataConfig)
@@ -375,6 +463,29 @@ def srn128_config() -> Config:
     return Config(model=ModelConfig(H=128, W=128, ch=256, remat=True))
 
 
+def token_test_config(imgsize: int = 16) -> Config:
+    """Tiny token-denoiser config for unit tests and CPU drives: hidden
+    64, 8 experts top-2, 2 layers, the indexer keeping a quarter of the
+    ``2 * (imgsize / 2)^2`` keys (32 of 128 at 16x16), as the full-width
+    configuration keeps 2048 of 8192."""
+    tokens = 2 * (imgsize // 2) ** 2
+    return Config(
+        model=TokenModelConfig(
+            H=imgsize, W=imgsize, hidden_size=64, num_hidden_layers=2,
+            num_attention_heads=4, num_key_value_heads=2, head_dim=32,
+            mrope_section=(4, 6, 6), num_experts=8, num_experts_per_tok=2,
+            moe_intermediate_size=32, experts_held=(0, 8),
+            indexer_num_heads=4, indexer_head_dim=16,
+            indexer_topk=tokens // 4, q_chunk=tokens // 2,
+            expert_token_chunk=4 * tokens, expert_block=16, emb_ch=32,
+            dtype="float32"),
+        train=TrainConfig(global_batch=8, warmup_examples=1024,
+                          max_steps=4, ckpt_every=2, log_every=1),
+        data=DataConfig(imgsize=imgsize),
+        diffusion=DiffusionConfig(timesteps=4),
+    )
+
+
 def test_config(imgsize: int = 16, ch: int = 8,
                 shallow: bool = False) -> Config:
     """Tiny config for unit tests / CPU-mesh dry runs.
@@ -396,3 +507,15 @@ def test_config(imgsize: int = 16, ch: int = 8,
         data=DataConfig(imgsize=imgsize),
         diffusion=DiffusionConfig(timesteps=4),
     )
+
+
+#: The presets the CLIs' ``--config`` names, by the name of the function
+#: in this module that builds each.
+NAMED_CONFIGS = {"srn64": "srn64_config", "srn128": "srn128_config",
+                 "test": "test_config", "token_test": "token_test_config"}
+
+
+def named_config(name: str) -> Config:
+    """The preset ``name``, built by this module's function of that name
+    as it is bound now (tests stand a patched builder in its place)."""
+    return globals()[NAMED_CONFIGS[name]]()

@@ -27,6 +27,25 @@ DIR_DEG = 8
 POSE_EMB_CH = posenc_nerf_channels(0, POS_DEG) + posenc_nerf_channels(0, DIR_DEG)
 
 
+def conditioning_rows(batch: dict, cond_mask: jnp.ndarray) -> int:
+    """``G`` of the forward contract (docs/DESIGN.md §1), checked: the
+    conditioning inputs and ``cond_mask`` share one leading dimension,
+    and it divides the ``B`` examples of ``x`` / ``z``."""
+    B = batch["x"].shape[0]
+    if cond_mask.ndim != 1 or B % cond_mask.shape[0]:
+        raise ValueError(
+            f"cond_mask {cond_mask.shape}: the conditioning rows must "
+            f"divide the {B} examples of x / z")
+    G = cond_mask.shape[0]
+    for k in ("logsnr", "R", "t", "K", "cam_dirs"):
+        if k in batch and batch[k].shape[0] != G:
+            raise ValueError(
+                f"batch[{k!r}] has {batch[k].shape[0]} rows, cond_mask "
+                f"has {G}: conditioning inputs share one leading "
+                "dimension")
+    return G
+
+
 class ConditioningProcessor(nn.Module):
     """Produces ``(logsnr_emb [G,F,emb_ch], pose_embs[level])`` for the UNet.
 

@@ -1,0 +1,191 @@
+"""Routed experts: a router over all experts, top-k renormalised gates, and
+the part of the result that the experts held here give.
+
+The layer is told which experts it holds (``held = (first, count)``).  It
+routes every token over all ``num_experts``, and computes, for the tokens
+routed to an expert it holds, that expert's gated output; what the absent
+experts would add is left out and nothing stands in for it.  With all
+experts held that is the whole layer; the shares of a set of chips that
+together hold every expert add up to it (tests/test_token_denoiser.py).
+No token is dropped: there is no capacity.
+
+How the experts' matmuls are laid out (:func:`expert_outputs`): the
+``T x k`` assignments of a chunk of ``T`` tokens are sorted by expert and
+each expert's run is padded to a multiple of ``block`` rows, so that
+every block of rows belongs to one expert; a scan over the blocks
+multiplies each by that expert's three matrices, read by a dynamic index
+into the stacked weights (the number of blocks is a static bound, ``T k /
+block + experts``, so a call's time does not depend on how the tokens
+were routed).  Rows come in by one gather and go back by one
+gather and a gated sum over the ``k`` slots; the ``[T x k, D]`` dispatch
+buffer exists for one chunk at a time (:class:`RoutedExperts` maps over
+chunks of ``token_chunk`` tokens).  Everything is plain XLA, the same on
+the CPU and the TPU, differentiable, and indifferent to ``vmap`` (the
+sampler maps its view program over objects).
+"""
+
+from __future__ import annotations
+
+from typing import Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from diff3d_tpu.utils.profiling import scope
+
+
+def rms_norm(x: jnp.ndarray, scale: jnp.ndarray, eps: float) -> jnp.ndarray:
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1,
+                                     keepdims=True) + eps)
+    return (y * scale).astype(x.dtype)
+
+
+def route(logits: jnp.ndarray, k: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``[T, E]`` float32 router logits -> (``[T, k]`` expert ids,
+    ``[T, k]`` float32 gates): softmax over all experts, the ``k``
+    largest, renormalised to sum to one."""
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    gates, ids = jax.lax.top_k(probs, k)
+    return ids, gates / gates.sum(axis=-1, keepdims=True)
+
+
+def expert_outputs(x: jnp.ndarray, ids: jnp.ndarray, gates: jnp.ndarray,
+                   w_gate: jnp.ndarray, w_up: jnp.ndarray,
+                   w_down: jnp.ndarray, *, first: int,
+                   block: int) -> jnp.ndarray:
+    """Gated sum of the held experts' outputs for one chunk of tokens.
+
+    ``x [T, D]`` (compute dtype), ``ids / gates [T, k]`` as :func:`route`
+    gives them (ids over all experts), ``w_gate / w_up [E, D, F]``,
+    ``w_down [E, F, D]`` the held experts ``first .. first + E - 1``, in
+    the compute dtype.
+    Expert ``e``: ``w_down_e (silu(w_gate_e x) * w_up_e x)``.
+    """
+    T, D = x.shape
+    K = ids.shape[1]
+    E = w_gate.shape[0]
+    A, m = T * K, block
+    local = ids.reshape(A) - first
+    # assignments to experts held elsewhere sort last, into bucket E
+    e_of = jnp.where((local >= 0) & (local < E), local, E)
+    order = jnp.argsort(e_of, stable=True)           # sorted -> assignment
+    cnt = (e_of[:, None] == jnp.arange(E)[None, :]).sum(axis=0)
+    before = jnp.arange(E)[:, None] < jnp.arange(E)[None, :]
+    starts = lambda n: (n[:, None] * before).sum(axis=0)  # noqa: E731
+    off = starts(cnt)                                # run starts, sorted
+    padded = -(-cnt // m) * m
+    poff = starts(padded)                            # run starts, padded
+
+    # the padded layout: block b holds rows of one expert only
+    n_blocks = -(-A // m) + E                        # a bound, static
+    e_blk = (jnp.arange(n_blocks)[:, None] * m
+             >= (poff + padded)[None, :]).sum(axis=1)
+    # a block past the last run counts as the last expert's: its rows lie
+    # beyond that run's padding, so none of them is valid
+    e_blk = jnp.minimum(e_blk, E - 1)
+    # per block, then spread over the block's m rows: lookups in the
+    # experts' small tables per row are slow gathers on the TPU
+    spread = lambda a: jnp.repeat(a, m)  # noqa: E731
+    r = jnp.arange(n_blocks * m) - spread(poff[e_blk])
+    valid = r < spread(cnt[e_blk])
+    src = order[jnp.clip(spread(off[e_blk]) + r, 0, A - 1)]
+    token = jnp.where(valid, src // K, T)            # T: the zero row
+    x0 = jnp.concatenate([x, jnp.zeros((1, D), x.dtype)])
+    rows = x0[token].reshape(n_blocks, m, D)
+
+    def one_block(_, inp):
+        xb, e = inp
+        f32 = jnp.float32
+        h = (jax.nn.silu(jnp.dot(xb, w_gate[e], preferred_element_type=f32))
+             * jnp.dot(xb, w_up[e], preferred_element_type=f32))
+        return None, jnp.dot(h.astype(xb.dtype), w_down[e])
+
+    # every block of the static bound is computed: one past the last run
+    # holds zero rows and gives zeros (no bias), and under the sampler's
+    # vmap a ``lax.cond`` that skipped it would run both branches anyway
+    _, ys = jax.lax.scan(one_block, None, (rows, e_blk))
+    # back: assignment a sits at sorted position pos[a], and in the padded
+    # layout a run is shifted as a whole, so the shift is looked up with
+    # a one-hot product, not a gather per assignment
+    pos = jnp.argsort(order)
+    shift = jnp.concatenate([poff - off, jnp.zeros((1,), poff.dtype)])
+    onehot = (e_of[:, None] == jnp.arange(E + 1)[None, :])
+    at = jnp.where(e_of < E,
+                   pos + (onehot * shift[None, :]).sum(axis=1),
+                   n_blocks * m)
+    y0 = jnp.concatenate([ys.reshape(n_blocks * m, D),
+                          jnp.zeros((1, D), ys.dtype)])
+    picked = y0[at].reshape(T, K, D).astype(jnp.float32)
+    return (picked * gates[..., None]).sum(axis=1).astype(x.dtype)
+
+
+class RoutedExperts(nn.Module):
+    """``h [..., D] -> h + experts(norm(h))``: the layer's second half, the
+    held experts' part of it.  Norm, routing, experts and the residual
+    add run on one chunk of ``token_chunk`` tokens at a time, so only the
+    layer's input and output exist at the size of the whole call."""
+
+    num_experts: int
+    top_k: int
+    width: int
+    held: Tuple[int, int]
+    token_chunk: int
+    block: int
+    eps: float = 1e-6
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, h: jnp.ndarray, norm_scale: jnp.ndarray
+                 ) -> jnp.ndarray:
+        D = h.shape[-1]
+        first, held = self.held
+        x = h.reshape(-1, D)
+        T = x.shape[0]
+        chunk = min(self.token_chunk, T)
+        if T % chunk:
+            raise ValueError(
+                f"RoutedExperts: token_chunk={chunk} must divide the "
+                f"{T} tokens of a call")
+
+        init = nn.initializers.variance_scaling(
+            1.0, "fan_in", "normal", in_axis=-2, out_axis=-1, batch_axis=0)
+        router = self.param("router", nn.initializers.lecun_normal(),
+                            (D, self.num_experts))
+        w_gate = self.param("w_gate", init, (held, D, self.width))
+        w_up = self.param("w_up", init, (held, D, self.width))
+        w_down = self.param("w_down", init, (held, self.width, D))
+
+        # The experts' matrices in the compute dtype, made when the layer
+        # runs and dropped after it.  ``tie`` is zero, but it is computed
+        # from this call's tokens: a cast that depends on nothing but the
+        # parameters is lifted by XLA out of the sampler's loop, and a
+        # bf16 copy of every layer's experts (4.8 GB at four layers of
+        # 128) then lives beside the float32 parameters.  Neither
+        # ``optimization_barrier`` nor a cast of the one expert inside
+        # the scan stops that (compiled for the v5e, PR 26).  The token is
+        # made finite first, so that a NaN or inf in it stays that
+        # token's own and does not reach every expert's weights.
+        with scope("experts"):
+            x00 = x[0, 0].astype(jnp.float32)
+            tie = jnp.where(jnp.isfinite(x00), x00, 0.0) * 0.0
+            w_gate, w_up, w_down = ((w + tie).astype(self.dtype)
+                                    for w in (w_gate, w_up, w_down))
+
+        def one_chunk(hc):
+            with scope("residual"):
+                xc = rms_norm(hc, norm_scale, self.eps).astype(self.dtype)
+            with scope("moe_router"):
+                logits = jnp.dot(xc, router.astype(self.dtype),
+                                 preferred_element_type=jnp.float32)
+                ids, gates = route(logits, self.top_k)
+            with scope("experts"):
+                y = expert_outputs(xc, ids, gates, w_gate, w_up, w_down,
+                                   first=first, block=self.block)
+            with scope("residual"):
+                return hc + y.astype(hc.dtype)
+
+        with scope("experts"):
+            y = jax.lax.map(one_chunk, x.reshape(T // chunk, chunk, D))
+            return y.reshape(h.shape)

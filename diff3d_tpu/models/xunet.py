@@ -37,7 +37,8 @@ import flax.linen as nn
 import jax.numpy as jnp
 
 from diff3d_tpu.config import ModelConfig
-from diff3d_tpu.models.conditioning import ConditioningProcessor
+from diff3d_tpu.models.conditioning import (ConditioningProcessor,
+                                            conditioning_rows)
 from diff3d_tpu.models.layers import FrameGroupNorm, ResnetBlock, XUNetBlock
 from diff3d_tpu.utils.profiling import count, scope
 
@@ -60,17 +61,7 @@ class XUNet(nn.Module):
         dtype = jnp.dtype(cfg.dtype)
         B, H, W, C = batch["x"].shape
         assert (H, W) == (cfg.H, cfg.W), ((H, W), (cfg.H, cfg.W))
-        if cond_mask.ndim != 1 or B % cond_mask.shape[0]:
-            raise ValueError(
-                f"cond_mask {cond_mask.shape}: the conditioning rows must "
-                f"divide the {B} examples of x / z")
-        G = cond_mask.shape[0]
-        for k in ("logsnr", "R", "t", "K", "cam_dirs"):
-            if k in batch and batch[k].shape[0] != G:
-                raise ValueError(
-                    f"batch[{k!r}] has {batch[k].shape[0]} rows, cond_mask "
-                    f"has {G}: conditioning inputs share one leading "
-                    "dimension")
+        G = conditioning_rows(batch, cond_mask)
         # once per trace, like the compile.* events
         count("conditioning.groups", G)
         count("conditioning.examples", B)

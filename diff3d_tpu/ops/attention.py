@@ -68,8 +68,17 @@ def _resolve_auto(q: jnp.ndarray) -> str:
 
 
 def sdpa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-         impl: str = "auto") -> jnp.ndarray:
+         impl: str = "auto", keep: jnp.ndarray | None = None) -> jnp.ndarray:
     """Scaled dot-product attention over ``[B, L, H, D]`` tensors.
+
+    ``keep`` (optional ``[B, Lq, Lk]`` bool, shared by all heads) is a
+    selection: the softmax runs over the kept keys alone, as the sparse
+    attention of the token denoiser needs (models/sparse_attention.py).
+    ``k`` / ``v`` may then have fewer heads than ``q`` (grouped queries:
+    their head count divides ``q``'s).  Only the XLA core takes a
+    selection — the Pallas flash kernel and the sequence-parallel cores
+    have no mask operand — so ``keep`` goes with ``impl='auto'|'xla'`` and
+    always runs the XLA core: a dense score tile, masked.
 
     ``impl`` may also name a sequence-parallel core — ``'ring:<axis>'`` or
     ``'ulysses:<axis>'`` — in which case q/k/v are local token shards of a
@@ -80,6 +89,11 @@ def sdpa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     ``shard_map`` whose specs shard the spatial axis.  Everything else
     ('auto' | 'pallas' | 'xla') goes through the shared kernel registry.
     """
+    if keep is not None:
+        if impl not in ("auto", "xla"):
+            raise ValueError(
+                f"sdpa: a selection needs the XLA core, got impl={impl!r}")
+        return jax.nn.dot_product_attention(q, k, v, mask=keep[:, None])
     if ":" in impl:
         from diff3d_tpu.parallel import ring_sdpa, ulysses_sdpa
         kind, _, axis = impl.partition(":")

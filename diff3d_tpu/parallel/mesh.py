@@ -206,6 +206,12 @@ def tp_param_sharding(mesh: Mesh, path, shape: Sequence[int],
         parallel); bias replicated.
       * conv kernels ``[kh, kw, cin, cout]`` and Dense kernels (FiLM,
         logsnr MLP) — output channels over ``model``; biases likewise.
+      * the token denoiser's expert stacks ``moe/w_gate``, ``w_up``,
+        ``w_down`` ``[experts, in, out]`` — the expert dim over ``model``
+        (expert parallelism: a device holds whole experts); the router,
+        which scores all experts, replicated.  The layer itself is one
+        device's program today (models/moe.py): on a mesh GSPMD gathers
+        what its dynamic expert index needs, with no token exchange.
       * everything else (norm scales, learned pose embeddings, tiny
         leaves) — replicated.
 
@@ -221,7 +227,10 @@ def tp_param_sharding(mesh: Mesh, path, shape: Sequence[int],
         return len(shape) > dim and shape[dim] % tp == 0 and shape[dim] >= tp
 
     is_kernel = names and names[-1] == "kernel"
-    if tp > 1 and is_kernel:
+    if tp > 1 and names and names[-1] in ("w_gate", "w_up", "w_down"):
+        if len(shape) == 3 and shardable(0):
+            spec[0] = model_axis           # expert stacks: whole experts
+    elif tp > 1 and is_kernel:
         if any(n in ("q_proj", "k_proj", "v_proj") for n in names):
             if shardable(len(shape) - 1):
                 spec[-1] = model_axis

@@ -61,7 +61,6 @@ from diff3d_tpu.config import Config
 from diff3d_tpu.diffusion import (SAMPLER_KINDS, sample_loop_prepare,
                                   sample_loop_scan, sample_view,
                                   sample_view_commit, schedule_start_index)
-from diff3d_tpu.models import XUNet
 from diff3d_tpu.utils.profiling import scope, span
 
 
@@ -100,7 +99,11 @@ class Sampler:
     """Runs the full autoregressive view loop for one object.
 
     Args:
-      model: the X-UNet.
+      model: a denoiser of the forward contract (docs/DESIGN.md §1),
+        as :func:`diff3d_tpu.models.build_model` gives it: a Flax module
+        whose ``apply({"params": p}, batch, cond_mask=..., constrain=...)``
+        takes ``x`` / ``z`` at ``B`` examples and the conditioning inputs
+        at ``G`` rows, ``G`` dividing ``B``.
       params: trained parameters (typically the EMA pytree).  Held as the
         *default* — every compiled entry point takes params as a jit
         argument, so callers (checkpoint hot-swap in serving) may pass a
@@ -139,7 +142,7 @@ class Sampler:
         refuse a truncated sampler.
     """
 
-    def __init__(self, model: XUNet, params, cfg: Config,
+    def __init__(self, model, params, cfg: Config,
                  scan_chunks: int = 1, mesh=None,
                  sampler_kind: str = "ancestral",
                  steps: Optional[int] = None,

@@ -609,7 +609,7 @@ def boot_worker(cfg: Config, *, name: str, devices: List[int],
     random init params (the test/dev path)."""
     import jax
 
-    from diff3d_tpu.models import XUNet
+    from diff3d_tpu.models import build_xunet
     from diff3d_tpu.parallel.mesh import make_mesh
     from diff3d_tpu.sampling import Sampler
     from diff3d_tpu.serving.fleet import Replica
@@ -624,7 +624,7 @@ def boot_worker(cfg: Config, *, name: str, devices: List[int],
     slice_devices = [all_devices[i] for i in devices]
     mesh_env = make_mesh(cfg.mesh, devices=slice_devices)
 
-    model = XUNet(cfg.model)
+    model = build_xunet(cfg, "serving.worker")
     if params is None:
         params = init_params(model, cfg, jax.random.PRNGKey(0))
     default_steps = steps if steps is not None else cfg.diffusion.timesteps

@@ -30,7 +30,7 @@ import numpy as np
 
 from diff3d_tpu.config import Config
 from diff3d_tpu.diffusion import p_losses
-from diff3d_tpu.models import XUNet
+from diff3d_tpu.models import build_model
 from diff3d_tpu.parallel import MeshEnv, make_mesh
 from diff3d_tpu.parallel.multihost import is_primary
 from diff3d_tpu.runtime.retry import (RetryBudget, RetryPolicy,
@@ -59,7 +59,7 @@ def _input_wait() -> tuple:
             int(RECORDER.counters().get("prefetch.starved", 0)))
 
 
-def init_params(model: XUNet, cfg: Config, rng: jax.Array):
+def init_params(model, cfg: Config, rng: jax.Array):
     """Initialise params with a dummy batch (shapes only).  Compiled —
     eager flax init dispatches thousands of tiny device ops; one compiled
     program does not."""
@@ -90,13 +90,14 @@ class Trainer:
         self.loader = loader
         self.env = env or make_mesh(cfg.mesh)
         self.workdir = workdir
-        self.model = XUNet(cfg.model)
+        self.model = build_model(cfg)
         self.rng = jax.random.PRNGKey(cfg.train.seed)
 
         params = init_params(self.model, cfg, self.rng)
         n_params = sum(int(np.prod(p.shape))
                        for p in jax.tree.leaves(params))
-        log.info("XUNet: %.1fM params", n_params / 1e6)
+        log.info("%s: %.1fM params", type(self.model).__name__,
+                 n_params / 1e6)
         state = create_train_state(params, cfg.train)
 
         # Place the fresh state according to the mesh policy before any

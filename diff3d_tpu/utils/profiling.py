@@ -50,7 +50,11 @@ SPAN_PREFIX = "d3d:"
 #: path is its class; forward and backward share a tag.
 SCOPES = ("conv", "film", "groupnorm", "attention", "conditioning",
           "dropout", "residual", "loss", "grad_accum", "optimizer", "ema",
-          "metrics", "sampler", "record")
+          "metrics", "sampler", "record",
+          # the token denoiser's own (models/token_denoiser.py); it
+          # shares "attention" (projections), "residual", "conditioning"
+          "patch_embed", "moe_router", "experts", "indexer",
+          "sparse_attention", "rope")
 
 
 def scope(tag: str):

@@ -248,6 +248,39 @@ def programs():
             "lower": lambda: (lower_train(), lower_view())}
 
 
+#: the token denoiser's own classes (models/token_denoiser.py)
+TOKEN_SCOPES = {"patch_embed", "moe_router", "experts", "indexer",
+                "sparse_attention", "rope"}
+
+
+def test_the_token_denoisers_classes_are_in_its_view_program():
+    """Every device op of the token denoiser's view program falls in a
+    class: its own six and the shared ``attention`` (projections),
+    ``residual``, ``conditioning``, ``sampler``, ``record``."""
+    from diff3d_tpu.config import token_test_config
+    from diff3d_tpu.models import build_model
+    from diff3d_tpu.sampling import Sampler
+
+    cfg = token_test_config()
+    model = build_model(cfg)
+    params = jax.eval_shape(
+        lambda: init_params(model, cfg, jax.random.PRNGKey(0)))
+    low = Sampler(model, params, cfg, sampler_kind="ddim",
+                  steps=2).lower_step_many(1, 2)
+    with no_compile_cache():
+        text = low.compile().as_text()
+    ops = op_names(text)
+    tags = [op_class(n)[0] for _, n in ops]
+    assert set(tags) - {None} == TOKEN_SCOPES | {
+        "attention", "residual", "conditioning", "sampler", "record"}
+    assert tags.count(None) / len(tags) < 0.12
+    # a bare primitive name (``lt_to``, ``reduce_sum``) is the body of a
+    # sort's comparator or a reduction's adder, no op of its own
+    named_untagged = [n for (_, n), t in zip(ops, tags)
+                      if t is None and "/" in n]
+    assert len(named_untagged) / len(tags) < 0.002, named_untagged[:5]
+
+
 def test_every_class_is_in_the_compiled_programs_and_few_ops_have_none(
         programs):
     train, view = programs["lower"]()
@@ -265,7 +298,7 @@ def test_every_class_is_in_the_compiled_programs_and_few_ops_have_none(
         named_untagged = [n for (_, n), t in zip(ops, tags)
                           if t is None and n]
         assert len(named_untagged) / len(tags) < 0.002, named_untagged[:5]
-    assert seen - {None} == set(SCOPES)
+    assert seen - {None} == set(SCOPES) - TOKEN_SCOPES
     # forward and backward share a tag; the backward is told by transpose(
     bwd = {op_class(n) for _, n in op_names(texts["train"])}
     for tag in ("conv", "film", "groupnorm", "attention", "conditioning",

@@ -28,7 +28,7 @@ def main() -> None:
     import numpy as np
 
     from diff3d_tpu.config import srn64_config
-    from diff3d_tpu.models import XUNet
+    from diff3d_tpu.models import build_model
     from diff3d_tpu.sampling.runtime import Sampler
     from diff3d_tpu.train.trainer import init_params
 
@@ -37,7 +37,7 @@ def main() -> None:
         cfg = dataclasses.replace(
             cfg, model=dataclasses.replace(cfg.model, attn_impl=sys.argv[1]))
         print(f"attn_impl={sys.argv[1]}")
-    model = XUNet(cfg.model)
+    model = build_model(cfg)
     rng = jax.random.PRNGKey(0)
     params = init_params(model, cfg, rng)
     sampler = Sampler(model, params, cfg)

@@ -61,7 +61,7 @@ def _build_service(args):
 
     from diff3d_tpu import config as config_lib
     from diff3d_tpu.config import ServingConfig
-    from diff3d_tpu.models import XUNet
+    from diff3d_tpu.models import build_model
     from diff3d_tpu.sampling import Sampler
     from diff3d_tpu.serving import ServingService
     from diff3d_tpu.train.trainer import init_params
@@ -78,7 +78,7 @@ def _build_service(args):
         max_wait_ms=args.max_wait_ms, default_timeout_s=args.timeout_s,
         max_views=max(16, args.n_views),
         result_cache_entries=0))     # load bench must not replay results
-    model = XUNet(cfg.model)
+    model = build_model(cfg)
     params = init_params(model, cfg, jax.random.PRNGKey(0))
     mesh_env = None
     if args.mesh:

@@ -79,7 +79,7 @@ def _build(args):
 
     from diff3d_tpu import config as config_lib
     from diff3d_tpu.config import ServingConfig
-    from diff3d_tpu.models import XUNet
+    from diff3d_tpu.models import build_model
     from diff3d_tpu.sampling import Sampler
     from diff3d_tpu.serving import FleetService
     from diff3d_tpu.testing.faults import FaultInjector
@@ -97,7 +97,7 @@ def _build(args):
         replicas=args.replicas,
         heartbeat_interval_s=0.1, heartbeat_timeout_s=2.0,
         result_cache_entries=0))     # a soak must not replay results
-    model = XUNet(cfg.model)
+    model = build_model(cfg)
     params = init_params(model, cfg, jax.random.PRNGKey(0))
     sampler = Sampler(model, params, cfg)
     inj = FaultInjector(seed=args.seed)

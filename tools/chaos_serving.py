@@ -61,7 +61,7 @@ def _build(args):
 
     from diff3d_tpu import config as config_lib
     from diff3d_tpu.config import ServingConfig
-    from diff3d_tpu.models import XUNet
+    from diff3d_tpu.models import build_model
     from diff3d_tpu.sampling import Sampler
     from diff3d_tpu.serving import ServingService
     from diff3d_tpu.testing.faults import FaultInjector, wrap_sampler
@@ -78,7 +78,7 @@ def _build(args):
         step_retry_attempts=2, step_retry_backoff_s=0.05,
         degraded_recovery_steps=2, retry_after_s=1.0,
         result_cache_entries=0))     # a soak must not replay results
-    model = XUNet(cfg.model)
+    model = build_model(cfg)
     params = init_params(model, cfg, jax.random.PRNGKey(0))
     sampler = Sampler(model, params, cfg)
     inj = FaultInjector(seed=args.seed)

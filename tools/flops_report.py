@@ -51,7 +51,7 @@ def main() -> None:
 
     from diff3d_tpu import config as config_lib
     from diff3d_tpu.data import InfiniteLoader, SyntheticDataset
-    from diff3d_tpu.models import XUNet
+    from diff3d_tpu.models import build_model
     from diff3d_tpu.parallel import make_mesh
     from diff3d_tpu.train import create_train_state, make_train_step
     from diff3d_tpu.train.trainer import init_params
@@ -72,7 +72,7 @@ def main() -> None:
         train=dataclasses.replace(cfg.train, global_batch=global_batch,
                                   accum_steps=accum))
     env = make_mesh(cfg.mesh)
-    model = XUNet(cfg.model)
+    model = build_model(cfg)
     rng = jax.random.PRNGKey(0)
     state = create_train_state(init_params(model, cfg, rng), cfg.train)
     state = jax.device_put(state, env.state_shardings(state))

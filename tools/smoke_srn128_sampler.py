@@ -41,7 +41,7 @@ def main() -> None:
 
     from diff3d_tpu import config as config_lib
     from diff3d_tpu.data import SyntheticScenesDataset
-    from diff3d_tpu.models import XUNet
+    from diff3d_tpu.models import build_model
     from diff3d_tpu.sampling import Sampler
     from diff3d_tpu.train.trainer import init_params
 
@@ -54,7 +54,7 @@ def main() -> None:
         cfg, diffusion=dataclasses.replace(cfg.diffusion,
                                            timesteps=args.timesteps))
 
-    model = XUNet(cfg.model)
+    model = build_model(cfg)
     params = init_params(model, cfg, jax.random.PRNGKey(0))
     n_params = sum(x.size for x in jax.tree.leaves(params))
     print(f"params: {n_params / 1e6:.1f}M  H={cfg.model.H}  "

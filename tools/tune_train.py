@@ -29,7 +29,7 @@ def run_variant(global_batch: int, accum: int, remat: bool, policy: str,
 
     srn64_config = getattr(config_mod, f"{CONFIG}_config")
     from diff3d_tpu.data import InfiniteLoader, SyntheticDataset
-    from diff3d_tpu.models import XUNet
+    from diff3d_tpu.models import build_model
     from diff3d_tpu.parallel import make_mesh
     from diff3d_tpu.train import create_train_state, make_train_step
     from diff3d_tpu.train.trainer import init_params
@@ -43,7 +43,7 @@ def run_variant(global_batch: int, accum: int, remat: bool, policy: str,
                                   accum_steps=accum))
 
     env = make_mesh(cfg.mesh)
-    model = XUNet(cfg.model)
+    model = build_model(cfg)
     rng = jax.random.PRNGKey(0)
     state = create_train_state(init_params(model, cfg, rng), cfg.train)
     state = jax.device_put(state, env.state_shardings(state))
