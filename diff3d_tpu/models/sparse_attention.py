@@ -15,12 +15,18 @@ Per layer, on the normed tokens ``u [B, L, D]`` of ``B`` examples:
     alone, scores over ``sqrt(head_dim)``; ``W_o`` back to ``D``.
 
 With ``topk >= L`` every key is selected and the layer is dense
-grouped-query attention.  One path: the scores of a tile of ``q_chunk``
-queries against all keys are computed dense and masked by the selection
-(:func:`diff3d_tpu.ops.attention.sdpa` with ``keep``), one example and
-one tile of queries at a time, so no ``[B, Hq, L, L]`` array exists and
-``q`` exists for one example only (:func:`attend_example`: plain functions
-of the layer's arrays, mapped over the examples).
+grouped-query attention.  One tile of ``q_chunk`` queries against all
+keys at a time, one example at a time
+(:func:`diff3d_tpu.ops.attention.sdpa` with ``keep``), so no ``[B, Hq, L,
+L]`` array exists and ``q`` exists for one example only
+(:func:`attend_example`: plain functions of the layer's arrays, mapped
+over the examples).  The core under the selection has two forms, and
+``sdpa`` picks by what the process and the shapes are: on a TPU process,
+at a head dim of whole lane tiles and whole query / key blocks, one
+Pallas kernel that streams the keys through VMEM and writes only the
+tile's output (``ops/pallas_attention.selected_attention``); elsewhere
+(CPU processes, toy widths) the tile's scores computed dense and masked
+by XLA, which is also the kernel's gradient.
 """
 
 from __future__ import annotations

@@ -6,11 +6,19 @@ The reference runs ``torch.nn.MultiheadAttention`` over ``H*W`` tokens
 with :mod:`diff3d_tpu.ops.dispatch` (shared with the fused GroupNorm
 epilogues):
 
-  * ``'xla'``    — ``jax.nn.dot_product_attention``; XLA already emits a
-    fused, flash-style kernel on TPU for moderate sequence lengths.
-  * ``'pallas'`` — hand-written TPU Pallas flash kernel
-    (:mod:`diff3d_tpu.ops.pallas_attention`), tiled for the MXU.
-  * ``'auto'``   — pallas on TPU when shapes qualify, else xla.
+  * ``'xla'``    — ``jax.nn.dot_product_attention``: the score tile is
+    written to HBM and read back (XLA emits no flash kernel on the v5e:
+    under a selection at ``[32, 512, 8192]`` it makes three passes over
+    a 512 MB float32 tile, PERF.md section 6, PR 27).
+  * ``'pallas'`` — hand-written TPU Pallas kernels
+    (:mod:`diff3d_tpu.ops.pallas_attention`): ``flash_attention`` for
+    the plain core, ``selected_attention`` for the core under a
+    selection.
+  * ``'auto'``   — pallas on a TPU process when the operands qualify,
+    else xla.
+
+Two ops are registered: ``'sdpa'`` (plain) and ``'sdpa_selected'`` (with
+``keep``); each has its own ``supports`` and ``auto`` policy.
 
 All shapes here are ``[B, L, n_heads, head_dim]`` (jax.nn convention).
 """
@@ -21,6 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from diff3d_tpu.ops import dispatch
+from diff3d_tpu.utils.profiling import count
 
 
 def _xla_sdpa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray) -> jnp.ndarray:
@@ -51,9 +60,33 @@ def _pallas_auto(q, *args) -> bool:
     return D > 64 and L >= 4096
 
 
+def _xla_selected(q, k, v, keep) -> jnp.ndarray:
+    from diff3d_tpu.ops.pallas_attention import selected_reference
+
+    return selected_reference(q, k, v, keep)
+
+
+def _pallas_selected(q, k, v, keep) -> jnp.ndarray:
+    from diff3d_tpu.ops.pallas_attention import selected_attention
+
+    return selected_attention(q, k, v, keep)
+
+
+def _pallas_selected_supports(q, k, v, keep) -> bool:
+    from diff3d_tpu.ops.pallas_attention import selected_supports
+
+    return selected_supports(q, k, v, keep)
+
+
 dispatch.register("sdpa", "xla", _xla_sdpa)
 dispatch.register("sdpa", "pallas", _pallas_sdpa,
                   supports=_pallas_supports, auto=_pallas_auto)
+# Under a selection the kernel wins wherever it runs (one v5e chip,
+# [32 / 4 heads, 512, 8192] x 128: 8.3 ms a layer-example against XLA's
+# 36.6, PERF.md section 6, PR 27): no 'auto' policy beyond 'supports'.
+dispatch.register("sdpa_selected", "xla", _xla_selected)
+dispatch.register("sdpa_selected", "pallas", _pallas_selected,
+                  supports=_pallas_selected_supports)
 
 
 def _resolve_auto(q: jnp.ndarray) -> str:
@@ -75,10 +108,16 @@ def sdpa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     selection: the softmax runs over the kept keys alone, as the sparse
     attention of the token denoiser needs (models/sparse_attention.py).
     ``k`` / ``v`` may then have fewer heads than ``q`` (grouped queries:
-    their head count divides ``q``'s).  Only the XLA core takes a
-    selection — the Pallas flash kernel and the sequence-parallel cores
-    have no mask operand — so ``keep`` goes with ``impl='auto'|'xla'`` and
-    always runs the XLA core: a dense score tile, masked.
+    their head count divides ``q``'s).  A selection has two cores, op
+    ``'sdpa_selected'`` of the registry: the Pallas forward kernel
+    ``selected_attention``, which keeps the score tile on chip, and the
+    XLA expression (a dense score tile, masked), which is also the
+    kernel's gradient.  ``'auto'`` takes the kernel on a TPU process
+    when its ``supports`` holds (head dim whole lane tiles, whole query
+    and key blocks) and the XLA expression otherwise, so CPU processes
+    lower as they did; ``'pallas'`` is honoured or raises; the
+    sequence-parallel cores have no mask operand.  Each traced site adds
+    1 to the recorder's ``sdpa.selected.pallas`` or ``sdpa.selected.xla``.
 
     ``impl`` may also name a sequence-parallel core — ``'ring:<axis>'`` or
     ``'ulysses:<axis>'`` — in which case q/k/v are local token shards of a
@@ -90,10 +129,9 @@ def sdpa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     ('auto' | 'pallas' | 'xla') goes through the shared kernel registry.
     """
     if keep is not None:
-        if impl not in ("auto", "xla"):
-            raise ValueError(
-                f"sdpa: a selection needs the XLA core, got impl={impl!r}")
-        return jax.nn.dot_product_attention(q, k, v, mask=keep[:, None])
+        core = dispatch.resolve("sdpa_selected", impl, q, k, v, keep)
+        count(f"sdpa.selected.{core.name}")
+        return core.fn(q, k, v, keep)
     if ":" in impl:
         from diff3d_tpu.parallel import ring_sdpa, ulysses_sdpa
         kind, _, axis = impl.partition(":")

@@ -1,4 +1,13 @@
-"""TPU Pallas flash attention (forward + backward).
+"""TPU Pallas attention kernels: flash attention (forward + backward) and
+attention under a selection (forward).
+
+Two kernels share this file and its helpers and nothing of their bodies.
+:func:`flash_attention`, described first, is the X-UNet's: no mask, one
+key-value head per query head, float32 dots, 128 x 128 tiles, its own
+backward kernels.  :func:`selected_attention` (the last section) is the
+token denoiser's: a selection ``keep [B, Lq, Lk]``, grouped queries,
+operands to the MXU in the dtype given, blocks sized from the shape,
+the XLA expression's gradient.
 
 Replaces the reference's ``torch.nn.MultiheadAttention`` sdpa core
 (``/root/reference/xunet.py:154-177``, which delegates to cuDNN) with a
@@ -444,3 +453,175 @@ def flash_attention_lse(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     if interpret is None:
         interpret = dispatch.interpret_default()
     return _flash_lse(q, k, v, scale, bool(interpret))
+
+
+# --------------------------------------------------------------------------
+# attention under a selection: forward kernel, grouped queries
+# --------------------------------------------------------------------------
+
+SELECT_BLOCK_Q = (512, 256, 128, 64, 32)   # int8 ``keep`` tiles are (32, 128)
+SELECT_BLOCK_K = (2048, 1024, 512, 256, 128)
+SELECT_TILE = 1 << 19       # score elements of one head's block
+SELECT_VMEM_BYTES = 64 << 20   # of the v5e's 128 MiB; the default is 16
+
+
+def _selected_blocks(Lq: int, Lk: int) -> Optional[tuple[int, int]]:
+    """(query block, key block) for ``Lq`` queries on ``Lk`` keys, or
+    None when a length is no whole number of blocks.  Wide key blocks
+    first: the row max and row sum cross the lanes once per row and key
+    block, whatever the block's width (at 512 queries x 8192 keys on the
+    v5e, 256 x 2048 ran at 8.3 ms a layer-example, 512 x 512 at 16.6:
+    PERF.md section 6, PR 27); then as many queries as keep one head's
+    float32 score block at ``SELECT_TILE`` elements (2 MB)."""
+    bk = next((c for c in SELECT_BLOCK_K if Lk % c == 0), None)
+    if bk is None:
+        return None
+    bq = next((c for c in SELECT_BLOCK_Q
+               if Lq % c == 0 and c * bk <= SELECT_TILE), None)
+    return None if bq is None else (bq, bk)
+
+
+def selected_supports(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                      keep: jnp.ndarray) -> bool:
+    """Shapes/dtypes :func:`selected_attention` handles: ``q [B, Lq, Hq,
+    D]``, ``k, v [B, Lk, Hkv, D]`` of one dtype (bf16 or float32), ``keep
+    [B, Lq, Lk]``; ``D`` whole lane tiles (no head-dim padding on this
+    path), ``Hkv`` dividing ``Hq``, ``Lq`` / ``Lk`` whole query / key
+    blocks."""
+    if q.ndim != 4 or k.ndim != 4 or k.shape != v.shape or keep.ndim != 3:
+        return False
+    if q.dtype not in (jnp.float32, jnp.bfloat16) \
+            or k.dtype != q.dtype or v.dtype != q.dtype:
+        return False
+    B, Lq, Hq, D = q.shape
+    Lk, Hkv = k.shape[1], k.shape[2]
+    return (k.shape[0] == B and k.shape[3] == D and D % LANE == 0
+            and D <= MAX_D and Hq % Hkv == 0
+            and keep.shape == (B, Lq, Lk)
+            and _selected_blocks(Lq, Lk) is not None)
+
+
+def _selected_kernel(q_ref, k_ref, v_ref, keep_ref, o_ref, m_scr, l_scr,
+                     acc_scr):
+    """One (example, key-value head, query block, key block) step: the
+    ``group`` query heads that share this key-value head take the same
+    ``k`` / ``v`` block and the same ``keep`` block in turn.  ``q`` and
+    ``o`` blocks are ``[bq, group * D]``: head ``g`` is the lane slice
+    ``[g * D, (g + 1) * D)``, whole lane tiles, so nothing is transposed
+    in HBM or on chip."""
+    D = k_ref.shape[-1]
+    group = q_ref.shape[-1] // D
+    scale = float(1.0 / np.sqrt(D))
+    ki = pl.program_id(3)
+
+    @pl.when(ki == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    # 0 on a kept key, NEG_INF on the others: float32 absorbs any score
+    # into NEG_INF, so a dropped key's probability is exp(NEG_INF - m) = 0
+    # once its row has met a kept key, and a row without any (no caller
+    # makes one) averages all keys, as the XLA expression does.
+    bias = (keep_ref[0].astype(jnp.float32) - 1.0) * (-NEG_INF)
+    k = k_ref[0]                                           # [bk, D]
+    v = v_ref[0]
+    for g in range(group):
+        q = q_ref[0, :, g * D:(g + 1) * D]                 # [bq, D]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        s = s * scale + bias                               # [bq, bk] f32
+        m_prev = m_scr[g, :, :1]                           # [bq, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_new = alpha * l_scr[g, :, :1] + jnp.sum(p, axis=-1, keepdims=True)
+        pv = jnp.dot(p.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)   # [bq, D]
+        acc_scr[g] = acc_scr[g] * alpha + pv
+        m_scr[g] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+        l_scr[g] = jnp.broadcast_to(l_new, l_scr.shape[1:])
+
+    @pl.when(ki == pl.num_programs(3) - 1)
+    def _finalize():
+        for g in range(group):
+            o_ref[0, :, g * D:(g + 1) * D] = (
+                acc_scr[g] / l_scr[g, :, :1]).astype(o_ref.dtype)
+
+
+def _selected_fwd(q, k, v, keep, interpret: bool):
+    B, Lq, Hq, D = q.shape
+    Lk, Hkv = k.shape[1], k.shape[2]
+    group = Hq // Hkv
+    bq, bk = _selected_blocks(Lq, Lk)
+    qo_spec = pl.BlockSpec((1, bq, group * D),
+                           lambda b, h, qi, ki: (b, qi, h))
+    kv_spec = pl.BlockSpec((1, bk, D), lambda b, h, qi, ki: (b, ki, h))
+    keep_spec = pl.BlockSpec((1, bq, bk), lambda b, h, qi, ki: (b, qi, ki))
+    out = pl.pallas_call(
+        _selected_kernel,
+        grid=(B, Hkv, Lq // bq, Lk // bk),
+        in_specs=[qo_spec, kv_spec, kv_spec, keep_spec],
+        out_specs=qo_spec,
+        out_shape=_out_struct((B, Lq, Hq * D), q.dtype, q),
+        scratch_shapes=[_vmem((group, bq, LANE)), _vmem((group, bq, LANE)),
+                        _vmem((group, bq, D))],
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=SELECT_VMEM_BYTES),
+        interpret=interpret,
+    )(q.reshape(B, Lq, Hq * D), k.reshape(B, Lk, Hkv * D),
+      v.reshape(B, Lk, Hkv * D), keep.astype(jnp.int8))
+    return out.reshape(B, Lq, Hq, D)
+
+
+def selected_reference(q, k, v, keep):
+    """The XLA expression the kernel stands for, and the one it is
+    differentiated through: a dense score tile, masked."""
+    return jax.nn.dot_product_attention(q, k, v, mask=keep[:, None])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _selected(q, k, v, keep, interpret: bool):
+    return _selected_fwd(q, k, v, keep, interpret)
+
+
+def _selected_vjp_fwd(q, k, v, keep, interpret: bool):
+    return _selected_fwd(q, k, v, keep, interpret), (q, k, v, keep)
+
+
+def _selected_vjp_bwd(interpret, res, g):
+    q, k, v, keep = res
+    _, vjp = jax.vjp(lambda q, k, v: selected_reference(q, k, v, keep),
+                     q, k, v)
+    return (*vjp(g), np.zeros(keep.shape, jax.dtypes.float0))
+
+
+_selected.defvjp(_selected_vjp_fwd, _selected_vjp_bwd)
+
+
+def selected_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                       keep: jnp.ndarray,
+                       interpret: Optional[bool] = None) -> jnp.ndarray:
+    """Attention of ``q [B, Lq, Hq, D]`` over the keys ``keep [B, Lq, Lk]``
+    (bool, shared by all heads) selects among ``k, v [B, Lk, Hkv, D]``:
+    softmax over the kept keys alone, scores over ``sqrt(D)``.
+
+    One forward kernel: key / value blocks stream through VMEM against a
+    running max, sum and output accumulator in float32, the selection is
+    applied to each score block on chip and only the output is written,
+    so nothing of size ``[Hq, Lq, Lk]`` touches HBM.  The ``Hq / Hkv``
+    query heads of a group share each ``k`` / ``v`` / ``keep`` block
+    (:func:`_selected_kernel`).  Operands go to the MXU in the dtype
+    given; the probabilities are cast to it for the ``PV`` product, as
+    ``jax.nn.dot_product_attention`` casts them.  The gradient is the
+    XLA expression's (:func:`selected_reference`) on the saved operands:
+    there is no backward kernel.
+    """
+    assert selected_supports(q, k, v, keep), (q.shape, k.shape, v.shape,
+                                              keep.shape, q.dtype)
+    if interpret is None:
+        interpret = dispatch.interpret_default()
+    return _selected(q, k, v, keep, bool(interpret))

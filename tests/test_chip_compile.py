@@ -15,6 +15,11 @@ stream is float32 (``/ np.sqrt(2.0)`` promotes it), so most GroupNorm
 inputs are float32 while FiLM sites see the bf16 conv output.  N (frames
 x batch) only scales the kernels' outer grid; the tests use N = 2.
 
+The token denoiser has one kernel site, ``sdpa(keep=)`` of
+``keye_vl2_tok128``: one tile of 512 queries (32 heads) against the 8192
+keys of an example (4 heads) at head dim 128, vmapped over the sampler's
+objects (PR 27).
+
 Fixture rules (on-chip-measurement guide, section 2): the topology is
 described inside a module-scoped, non-autouse fixture of THIS file, which
 skips where it cannot be described — never at import, in ``skipif``, in
@@ -24,12 +29,15 @@ persistent cache is off around them (a TPU executable written from a CPU
 process cannot be read back).
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from diff3d_tpu.ops.pallas_attention import flash_attention
+from diff3d_tpu.ops.pallas_attention import (flash_attention,
+                                             selected_attention)
 from diff3d_tpu.ops.pallas_film import fused_groupnorm
 
 F32, BF16 = "float32", "bfloat16"
@@ -180,3 +188,52 @@ def test_flash_attention_backward_compiles_for_v5e(
 
     _compile_for_chip(jax.grad(loss, argnums=(0, 1, 2)),
                       *_qkv(one_chip, L, heads, D, dtype))
+
+
+# keye_vl2_tok128's sdpa(keep=) site: (Lq, Lk, Hq, Hkv, D)
+SELECTED_SITE = (512, 8192, 32, 4, 128)
+
+
+def _selected_operands(one_chip, dtype, lead=()):
+    Lq, Lk, Hq, Hkv, D = SELECTED_SITE
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(lead + shape, jnp.dtype(dt),
+                                    sharding=one_chip)
+    return (sds((1, Lq, Hq, D), dtype), sds((1, Lk, Hkv, D), dtype),
+            sds((1, Lk, Hkv, D), dtype), sds((1, Lq, Lk), "bool"))
+
+
+def _selected(q, k, v, keep):
+    return selected_attention(q, k, v, keep, interpret=False)
+
+
+@pytest.mark.parametrize("dtype", [BF16, F32])
+def test_selected_attention_forward_compiles_for_v5e(
+        one_chip, no_persistent_cache, dtype):
+    compiled = _compile_for_chip(_selected,
+                                 *_selected_operands(one_chip, dtype))
+    # the score tile stays on chip: nothing of [Hq, Lq, Lk] in the program
+    assert not re.search(r"(f32|bf16)\[[\d,]*512,8192\]", compiled.as_text())
+
+
+def test_selected_attention_under_the_samplers_vmap_compiles_for_v5e(
+        one_chip, no_persistent_cache):
+    """``Sampler`` vmaps the view program over objects: Pallas adds a grid
+    axis in front of the kernel's own, which the chip's compiler must
+    take with the kernel's ``dimension_semantics``."""
+    _compile_for_chip(jax.vmap(_selected),
+                      *_selected_operands(one_chip, BF16, lead=(2,)))
+
+
+def test_selected_attention_gradient_compiles_for_v5e(
+        one_chip, no_persistent_cache):
+    """The token train step's pair: the kernel forward, the XLA
+    expression's VJP backward."""
+    q, k, v, keep = _selected_operands(one_chip, BF16)
+
+    def loss(q, k, v, keep):
+        # squared, so that the backward needs the kernel's output
+        return jnp.sum(_selected(q, k, v, keep).astype(jnp.float32) ** 2)
+
+    _compile_for_chip(jax.grad(loss, argnums=(0, 1, 2)), q, k, v, keep)

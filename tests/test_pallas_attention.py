@@ -157,3 +157,189 @@ def test_resolve_auto_policy(monkeypatch):
     assert att._resolve_auto(q(4096, 64)) == "xla"    # 2x lane padding
     assert att._resolve_auto(q(4096, 128)) == "pallas"
     assert att._resolve_auto(q(1024, 128)) == "xla"   # short seq
+
+
+# --------------------------------------------------------------------------
+# attention under a selection (selected_attention, sdpa(keep=))
+# --------------------------------------------------------------------------
+
+from diff3d_tpu.ops import dispatch  # noqa: E402
+from diff3d_tpu.ops.pallas_attention import (selected_attention,  # noqa: E402
+                                             selected_reference,
+                                             selected_supports)
+
+
+def _selection(rng, B, Lq, Lk, topk):
+    """``keep [B, Lq, Lk]`` as the token denoiser makes it (scores at
+    least the ``topk``-th largest of their row), with what the kernel
+    must survive: scores drawn from 40 values, so keys tie with the
+    ``topk``-th and rows keep different numbers; the second key block of
+    128 kept by no row; the first kept by no row of the first half."""
+    scores = rng.randint(0, 40, (B, Lq, Lk)).astype(np.float32)
+    scores[:, :, 128:256] = -1.0
+    scores[:, :Lq // 2, :128] = -1.0
+    kth = np.sort(scores, axis=-1)[..., Lk - topk]
+    keep = scores >= kth[..., None]
+    assert not keep[:, :, 128:256].any() and not keep[:, :Lq // 2, :128].any()
+    assert len(set(keep.sum(-1).ravel().tolist())) > 1 \
+        and keep.sum(-1).min() >= topk
+    return jnp.asarray(keep)
+
+
+def _selected_operands(dtype, group, B=2, Lq=64, Lk=384, Hkv=1, D=128,
+                       seed=0):
+    """Lq != Lk; 384 keys are three key blocks of 128."""
+    rng = np.random.RandomState(seed)
+    q = jnp.asarray(rng.randn(B, Lq, Hkv * group, D), dtype)
+    k = jnp.asarray(rng.randn(B, Lk, Hkv, D), dtype)
+    v = jnp.asarray(rng.randn(B, Lk, Hkv, D), dtype)
+    return q, k, v, _selection(rng, B, Lq, Lk, topk=48)
+
+
+def _f32(x):
+    return np.asarray(x.astype(jnp.float32))
+
+
+@pytest.mark.parametrize("group", [1, 4, 8])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_selected_attention_matches_xla(dtype, group):
+    Hkv = 2 if group == 4 else 1
+    q, k, v, keep = _selected_operands(dtype, group, Hkv=Hkv)
+    assert selected_supports(q, k, v, keep)
+    out = selected_attention(q, k, v, keep, interpret=True)
+    ref = selected_reference(q, k, v, keep)
+    assert out.shape == ref.shape and out.dtype == dtype
+    # float32: only the order of the sums differs; bf16: the unnormalised
+    # probabilities are rounded for PV where XLA rounds the normalised
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(_f32(out), _f32(ref), atol=tol, rtol=0)
+    # and against the float32 softmax over the kept keys, written out
+    qf, kf, vf = (a.astype(jnp.float32) for a in (q, k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", qf, jnp.repeat(kf, group, axis=2),
+                   precision="highest") / np.sqrt(128.0)
+    p = jax.nn.softmax(jnp.where(keep[:, None], s, -jnp.inf), axis=-1)
+    want = jnp.einsum("bhqk,bkhd->bqhd", p, jnp.repeat(vf, group, axis=2),
+                      precision="highest")
+    np.testing.assert_allclose(_f32(out), want, atol=tol, rtol=0)
+
+
+def test_selected_attention_row_without_a_kept_key_averages_all_keys():
+    """No caller makes such a row (``topk >= 1``); the kernel then does
+    what the XLA expression does, and never divides by zero."""
+    q, k, v, keep = _selected_operands(jnp.float32, 4, B=1)
+    keep = keep.at[0, 3].set(False)
+    out = selected_attention(q, k, v, keep, interpret=True)
+    np.testing.assert_allclose(out, selected_reference(q, k, v, keep),
+                               atol=2e-5, rtol=0)
+    np.testing.assert_allclose(
+        out[0, 3], jnp.repeat(v[0].mean(axis=0), 4, axis=0), atol=2e-5)
+
+
+@pytest.mark.parametrize("how", ["vmap", "map", "vmap_of_map"])
+def test_selected_attention_under_the_call_paths_transformations(how):
+    """The sampler vmaps the view program over objects and the layer maps
+    over examples and query tiles: a leading axis by ``jax.vmap`` (Pallas
+    adds a grid axis), by ``lax.map``, and the map inside the vmap."""
+    q, k, v, keep = _selected_operands(jnp.float32, 4, B=3)
+    one = lambda q, k, v, keep: selected_attention(           # noqa: E731
+        q[None], k[None], v[None], keep[None], interpret=True)[0]
+    tiles = lambda q, k, v, keep: jax.lax.map(                # noqa: E731
+        lambda a: one(a[0], k, v, a[1]),
+        (q.reshape(2, 32, *q.shape[1:]), keep.reshape(2, 32, -1))
+    ).reshape(q.shape)
+    if how == "vmap":
+        out = jax.jit(jax.vmap(one))(q, k, v, keep)
+    elif how == "map":
+        out = jax.jit(lambda *a: jax.lax.map(lambda b: one(*b), a))(
+            q, k, v, keep)
+    else:
+        out = jax.jit(jax.vmap(tiles))(q, k, v, keep)
+    np.testing.assert_allclose(out, selected_reference(q, k, v, keep),
+                               atol=2e-5, rtol=0)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_selected_attention_gradient_is_the_xla_expressions(dtype):
+    """No backward kernel: the cotangents are the XLA expression's VJP at
+    the saved operands, to the last bit, also under ``jax.grad``."""
+    q, k, v, keep = _selected_operands(dtype, 4)
+    g = jnp.asarray(np.random.RandomState(5).randn(*q.shape), dtype)
+    _, vjp = jax.vjp(lambda q, k, v: selected_attention(
+        q, k, v, keep, interpret=True), q, k, v)
+    _, want = jax.vjp(lambda q, k, v: selected_reference(q, k, v, keep),
+                      q, k, v)
+    for a, b in zip(vjp(g), want(g)):
+        assert a.dtype == dtype and float(jnp.abs(_f32(b)).max()) > 0
+        np.testing.assert_array_equal(_f32(a), _f32(b))
+    loss = lambda fn: jax.grad(lambda q: jnp.sum(                 # noqa: E731
+        fn(q, k, v, keep).astype(jnp.float32) * g.astype(jnp.float32)))
+    np.testing.assert_array_equal(
+        _f32(loss(lambda *a: selected_attention(*a, interpret=True))(q)),
+        _f32(loss(selected_reference)(q)))
+
+
+UNSUPPORTED = {
+    "head_dim_32": dict(D=32),
+    "keys_not_whole_blocks": dict(Lk=200),
+    "queries_not_whole_blocks": dict(Lq=40),
+    "kv_heads_do_not_divide": dict(Hq=4, Hkv=3),
+    "float16": dict(dtype=jnp.float16),
+}
+
+
+@pytest.mark.parametrize("case", list(UNSUPPORTED))
+def test_sdpa_pallas_with_keep_on_unsupported_operands_raises(case):
+    p = dict(Lq=64, Lk=256, Hq=4, Hkv=2, D=128, dtype=jnp.float32)
+    p.update(UNSUPPORTED[case])
+    q = jnp.zeros((1, p["Lq"], p["Hq"], p["D"]), p["dtype"])
+    k = jnp.zeros((1, p["Lk"], p["Hkv"], p["D"]), p["dtype"])
+    keep = jnp.ones((1, p["Lq"], p["Lk"]), bool)
+    assert not selected_supports(q, k, k, keep)
+    with pytest.raises(ValueError, match="sdpa_selected.*'pallas' was "
+                                         "requested explicitly"):
+        sdpa(q, k, k, impl="pallas", keep=keep)
+    # 'auto' may choose, and off the TPU it chooses the XLA expression
+    assert dispatch.resolve("sdpa_selected", "auto", q, k, k,
+                            keep).name == "xla"
+
+
+def test_sdpa_with_keep_routes_by_request():
+    q, k, v, keep = _selected_operands(jnp.float32, 4, B=1)
+    ref = selected_reference(q, k, v, keep)
+    np.testing.assert_array_equal(sdpa(q, k, v, impl="xla", keep=keep), ref)
+    np.testing.assert_array_equal(sdpa(q, k, v, keep=keep), ref)  # CPU: xla
+    np.testing.assert_allclose(sdpa(q, k, v, impl="pallas", keep=keep), ref,
+                               atol=2e-5, rtol=0)
+    with pytest.raises(ValueError, match="ring:model"):
+        sdpa(q, k, v, impl="ring:model", keep=keep)
+
+
+def test_selected_auto_takes_the_kernel_on_a_tpu_process(monkeypatch):
+    q, k, v, keep = _selected_operands(jnp.bfloat16, 8, B=1)
+    small = jnp.zeros((1, 64, 4, 32), jnp.bfloat16)
+    monkeypatch.setattr(dispatch, "default_backend", lambda: "tpu")
+    assert dispatch.resolve("sdpa_selected", "auto", q, k, v,
+                            keep).name == "pallas"
+    assert dispatch.resolve("sdpa_selected", "auto", small, small, small,
+                            jnp.ones((1, 64, 64), bool)).name == "xla"
+
+
+def test_sdpa_with_keep_on_a_cpu_process_lowers_to_the_xla_expression():
+    """The text a CPU process lowers ``sdpa(keep=)`` to is the text of
+    ``jax.nn.dot_product_attention(mask=)``, what the parent lowered: no
+    kernel, in interpret mode or otherwise."""
+    q, k, v, keep = _selected_operands(jnp.float32, 4, B=1)
+
+    def core(q, k, v, keep):
+        return sdpa(q, k, v, keep=keep)
+    mine = jax.jit(core).lower(q, k, v, keep).as_text()
+
+    def core(q, k, v, keep):                                  # noqa: F811
+        return jax.nn.dot_product_attention(q, k, v, mask=keep[:, None])
+    assert mine == jax.jit(core).lower(q, k, v, keep).as_text()
+    assert "custom_call" not in mine and "pallas" not in mine
+    forced = jax.jit(lambda *a: sdpa(*a[:3], impl="pallas", keep=a[3])
+                     ).lower(q, k, v, keep).as_text()
+    assert forced != mine

@@ -29,6 +29,8 @@ from diff3d_tpu.config import token_test_config  # noqa: E402
 from diff3d_tpu.models import (TokenDenoiser, UnsupportedModelError, XUNet,
                                build_model, build_xunet)  # noqa: E402
 from diff3d_tpu.models import moe, sparse_attention  # noqa: E402
+from diff3d_tpu.ops import dispatch  # noqa: E402
+from diff3d_tpu.ops.pallas_attention import selected_supports  # noqa: E402
 from diff3d_tpu.utils.profiling import RECORDER  # noqa: E402
 
 with open(os.path.join(ROOT, "benchmark", "configs",
@@ -118,7 +120,38 @@ def test_g_rows_equal_repeated_rows_and_must_divide(tiny):
                             cond_mask=jnp.ones((3,), bool))
 
 
-def test_counters_of_a_traced_program(tiny):
+@pytest.fixture
+def kernel_forced(monkeypatch):
+    """The registry's policy patched to what it resolves on a TPU process:
+    ``sdpa(keep=)`` at ``impl='auto'`` takes the Pallas core (in interpret
+    mode, this being a CPU process).  No option of the program does this."""
+    impls = dispatch._REGISTRY["sdpa_selected"]
+    monkeypatch.setitem(impls, "xla", impls["pallas"])
+
+
+@pytest.fixture(scope="module")
+def wide():
+    """``keye_vl2_tok_tiny`` with the head dim at one lane tile (128, the
+    kernel's: rotary sections and the indexer's dim follow)."""
+    config = dict(TINY, head_dim=128,
+                  rope_scaling=dict(TINY["rope_scaling"],
+                                    mrope_section=[16, 24, 24]),
+                  sa_config=dict(TINY["sa_config"], indexer_head_dim=64))
+    cfg, mcfg = adapters_tokens.build_config(config), rt.model_dict(config)
+    m = cfg.model
+    assert selected_supports(
+        jnp.zeros((1, m.q_chunk, m.num_attention_heads, 128)),
+        *[jnp.zeros((1, 128, m.num_key_value_heads, 128))] * 2,
+        jnp.ones((1, m.q_chunk, 128), bool))
+    return {"cfg": cfg, "mcfg": mcfg, "model": build_model(cfg),
+            "flat": rt.make_params(mcfg, jax.random.PRNGKey(11))()}
+
+
+@pytest.mark.parametrize("core", ["xla", "pallas"])
+def test_counters_of_a_traced_program(tiny, core, request):
+    if core == "pallas":
+        request.getfixturevalue("kernel_forced")
+        tiny = request.getfixturevalue("wide")
     before = RECORDER.counters()
     batch = make_batch(jax.random.PRNGKey(4), 4, 2)
     jax.eval_shape(lambda p: tiny["model"].apply(
@@ -127,9 +160,38 @@ def test_counters_of_a_traced_program(tiny):
     after = RECORDER.counters()
     d = {k: after[k] - before.get(k, 0) for k in after}
     assert d["conditioning.groups"] == 2 and d["conditioning.examples"] == 4
-    # the X-UNet's two, and no counter that nothing reads
+    # one per traced sdpa(keep=) site, a site a layer, by the core it took
+    other = {"xla": "pallas", "pallas": "xla"}[core]
+    assert d[f"sdpa.selected.{core}"] == 2
+    assert not d.get(f"sdpa.selected.{other}")
+    # the X-UNet's two, the selection's two, and no other counter
     assert not [k for k, v in d.items() if v and not k.startswith(
-        ("conditioning.", "compile."))], d
+        ("conditioning.", "compile.", "sdpa.selected."))], d
+
+
+def test_token_test_model_lowers_as_the_parent_did_on_a_cpu_process(
+        monkeypatch):
+    """``auto`` resolves to the XLA expression here: the program of the
+    ``token_test`` preset is, to the letter, the program with the parent's
+    ``sdpa(keep=)`` body in the layer, and holds no Pallas call."""
+    cfg = token_test_config()
+    model = build_model(cfg)
+    batch = make_batch(jax.random.PRNGKey(5), 2, 2)
+    mask = jnp.array([True, False])
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), batch, cond_mask=mask))["params"]
+
+    def text():
+        return jax.jit(lambda p, b, m: model.apply(
+            {"params": p}, b, cond_mask=m)).lower(params, batch,
+                                                  mask).as_text()
+    mine = text()
+    assert "tpu_custom_call" not in mine and "pallas" not in mine
+    monkeypatch.setattr(
+        sparse_attention, "sdpa",
+        lambda q, k, v, keep: jax.nn.dot_product_attention(
+            q, k, v, mask=keep[:, None]))
+    assert mine == text()
 
 
 # ------------------------------------------------- the layers by themselves
@@ -212,6 +274,21 @@ def test_sparse_attention_is_the_gathered_reference_when_keys_are_dropped():
                                np.asarray(literal)[clean], atol=2e-5, rtol=0)
     dense = _run_attention(layer.clone(topk=128), mine, h)
     assert float(jnp.abs(got - dense).max()) > 1e-3       # it does select
+
+
+def test_forward_with_the_kernel_forced_is_the_reference(wide, kernel_forced):
+    """The whole model, float32: every ``sdpa(keep=)`` runs the Pallas
+    core, on the layer's own selection, inside its maps over examples and
+    query tiles."""
+    batch = make_batch(jax.random.PRNGKey(12), 4, 2)
+    mask = jnp.array([True, False])
+    before = RECORDER.counters().get("sdpa.selected.pallas", 0)
+    got = run_program(wide["model"], wide["flat"], batch, mask)
+    assert RECORDER.counters()["sdpa.selected.pallas"] > before
+    ref, _ = jax.jit(lambda p: rt.forward(p, batch, mask, wide["mcfg"]))(
+        wide["flat"])
+    assert float(jnp.abs(ref).mean()) > 0.05
+    np.testing.assert_allclose(got, ref, atol=2e-5, rtol=0)
 
 
 def test_mrope_turns_each_section_by_its_own_axis():
