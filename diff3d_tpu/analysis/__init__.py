@@ -3,9 +3,9 @@
 Four layers, one contract (DESIGN.md §9–12):
 
   * ``analysis.lint`` — graftlint, the AST tracer-hygiene linter
-    (``python -m diff3d_tpu.analysis`` walks diff3d_tpu/, tools/ and
-    bench.py and exits nonzero on unsuppressed findings; tier 1 runs it
-    as a gate);
+    (``python -m diff3d_tpu.analysis`` walks diff3d_tpu/ and tools/
+    and exits nonzero on unsuppressed findings; tier 1 runs it as a
+    gate);
   * ``analysis.ir`` / ``analysis.budgets`` / ``analysis.shardcheck`` —
     the IR-level sharding & communication analyzer: per-program
     collective/dtype/param-placement reports over lowered StableHLO and
@@ -24,8 +24,7 @@ Four layers, one contract (DESIGN.md §9–12):
     running code.
 """
 
-from diff3d_tpu.analysis.ir import (ProgramReport, analyze_jitted,
-                                    analyze_lowered, comms_summary,
+from diff3d_tpu.analysis.ir import (ProgramReport, analyze_lowered,
                                     cost_summary)
 from diff3d_tpu.analysis.lint import (Finding, lint_paths, lint_source,
                                       main)
@@ -41,8 +40,7 @@ from diff3d_tpu.analysis.witness import (LockWitness, WitnessViolation,
 __all__ = [
     "Finding", "lint_paths", "lint_source", "main",
     "lockcheck_paths", "lockcheck_source",
-    "ProgramReport", "analyze_lowered", "analyze_jitted",
-    "comms_summary", "cost_summary",
+    "ProgramReport", "analyze_lowered", "cost_summary",
     "RecompilationSentinel", "CompileBudgetExceeded", "compile_budget",
     "no_host_transfers", "assert_consumed", "assert_live", "owned",
     "LockWitness", "WitnessViolation", "install_witness",

@@ -781,7 +781,8 @@ def verify_hoist(original, hoisted, example_args, *, name: str = "hoist",
 
 
 def semantic_summary(report: SemanticReport) -> dict:
-    """The compact block bench.py embeds next to each perf number."""
+    """The compact block ``equivcheck --format json`` prints per
+    program."""
     return {
         "available": report.available,
         "digest": report.digest or None,

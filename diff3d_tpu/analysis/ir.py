@@ -29,9 +29,7 @@ places:
 
 ``analysis/budgets.py`` checks reports against committed per-program
 budget manifests; ``analysis/shardcheck.py`` is the program registry +
-CLI; ``tools/flops_report.py`` and ``bench.py`` consume
-:func:`cost_summary` / :func:`comms_summary` so perf numbers and comms
-counts come from one extraction path.
+CLI.
 """
 
 from __future__ import annotations
@@ -311,8 +309,8 @@ def param_sharding_table(params_template, actual_shardings,
 
 
 def cost_summary(compiled) -> Dict[str, Optional[float]]:
-    """``{"flops", "bytes_accessed"}`` from XLA cost analysis — the one
-    extraction path shared by flops_report, bench, and the manifests."""
+    """``{"flops", "bytes_accessed"}`` from XLA cost analysis, as the
+    manifests record them (:func:`analyze_lowered`)."""
     try:
         ca = compiled.cost_analysis()
     except Exception:
@@ -400,18 +398,6 @@ def analyze_lowered(name: str, lowered, *, params_template=None,
         semantic=semantic)
 
 
-def analyze_jitted(name: str, fn, *abstract_args, params_template=None,
-                   params_argnum: int = 0,
-                   expected_param_shardings=None) -> ProgramReport:
-    """Lower ``fn`` (anything with ``.lower`` — a jitted callable or the
-    sharded train/distill step wrappers) on abstract args and analyze."""
-    lowered = fn.lower(*abstract_args)
-    return analyze_lowered(
-        name, lowered, params_template=params_template,
-        params_argnum=params_argnum,
-        expected_param_shardings=expected_param_shardings)
-
-
 def abstractify(tree):
     """Pytree of arrays -> matching ``ShapeDtypeStruct`` pytree (lower
     programs without staging real buffers on a device)."""
@@ -419,16 +405,3 @@ def abstractify(tree):
 
     return jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(jax.numpy.shape(x), x.dtype), tree)
-
-
-def comms_summary(report: ProgramReport) -> dict:
-    """The compact block bench.py embeds next to each perf number."""
-    return {
-        "collectives": {op: c.to_json()
-                        for op, c in sorted(report.collectives.items())},
-        "total_collective_bytes": report.total_collective_bytes,
-        "resharding_sites": len(report.resharding_sites),
-        "dtype_upcasts": dict(sorted(report.dtype_upcasts.items())),
-        "host_callbacks": len(report.host_callbacks),
-        "replicated_policy_params": report.replicated_policy_params,
-    }

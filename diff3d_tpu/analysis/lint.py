@@ -43,7 +43,7 @@ SEVERITY_ERROR = "error"
 SEVERITY_WARNING = "warning"
 
 #: Default lint targets, relative to the repo root (ISSUE 8 gate scope).
-DEFAULT_TARGETS = ("diff3d_tpu", "tools", "bench.py")
+DEFAULT_TARGETS = ("diff3d_tpu", "tools")
 DEFAULT_BASELINE = ".graftlint-baseline.json"
 
 _RULE_HEAD_RE = re.compile(r"\s*,?\s*([A-Za-z]+\d+|all)")
@@ -334,7 +334,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                     "docs/DESIGN.md §9)")
     p.add_argument("paths", nargs="*",
                    help="files/dirs to lint (default: diff3d_tpu, "
-                        "tools, bench.py under the repo root)")
+                        "tools under the repo root)")
     p.add_argument("--baseline", default=None,
                    help=f"baseline JSON (default <root>/"
                         f"{DEFAULT_BASELINE} when present)")

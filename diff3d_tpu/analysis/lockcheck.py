@@ -77,7 +77,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                     "docs/DESIGN.md §12)")
     p.add_argument("paths", nargs="*",
                    help="files/dirs to check (default: diff3d_tpu, "
-                        "tools, bench.py under the repo root)")
+                        "tools under the repo root)")
     p.add_argument("--baseline", default=None,
                    help=f"baseline JSON (default <root>/"
                         f"{DEFAULT_BASELINE} when present)")

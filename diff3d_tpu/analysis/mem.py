@@ -39,8 +39,8 @@ compiler actually said, not what the Python source hoped):
 ``analysis/membudgets.py`` diffs :class:`MemoryReport`s against
 committed manifests under ``runs/memcheck/`` (rules MC4xx);
 ``analysis/memcheck.py`` is the CLI over the shardcheck program
-registry; ``bench.py`` and serving ``/stats`` embed
-:func:`memory_summary` blocks next to the comms blocks.
+registry and prints :func:`memory_summary` blocks under
+``--format json``.
 """
 
 from __future__ import annotations
@@ -757,8 +757,8 @@ def analyze_lowered_memory(name: str, lowered) -> MemoryReport:
 
 
 def memory_summary(report: MemoryReport) -> dict:
-    """The compact block bench.py / serving stats embed next to each
-    perf number (mirror of :func:`ir.comms_summary`)."""
+    """The compact block ``memcheck --format json`` prints per
+    program."""
     return {
         "peak_bytes": report.peak_bytes,
         "argument_bytes": report.argument_bytes,

@@ -886,7 +886,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                     "docs/DESIGN.md §17)")
     p.add_argument("paths", nargs="*",
                    help="files/dirs for the static pass (default: "
-                        "diff3d_tpu, tools, bench.py under the repo "
+                        "diff3d_tpu, tools under the repo "
                         "root, plus tests/ for the RC508 guard rule)")
     p.add_argument("--ast-only", action="store_true",
                    help="static rules only (no stream builds, no jax)")

@@ -50,13 +50,6 @@ class ModelConfig:
     # 'ring:<axis>' / 'ulysses:<axis>' for token-sharded attention inside
     # shard_map (long-context scaling; see ops/attention.py).
     attn_impl: str = "auto"
-    # Optional per-resolution-level override of attn_impl (one entry per
-    # ch_mult level; the middle block uses the last entry).  The 128^2
-    # config's attention sites differ sharply by level — L=1024/D=128 at
-    # level 2 vs L=256/D=256 at level 3 + middle — and the best engine
-    # per site is a measured question (tools/profile128.py), not one a
-    # single global attn_impl can answer.
-    attn_impl_levels: Optional[Sequence[str]] = None
     # Kernel backend for the fused GroupNorm->FiLM/SiLU epilogues
     # (ops/pallas_film.py via ops/dispatch.py): 'xla' (default) keeps the
     # plain composition — bit-identical graphs to pre-kernel-layer
@@ -70,14 +63,6 @@ class ModelConfig:
     def num_resolutions(self) -> int:
         return len(self.ch_mult)
 
-    def attn_impl_at(self, i_level: int) -> str:
-        """Attention engine for UNet level ``i_level`` (middle block =
-        deepest level)."""
-        if self.attn_impl_levels is None:
-            return self.attn_impl
-        return self.attn_impl_levels[min(i_level,
-                                         len(self.attn_impl_levels) - 1)]
-
     def validate(self) -> None:
         down = 2 ** (len(self.ch_mult) - 1)
         if self.H % down or self.W % down:
@@ -89,29 +74,16 @@ class ModelConfig:
             raise ValueError(
                 f"remat_policy={self.remat_policy!r} not in "
                 "('nothing', 'dots')")
-        def _impl_ok(impl: str) -> bool:
-            return (impl in ("auto", "pallas", "xla")
-                    or (impl.partition(":")[0] in ("ring", "ulysses")
-                        and bool(impl.partition(":")[2])))
-
         if self.kernels not in ("auto", "pallas", "xla"):
             raise ValueError(
                 f"kernels={self.kernels!r} not in ('auto', 'pallas', "
                 "'xla')")
-        if not _impl_ok(self.attn_impl):
+        core, _, axis = self.attn_impl.partition(":")
+        if not (self.attn_impl in ("auto", "pallas", "xla")
+                or (core in ("ring", "ulysses") and axis)):
             raise ValueError(
                 f"attn_impl={self.attn_impl!r}: expected 'auto', 'pallas', "
                 "'xla', 'ring:<axis>' or 'ulysses:<axis>'")
-        if self.attn_impl_levels is not None:
-            if len(self.attn_impl_levels) != self.num_resolutions:
-                raise ValueError(
-                    f"attn_impl_levels needs {self.num_resolutions} "
-                    f"entries (one per ch_mult level), got "
-                    f"{len(self.attn_impl_levels)}")
-            for impl in self.attn_impl_levels:
-                if not _impl_ok(impl):
-                    raise ValueError(
-                        f"attn_impl_levels entry {impl!r} invalid")
 
 
 @dataclasses.dataclass(frozen=True)

@@ -113,7 +113,7 @@ class XUNet(nn.Module):
                 h = constrain(block_cls(
                     features=dim_out[i_level], use_attn=use_attn,
                     num_heads=cfg.attn_heads, dropout=cfg.dropout,
-                    attn_impl=cfg.attn_impl_at(i_level), dtype=dtype,
+                    attn_impl=cfg.attn_impl, dtype=dtype,
                     kernels=cfg.kernels,
                     name=f"down_{i_level}_{i_block}")(h, emb, deterministic))
                 hs.append(h)
@@ -128,7 +128,7 @@ class XUNet(nn.Module):
         h = constrain(block_cls(
             features=dim_out[-1], use_attn=num_res in cfg.attn_levels,
             num_heads=cfg.attn_heads, dropout=cfg.dropout,
-            attn_impl=cfg.attn_impl_at(num_res - 1), dtype=dtype,
+            attn_impl=cfg.attn_impl, dtype=dtype,
             kernels=cfg.kernels,
             name="middle")(h, level_emb(num_res - 1), deterministic))
 
@@ -143,7 +143,7 @@ class XUNet(nn.Module):
                 h = constrain(block_cls(
                     features=dim_out[i_level], use_attn=use_attn,
                     num_heads=cfg.attn_heads, dropout=cfg.dropout,
-                    attn_impl=cfg.attn_impl_at(i_level), dtype=dtype,
+                    attn_impl=cfg.attn_impl, dtype=dtype,
                     kernels=cfg.kernels,
                     name=f"up_{i_level}_{i_block}")(h, emb, deterministic))
             if i_level != 0:

@@ -50,8 +50,9 @@ def _pallas_supports(q, k, v) -> bool:
 
 
 def _pallas_auto(q, *args) -> bool:
-    """Measured policy (one v5e chip, X-UNet shapes — see tools/tune_train):
-    the Pallas flash kernel zero-pads the head dim to the 128-lane MXU
+    """A rule carried over from the retired set-up's measurement, never
+    re-measured on this chip (ROADMAP.md design item 3): the Pallas
+    flash kernel zero-pads the head dim to the 128-lane MXU
     tile, so at D=32/64 it wastes 4x/2x of every QK^T and PV matmul and
     XLA's fused attention wins; only lane-filling heads (D > 64) with
     sequences long enough that the materialised [L, L] logits' HBM traffic

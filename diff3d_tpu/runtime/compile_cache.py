@@ -1,7 +1,7 @@
 """One place that decides where JAX's persistent compilation cache lives.
 
-Every ``main`` of this repo (the CLIs, ``bench.py``, ``chip_smoke.py``,
-the tools that jit, ``tests/conftest.py``) calls
+Every ``main`` of this repo (the CLIs, ``chip_smoke.py``, the tools
+that jit, ``tests/conftest.py``) calls
 :func:`configure_compile_cache` first thing, so the whole program shares
 one cache and a cold full-width compile is paid once per machine:
 

@@ -51,7 +51,7 @@ import socket
 import struct
 import threading
 import time
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -419,8 +419,12 @@ class RemoteReplica:
                  rpc_timeout_s: float = 10.0,
                  heartbeat_interval_s: float = 0.25,
                  heartbeat_timeout_s: float = 3.0,
-                 max_frame_bytes: int = DEFAULT_MAX_FRAME_BYTES):
+                 max_frame_bytes: int = DEFAULT_MAX_FRAME_BYTES,
+                 clock: Callable[[], float] = time.monotonic):
         self.host, self.port = host, int(port)
+        # The death clock; injectable so tests advance it themselves
+        # instead of racing a short timeout against wall time.
+        self._clock = clock
         self.rpc_timeout_s = float(rpc_timeout_s)
         self.heartbeat_interval_s = float(heartbeat_interval_s)
         self.heartbeat_timeout_s = float(heartbeat_timeout_s)
@@ -435,7 +439,7 @@ class RemoteReplica:
         self._dead = False  # guarded-by: self._lock
         self._dead_reason = ""  # guarded-by: self._lock
         self._hb_timeouts = 0  # guarded-by: self._lock
-        self._last_ok = time.monotonic()  # guarded-by: self._lock
+        self._last_ok = clock()  # guarded-by: self._lock
         self._stop_evt = threading.Event()
         self._wake_evt = threading.Event()
         self._poller: Optional[threading.Thread] = None
@@ -639,9 +643,9 @@ class RemoteReplica:
                 "state", timeout_s=min(self.rpc_timeout_s,
                                        self.heartbeat_timeout_s))
         except TransportError as e:
+            now = self._clock()
             with self._lock:
-                expired = (time.monotonic() - self._last_ok
-                           > self.heartbeat_timeout_s)
+                expired = now - self._last_ok > self.heartbeat_timeout_s
                 if expired and not self._dead:
                     self._dead = True
                     self._dead_reason = f"heartbeat timeout: {e}"
@@ -649,9 +653,10 @@ class RemoteReplica:
             if self._is_dead():
                 log.warning("remote %s: marked dead (%s)", self.name, e)
             return False
+        now = self._clock()
         with self._lock:
             self._state = state
-            self._last_ok = time.monotonic()
+            self._last_ok = now
             return bool(self._inflight)
 
     def _poll_inflight(self) -> bool:

@@ -156,8 +156,8 @@ def make_train_step(model, cfg: Config, env: MeshEnv | None = None,
             return _jitted(state, batch)(state, batch, rng)
 
     # The sharded path jits lazily inside this closure; expose the same
-    # ``.lower`` the env=None jit has so analysis tooling (shardcheck,
-    # flops_report) can lower the REAL sharded program on abstract args
+    # ``.lower`` the env=None jit has so analysis tooling (shardcheck)
+    # can lower the REAL sharded program on abstract args
     # (ShapeDtypeStructs work — the sharding pytrees only map leaves).
     sharded_step.lower = (
         lambda state, batch, rng: _jitted(state, batch).lower(

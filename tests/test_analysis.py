@@ -10,8 +10,7 @@ accuracy under a forced retrace, the compile-budget marker, the transfer
 guard, and the donation guards against a real donating jit.
 
 The last test is the tier-1 gate itself: the repo's own lint run must be
-clean (zero unsuppressed findings over ``diff3d_tpu/``, ``tools/``,
-``bench.py``).
+clean (zero unsuppressed findings over ``diff3d_tpu/`` and ``tools/``).
 """
 
 import os

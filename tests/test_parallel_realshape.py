@@ -6,9 +6,9 @@ sharded == replicated at toy geometry (imgsize 8-16).  GSPMD conv halo
 exchanges and GroupNorm reductions are shape-sensitive: a halo that is
 correct at 16x16 with 2-row shards can still be wrong at 64x64 where
 downsampling produces 64->32->16->8 feature maps whose shard boundaries
-fall differently.  These slow-marked tests run the real srn64 spatial
-geometry (H=W=64, the full (1,2,2,4) ch_mult, attention at levels
-2/3/4) with reduced channel width — halos and reductions depend on
+fall differently.  These tests (the whole train step slow-marked) run
+the real srn64 spatial geometry (H=W=64, the full (1,2,2,4) ch_mult,
+attention at levels 2/3/4) with reduced channel width — halos and reductions depend on
 spatial dims and block structure, not on channel count.
 
 Reference hot spot being re-derived: 4096-token attention at 64^2
@@ -90,7 +90,6 @@ def test_cp_train_step_matches_replicated_at_srn64_shapes():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
 
 
-@pytest.mark.slow
 def test_cp_forward_matches_unsharded_at_srn64_shapes():
     """Plain forward (no optimizer) under context-parallel activation
     constraints at 64x64 == unsharded forward, to fp32 tolerance —
@@ -129,7 +128,6 @@ def test_cp_forward_matches_unsharded_at_srn64_shapes():
                                atol=1e-4, rtol=1e-4)
 
 
-@pytest.mark.slow
 @pytest.mark.parametrize("core,n_shards", [("ring", 8), ("ulysses", 4)])
 def test_seq_parallel_attention_at_srn64_token_count(core, n_shards):
     """Ring / Ulysses attention over the REAL srn64 token count — L=4096

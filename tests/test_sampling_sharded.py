@@ -104,10 +104,6 @@ def test_sharded_synthesize_many_pads_to_lane_multiple(setup):
     np.testing.assert_allclose(got, ref, atol=1e-5, rtol=1e-5)
 
 
-# Tier-1 budget: identical claim to the data=2 pad test above (the pad
-# mask / lane-drop path is mesh-size-independent); this variant only
-# adds the all-8-device sampler-mesh compile, ~16s of tier-1 wall.
-@pytest.mark.slow
 def test_sharded_synthesize_many_pads_full_mesh(setup):
     """N=3 objects on the full 8-device data mesh: pad 3 -> 8."""
     cfg, model, params, ds = setup

@@ -573,7 +573,6 @@ def test_trajectory_http_poll_and_ndjson_stream(traj_env, lock_witness):
         service.stop()
 
 
-@pytest.mark.slow
 @pytest.mark.lock_witness
 def test_trajectory_cobatches_with_view_requests(traj_env, lock_witness):
     """Interleaving: a trajectory and a plain view request in the same

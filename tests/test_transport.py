@@ -450,11 +450,26 @@ def test_heartbeat_timeout_is_terminal_death_with_typed_session_lost(
     SessionLost naming it, and later submits are EngineStopped — never
     a hang."""
     fake = BootableFake("w-dead")          # never resolves
+    # The death clock is the test's: no probe can time out on a busy
+    # machine (30 s), and silence turns into death when the test says
+    # the timeout has passed, not when wall time does.
+    now = [0.0]
     worker, remote = _worker_pair(fake, heartbeat_interval_s=0.05,
-                                  heartbeat_timeout_s=0.4)
+                                  heartbeat_timeout_s=30.0,
+                                  clock=lambda: now[0])
     try:
         req = remote.submit(_req(session_id="s-lost", seed=7))
+        # The ledger the audit falls back on is the last heartbeat's
+        # copy: let one probe see the session before the worker dies.
+        _wait_for(lambda: remote._cached("session_records") == {"s-lost": 1},
+                  what="a heartbeat that saw the session")
         worker.stop()                      # abrupt close: SIGKILL shape
+        remote.depth()                     # the control connection fails too
+        _wait_for(lambda: not remote.transport_stats()["connected"],
+                  what="failed probes")
+        assert remote.health != "dead"     # silent, not yet past the timeout
+        assert not req.done()
+        now[0] += 30.1
         with pytest.raises(SessionLost) as ei:
             req.result(timeout=10)
         assert ei.value.replica == "w-dead"

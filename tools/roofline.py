@@ -145,12 +145,8 @@ MATMUL_SHAPES = [
     (16384, 4096, 4096),
 ]
 # X-UNet conv shape classes (B = microbatch * 2 frames folded together,
-# as the model runs them).  The first two are the srn64 bench step's
-# level-0/level-1 shapes at its microbatch of 64; measured (committed
-# runs/roofline_r4.json): 34.9 and 37.9 TFLOP/s against 136.6 for big
-# matmuls, while the wide 256ch/64^2/B=128 shape reaches 85 — so the
-# model's own levels cap near 35-38 and a train step at ~38 TFLOP/s is
-# at its op-mix ceiling, far though that is from the matmul roofline.
+# as the model runs them): srn64's level-0 / level-1 shapes at a
+# microbatch of 64 and two 256-channel shapes of the srn128 class.
 CONV_SHAPES = [
     (128, 64, 64, 128, 128, 3),    # srn64 level 0 (ch=128) @ microbatch 64
     (128, 32, 32, 256, 256, 3),    # srn64 level 1
@@ -159,9 +155,17 @@ CONV_SHAPES = [
 ]
 
 
-#: Datasheet bf16 peak (TFLOP/s) by ``device_kind``.  A TPU that is not
-#: in the table is an error, not a v5e.
-PEAK_BF16_TFLOPS_BY_KIND = {"TPU v5 lite": 197.0}  # Google Cloud, "TPU v5e"
+def datasheet_peak_bf16_tflops(device_kind: str) -> float:
+    """The chip's datasheet bf16 peak from ``benchmark/peaks.json``, the
+    one table (keyed by ``device_kind``, with its source).  A TPU that
+    is not in it is an error, not a v5e."""
+    with open(os.path.join(_REPO_ROOT, "benchmark", "peaks.json")) as f:
+        peaks = json.load(f)
+    if device_kind not in peaks:
+        raise SystemExit(
+            f"roofline: no peak on file for device_kind {device_kind!r}; "
+            "add it to benchmark/peaks.json with its source")
+    return peaks[device_kind]["flops_per_s"] / 1e12
 
 
 def main() -> None:
@@ -177,14 +181,8 @@ def main() -> None:
     configure_compile_cache()
 
     dev = jax.devices()[0]
-    peak = None
-    if dev.platform == "tpu":
-        if dev.device_kind not in PEAK_BF16_TFLOPS_BY_KIND:
-            raise SystemExit(
-                f"roofline: no bf16 peak on file for device_kind "
-                f"{dev.device_kind!r}; add it to PEAK_BF16_TFLOPS_BY_KIND "
-                "with its source")
-        peak = PEAK_BF16_TFLOPS_BY_KIND[dev.device_kind]
+    peak = (datasheet_peak_bf16_tflops(dev.device_kind)
+            if dev.platform == "tpu" else None)
     result = {
         "device": str(dev), "platform": dev.platform,
         "device_kind": dev.device_kind,
