@@ -12,16 +12,26 @@ No token is dropped: there is no capacity.
 How the experts' matmuls are laid out (:func:`expert_outputs`): the
 ``T x k`` assignments of a chunk of ``T`` tokens are sorted by expert and
 each expert's run is padded to a multiple of ``block`` rows, so that
-every block of rows belongs to one expert; a scan over the blocks
-multiplies each by that expert's three matrices, read by a dynamic index
-into the stacked weights (the number of blocks is a static bound, ``T k /
-block + experts``, so a call's time does not depend on how the tokens
-were routed).  Rows come in by one gather and go back by one
-gather and a gated sum over the ``k`` slots; the ``[T x k, D]`` dispatch
-buffer exists for one chunk at a time (:class:`RoutedExperts` maps over
-chunks of ``token_chunk`` tokens).  Everything is plain XLA, the same on
-the CPU and the TPU, differentiable, and indifferent to ``vmap`` (the
-sampler maps its view program over objects).
+every block of rows belongs to one expert; each block is multiplied by
+that expert's three matrices (the number of blocks is a static bound,
+``T k / block + experts``, which covers any routing: no capacity, and a
+call's shape does not depend on how the tokens were routed).  Rows come
+in by one gather and go back by one gather and a gated sum over the ``k``
+slots; the ``[T x k, D]`` dispatch buffer exists for one chunk at a time
+(:class:`RoutedExperts` maps over chunks of ``token_chunk`` tokens).
+
+The blocks' matmuls are op ``'expert_ffn'`` of the kernel registry
+(:mod:`diff3d_tpu.ops.pallas_moe`), two cores chosen from what the
+process and the shapes are, by no option: on a TPU process, where the
+widths are whole lane tiles, one grouped Pallas kernel that fetches an
+expert's matrices once per run of blocks, fuses gate, up and down per
+block and skips the blocks of the bound past the last run; everywhere
+else (CPU processes: tests, the analysis passes, ``token_test``) a
+``lax.scan`` over all the blocks, which is also the kernel's gradient.
+The sort, the layout's index arithmetic, the gathers and the gated sum
+are plain XLA on both.  Either way the layer is differentiable and
+indifferent to ``vmap`` (the sampler maps its view program over
+objects).
 """
 
 from __future__ import annotations
@@ -32,7 +42,9 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from diff3d_tpu.utils.profiling import scope
+from diff3d_tpu.ops import dispatch
+from diff3d_tpu.ops import pallas_moe  # noqa: F401 - registers 'expert_ffn'
+from diff3d_tpu.utils.profiling import count, scope
 
 
 def rms_norm(x: jnp.ndarray, scale: jnp.ndarray, eps: float) -> jnp.ndarray:
@@ -53,8 +65,8 @@ def route(logits: jnp.ndarray, k: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
 
 def expert_outputs(x: jnp.ndarray, ids: jnp.ndarray, gates: jnp.ndarray,
                    w_gate: jnp.ndarray, w_up: jnp.ndarray,
-                   w_down: jnp.ndarray, *, first: int,
-                   block: int) -> jnp.ndarray:
+                   w_down: jnp.ndarray, *, first: int, block: int,
+                   impl: str = "auto") -> jnp.ndarray:
     """Gated sum of the held experts' outputs for one chunk of tokens.
 
     ``x [T, D]`` (compute dtype), ``ids / gates [T, k]`` as :func:`route`
@@ -62,6 +74,9 @@ def expert_outputs(x: jnp.ndarray, ids: jnp.ndarray, gates: jnp.ndarray,
     ``w_down [E, F, D]`` the held experts ``first .. first + E - 1``, in
     the compute dtype.
     Expert ``e``: ``w_down_e (silu(w_gate_e x) * w_up_e x)``.
+    ``impl`` ('auto' | 'pallas' | 'xla') is the request to the registry
+    for the blocks' matmuls; the layer leaves it at 'auto'.  Each traced
+    site adds 1 to the recorder's ``experts.pallas`` or ``experts.xla``.
     """
     T, D = x.shape
     K = ids.shape[1]
@@ -80,8 +95,9 @@ def expert_outputs(x: jnp.ndarray, ids: jnp.ndarray, gates: jnp.ndarray,
 
     # the padded layout: block b holds rows of one expert only
     n_blocks = -(-A // m) + E                        # a bound, static
-    e_blk = (jnp.arange(n_blocks)[:, None] * m
-             >= (poff + padded)[None, :]).sum(axis=1)
+    blk_start = jnp.arange(n_blocks)[:, None] * m
+    ends = poff + padded                             # run ends, padded
+    e_blk = (blk_start >= ends[None, :]).sum(axis=1)
     # a block past the last run counts as the last expert's: its rows lie
     # beyond that run's padding, so none of them is valid
     e_blk = jnp.minimum(e_blk, E - 1)
@@ -95,17 +111,12 @@ def expert_outputs(x: jnp.ndarray, ids: jnp.ndarray, gates: jnp.ndarray,
     x0 = jnp.concatenate([x, jnp.zeros((1, D), x.dtype)])
     rows = x0[token].reshape(n_blocks, m, D)
 
-    def one_block(_, inp):
-        xb, e = inp
-        f32 = jnp.float32
-        h = (jax.nn.silu(jnp.dot(xb, w_gate[e], preferred_element_type=f32))
-             * jnp.dot(xb, w_up[e], preferred_element_type=f32))
-        return None, jnp.dot(h.astype(xb.dtype), w_down[e])
-
-    # every block of the static bound is computed: one past the last run
-    # holds zero rows and gives zeros (no bias), and under the sampler's
-    # vmap a ``lax.cond`` that skipped it would run both branches anyway
-    _, ys = jax.lax.scan(one_block, None, (rows, e_blk))
+    core = dispatch.resolve("expert_ffn", impl, rows, e_blk, ends,
+                            w_gate, w_up, w_down)
+    count(f"experts.{core.name}")
+    ys = core.fn(rows, e_blk, ends, w_gate, w_up, w_down)
+    # (the kernel leaves the blocks past the last run unwritten, where the
+    # scan gives zeros; ``at`` below points into runs only)
     # back: assignment a sits at sorted position pos[a], and in the padded
     # layout a run is shifted as a whole, so the shift is looked up with
     # a one-hot product, not a gather per assignment

@@ -20,6 +20,12 @@ The token denoiser has one kernel site, ``sdpa(keep=)`` of
 keys of an example (4 heads) at head dim 128, vmapped over the sampler's
 objects (PR 27).
 
+Its second is the routed experts' block kernel, ``expert_ffn`` of one
+chunk of 8192 tokens: 384 blocks of 256 rows x 2048 through 128 experts
+of 2048 x 768, alone, under the sampler's ``vmap`` over one object (the
+cell's: the prefetched tables lose the axis) and over two (jax loops),
+and with the scan's VJP behind it (PR 29).
+
 Fixture rules (on-chip-measurement guide, section 2): the topology is
 described inside a module-scoped, non-autouse fixture of THIS file, which
 skips where it cannot be described — never at import, in ``skipif``, in
@@ -39,6 +45,7 @@ from jax.sharding import SingleDeviceSharding
 from diff3d_tpu.ops.pallas_attention import (flash_attention,
                                              selected_attention)
 from diff3d_tpu.ops.pallas_film import fused_groupnorm
+from diff3d_tpu.ops.pallas_moe import expert_ffn
 
 F32, BF16 = "float32", "bfloat16"
 
@@ -237,3 +244,53 @@ def test_selected_attention_gradient_compiles_for_v5e(
         return jnp.sum(_selected(q, k, v, keep).astype(jnp.float32) ** 2)
 
     _compile_for_chip(jax.grad(loss, argnums=(0, 1, 2)), q, k, v, keep)
+
+
+# keye_vl2_tok128's expert_outputs site: (blocks, rows, D, experts, F)
+EXPERT_SITE = (384, 256, 2048, 128, 768)
+
+
+def _expert_operands(one_chip, dtype, lead=()):
+    n, m, D, E, F = EXPERT_SITE
+
+    def sds(shape, dt, lead=lead):
+        return jax.ShapeDtypeStruct(lead + shape, jnp.dtype(dt),
+                                    sharding=one_chip)
+    return (sds((n, m, D), dtype), sds((n,), "int32"), sds((E,), "int32"),
+            *[sds(s, dtype, ()) for s in [(E, D, F), (E, D, F), (E, F, D)]])
+
+
+def _experts(rows, e_blk, ends, w_gate, w_up, w_down):
+    return expert_ffn(rows, e_blk, ends, w_gate, w_up, w_down,
+                      interpret=False)
+
+
+@pytest.mark.parametrize("dtype", [BF16, F32])
+def test_expert_ffn_forward_compiles_for_v5e(one_chip, no_persistent_cache,
+                                             dtype):
+    compiled = _compile_for_chip(_experts, *_expert_operands(one_chip, dtype))
+    # gate and up stay on chip: nothing of [.., 256, 768] in the program
+    assert not re.search(r"(f32|bf16)\[[\d,]*256,768\]", compiled.as_text())
+
+
+@pytest.mark.parametrize("objects", [1, 2])
+def test_expert_ffn_under_the_samplers_vmap_compiles_for_v5e(
+        one_chip, no_persistent_cache, objects):
+    """Rows and the prefetched tables carry the object axis, the experts'
+    matrices do not."""
+    _compile_for_chip(
+        jax.vmap(_experts, in_axes=(0, 0, 0, None, None, None)),
+        *_expert_operands(one_chip, BF16, lead=(objects,)))
+
+
+def test_expert_ffn_gradient_compiles_for_v5e(one_chip, no_persistent_cache):
+    """The token train step's pair: the kernel forward, the scan's VJP
+    backward."""
+    def grads(rows, e_blk, ends, *w):
+        def loss(rows, w):
+            # squared, so that the backward needs the kernel's output
+            return jnp.sum(_experts(rows, e_blk, ends, *w).astype(
+                jnp.float32) ** 2)
+        return jax.grad(loss, argnums=(0, 1))(rows, w)
+
+    _compile_for_chip(grads, *_expert_operands(one_chip, BF16))

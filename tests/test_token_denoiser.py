@@ -31,6 +31,7 @@ from diff3d_tpu.models import (TokenDenoiser, UnsupportedModelError, XUNet,
 from diff3d_tpu.models import moe, sparse_attention  # noqa: E402
 from diff3d_tpu.ops import dispatch  # noqa: E402
 from diff3d_tpu.ops.pallas_attention import selected_supports  # noqa: E402
+from diff3d_tpu.ops.pallas_moe import expert_ffn_supports  # noqa: E402
 from diff3d_tpu.utils.profiling import RECORDER  # noqa: E402
 
 with open(os.path.join(ROOT, "benchmark", "configs",
@@ -123,17 +124,21 @@ def test_g_rows_equal_repeated_rows_and_must_divide(tiny):
 @pytest.fixture
 def kernel_forced(monkeypatch):
     """The registry's policy patched to what it resolves on a TPU process:
-    ``sdpa(keep=)`` at ``impl='auto'`` takes the Pallas core (in interpret
-    mode, this being a CPU process).  No option of the program does this."""
-    impls = dispatch._REGISTRY["sdpa_selected"]
-    monkeypatch.setitem(impls, "xla", impls["pallas"])
+    ``sdpa(keep=)`` and the experts' blocks at ``impl='auto'`` take their
+    Pallas cores (in interpret mode, this being a CPU process).  No
+    option of the program does this."""
+    for op in ("sdpa_selected", "expert_ffn"):
+        impls = dispatch._REGISTRY[op]
+        monkeypatch.setitem(impls, "xla", impls["pallas"])
 
 
 @pytest.fixture(scope="module")
 def wide():
-    """``keye_vl2_tok_tiny`` with the head dim at one lane tile (128, the
-    kernel's: rotary sections and the indexer's dim follow)."""
-    config = dict(TINY, head_dim=128,
+    """``keye_vl2_tok_tiny`` with the head dim, the hidden size and the
+    experts' width at one lane tile (128, the kernels': rotary sections
+    and the indexer's dim follow)."""
+    config = dict(TINY, head_dim=128, hidden_size=128,
+                  moe_intermediate_size=128,
                   rope_scaling=dict(TINY["rope_scaling"],
                                     mrope_section=[16, 24, 24]),
                   sa_config=dict(TINY["sa_config"], indexer_head_dim=64))
@@ -143,6 +148,10 @@ def wide():
         jnp.zeros((1, m.q_chunk, m.num_attention_heads, 128)),
         *[jnp.zeros((1, 128, m.num_key_value_heads, 128))] * 2,
         jnp.ones((1, m.q_chunk, 128), bool))
+    sds = jax.ShapeDtypeStruct
+    assert expert_ffn_supports(
+        sds((9, m.expert_block, 128), jnp.float32), sds((9,), jnp.int32),
+        sds((8,), jnp.int32), *[sds((8, 128, 128), jnp.float32)] * 3)
     return {"cfg": cfg, "mcfg": mcfg, "model": build_model(cfg),
             "flat": rt.make_params(mcfg, jax.random.PRNGKey(11))()}
 
@@ -164,16 +173,21 @@ def test_counters_of_a_traced_program(tiny, core, request):
     other = {"xla": "pallas", "pallas": "xla"}[core]
     assert d[f"sdpa.selected.{core}"] == 2
     assert not d.get(f"sdpa.selected.{other}")
-    # the X-UNet's two, the selection's two, and no other counter
+    # and one per traced expert_outputs site, the chunk map's body: a
+    # layer
+    assert d[f"experts.{core}"] == 2
+    assert not d.get(f"experts.{other}")
+    # the X-UNet's two, the selection's two, the experts' two, no other
     assert not [k for k, v in d.items() if v and not k.startswith(
-        ("conditioning.", "compile.", "sdpa.selected."))], d
+        ("conditioning.", "compile.", "sdpa.selected.", "experts."))], d
 
 
 def test_token_test_model_lowers_as_the_parent_did_on_a_cpu_process(
         monkeypatch):
-    """``auto`` resolves to the XLA expression here: the program of the
+    """``auto`` resolves to the XLA cores here: the program of the
     ``token_test`` preset is, to the letter, the program with the parent's
-    ``sdpa(keep=)`` body in the layer, and holds no Pallas call."""
+    ``sdpa(keep=)`` body and the parent's block scan in the layer, and
+    holds no Pallas call."""
     cfg = token_test_config()
     model = build_model(cfg)
     batch = make_batch(jax.random.PRNGKey(5), 2, 2)
@@ -191,7 +205,22 @@ def test_token_test_model_lowers_as_the_parent_did_on_a_cpu_process(
         sparse_attention, "sdpa",
         lambda q, k, v, keep: jax.nn.dot_product_attention(
             q, k, v, mask=keep[:, None]))
+
+    def parents_scan(rows, e_blk, ends, w_gate, w_up, w_down):
+        def one_block(_, inp):
+            xb, e = inp
+            f32 = jnp.float32
+            h = (jax.nn.silu(jnp.dot(xb, w_gate[e],
+                                     preferred_element_type=f32))
+                 * jnp.dot(xb, w_up[e], preferred_element_type=f32))
+            return None, jnp.dot(h.astype(xb.dtype), w_down[e])
+        return jax.lax.scan(one_block, None, (rows, e_blk))[1]
+    took = []
+    monkeypatch.setattr(
+        moe.dispatch, "resolve", lambda op, impl, *a: took.append(
+            (op, impl)) or dispatch.KernelImpl(op, "xla", parents_scan))
     assert mine == text()
+    assert took == [("expert_ffn", "auto")] * 2
 
 
 # ------------------------------------------------- the layers by themselves
@@ -277,14 +306,15 @@ def test_sparse_attention_is_the_gathered_reference_when_keys_are_dropped():
 
 
 def test_forward_with_the_kernel_forced_is_the_reference(wide, kernel_forced):
-    """The whole model, float32: every ``sdpa(keep=)`` runs the Pallas
-    core, on the layer's own selection, inside its maps over examples and
-    query tiles."""
+    """The whole model, float32: every ``sdpa(keep=)`` and every chunk of
+    routed tokens runs its Pallas core, on the layer's own selection and
+    routing, inside its maps over examples, query tiles and chunks."""
     batch = make_batch(jax.random.PRNGKey(12), 4, 2)
     mask = jnp.array([True, False])
-    before = RECORDER.counters().get("sdpa.selected.pallas", 0)
+    before = RECORDER.counters()
     got = run_program(wide["model"], wide["flat"], batch, mask)
-    assert RECORDER.counters()["sdpa.selected.pallas"] > before
+    for name in ("sdpa.selected.pallas", "experts.pallas"):
+        assert RECORDER.counters()[name] > before.get(name, 0)
     ref, _ = jax.jit(lambda p: rt.forward(p, batch, mask, wide["mcfg"]))(
         wide["flat"])
     assert float(jnp.abs(ref).mean()) > 0.05
@@ -371,6 +401,75 @@ def test_a_token_that_is_not_finite_stays_its_own(bad):
     got = layer.apply({"params": p}, h.at[0, 0].set(bad), jnp.ones((64,)))
     assert not bool(jnp.isfinite(got[0]).all())
     np.testing.assert_array_equal(np.asarray(got[1:]), np.asarray(clean[1:]))
+
+
+def _lane_layer(held, seed=0):
+    """A ``RoutedExperts`` at one lane tile of hidden size and expert
+    width (what the experts' kernel takes), its parameters cut to the
+    experts held, and two chunks of tokens."""
+    layer = moe.RoutedExperts(num_experts=8, top_k=2, width=128, held=held,
+                              token_chunk=128, block=16)
+    h = jax.random.normal(jax.random.PRNGKey(seed), (256, 128))
+    whole = moe.RoutedExperts(
+        num_experts=8, top_k=2, width=128, held=(0, 8), token_chunk=128,
+        block=16).init(jax.random.PRNGKey(seed + 1), h,
+                       jnp.ones((128,)))["params"]
+    first, count = held
+    cut = {k: (v if k == "router" else v[first:first + count])
+           for k, v in whole.items()}
+    return layer, cut, h
+
+
+@pytest.mark.parametrize("held", [(0, 8), (0, 4), (4, 4), (7, 1)])
+def test_expert_layer_with_the_kernel_forced_is_the_layer(held, request):
+    """The layer as the token denoiser calls it (norm, routing, chunks of
+    tokens under ``lax.map``), all experts held or a share of them, alone
+    and under the sampler's ``vmap`` over two objects."""
+    layer, p, h = _lane_layer(held)
+    scale = jnp.ones((128,))
+    two = jnp.stack([h, h[::-1]])
+
+    def both():                     # traced anew at each call
+        run = lambda p, h: layer.apply({"params": p}, h, scale) - h  # noqa
+        return run(p, h), jax.vmap(run, in_axes=(None, 0))(p, two)
+    want, want_two = both()
+    before = RECORDER.counters().get("experts.pallas", 0)
+    request.getfixturevalue("kernel_forced")
+    got, got_two = both()
+    assert RECORDER.counters()["experts.pallas"] == before + 2
+    assert float(jnp.abs(want).mean()) > 0.003
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
+    np.testing.assert_allclose(got_two, want_two, atol=2e-5, rtol=0)
+    np.testing.assert_allclose(got_two[1], want[::-1], atol=2e-5, rtol=0)
+
+
+def test_expert_shares_add_up_with_the_kernel_forced(kernel_forced):
+    scale = jnp.ones((128,))
+    parts = []
+    for held in [(0, 8), (0, 3), (3, 5)]:
+        layer, p, h = _lane_layer(held)
+        parts.append(layer.apply({"params": p}, h, scale) - h)
+    whole, lo, hi = parts
+    assert float(jnp.abs(lo).mean()) > 0.003 < float(jnp.abs(hi).mean())
+    np.testing.assert_allclose(lo + hi, whole, atol=2e-5, rtol=0)
+
+
+def test_expert_layer_gradient_with_the_kernel_forced_is_the_layers(
+        request):
+    """The token train step's pair: forward through the kernel, backward
+    through the scan's VJP."""
+    layer, p, h = _lane_layer((0, 8), seed=2)
+    scale = jnp.ones((128,))
+
+    def loss(p, h):
+        return jnp.sum(layer.apply({"params": p}, h, scale) ** 2)
+    want = jax.grad(loss, argnums=(0, 1))(p, h)
+    request.getfixturevalue("kernel_forced")
+    got = jax.grad(loss, argnums=(0, 1))(p, h)
+    for g, r in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert float(jnp.abs(r).mean()) > 1e-4
+        np.testing.assert_allclose(g, r, atol=2e-4, rtol=1e-4)
 
 
 # ------------------------------------------------------ sampler and trainer
