@@ -19,6 +19,8 @@ from __future__ import annotations
 import argparse
 import logging
 
+from diff3d_tpu.config import NAMED_CONFIGS
+
 
 def build_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(description=__doc__)
@@ -26,7 +28,7 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--out", required=True,
                    help="Orbax checkpoint root to write")
     p.add_argument("--config",
-                   choices=["srn64", "srn128", "test", "token_test"],
+                   choices=list(NAMED_CONFIGS),
                    default="srn64")
     p.add_argument("--step", type=int, default=None,
                    help="override the step recorded in the checkpoint")

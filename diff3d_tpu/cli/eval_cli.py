@@ -52,6 +52,7 @@ from diff3d_tpu.cli._common import (add_model_width_args,
                                     apply_model_width_overrides,
                                     build_abstract_state,
                                     load_eval_params)
+from diff3d_tpu.config import NAMED_CONFIGS
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -89,7 +90,7 @@ def build_parser() -> argparse.ArgumentParser:
     add_model_width_args(p)
     p.add_argument("--picklefile", default=None)
     p.add_argument("--config",
-                   choices=["srn64", "srn128", "test", "token_test"],
+                   choices=list(NAMED_CONFIGS),
                    default="srn64")
     p.add_argument("--objects", type=int, default=8,
                    help="number of val objects to evaluate")

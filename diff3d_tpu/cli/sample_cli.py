@@ -20,6 +20,7 @@ from diff3d_tpu.cli._common import (add_model_width_args,
                                     apply_model_width_overrides,
                                     build_abstract_state,
                                     load_eval_params)
+from diff3d_tpu.config import NAMED_CONFIGS
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -30,7 +31,7 @@ def build_parser() -> argparse.ArgumentParser:
                    help="SRN object dir with rgb/ pose/ intrinsics/")
     p.add_argument("--out", default="sampling")
     p.add_argument("--config",
-                   choices=["srn64", "srn128", "test", "token_test"],
+                   choices=list(NAMED_CONFIGS),
                    default="srn64")
     p.add_argument("--steps", type=int, default=None,
                    help="diffusion steps (reference: 256)")

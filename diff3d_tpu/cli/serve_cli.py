@@ -29,6 +29,7 @@ from diff3d_tpu.cli._common import (add_model_width_args,
                                     apply_model_width_overrides,
                                     build_abstract_state,
                                     load_eval_params)
+from diff3d_tpu.config import NAMED_CONFIGS
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -42,7 +43,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "for smoke tests and load benches, no --model "
                         "needed")
     p.add_argument("--config",
-                   choices=["srn64", "srn128", "test", "token_test"],
+                   choices=list(NAMED_CONFIGS),
                    default="srn64")
     p.add_argument("--host", default=None,
                    help="bind address (default: config, 127.0.0.1)")

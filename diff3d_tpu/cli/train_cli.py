@@ -21,6 +21,7 @@ import logging
 
 from diff3d_tpu.cli._common import (add_model_width_args,
                                     apply_model_width_overrides)
+from diff3d_tpu.config import NAMED_CONFIGS
 
 
 def _step_range(text: str) -> tuple:
@@ -52,7 +53,7 @@ def build_parser() -> argparse.ArgumentParser:
                    help="object index pickle; regenerated by globbing when "
                         "absent")
     p.add_argument("--config",
-                   choices=["srn64", "srn128", "test", "token_test"],
+                   choices=list(NAMED_CONFIGS),
                    default="srn64")
     p.add_argument("--batch", type=int, default=None,
                    help="global batch (default from config; reference runs "

@@ -90,12 +90,21 @@ class ModelConfig:
 class TokenModelConfig:
     """The token denoiser (``models/token_denoiser.py``): both frames as
     one sequence of ``patch x patch`` patches through pre-norm decoder
-    layers of grouped-query attention over the keys a lightning indexer
-    selects, and routed experts.  Field names follow the public language
-    model configs such blocks come from (``hidden_size``, ``num_experts``,
-    ...), so a benchmark configuration file maps onto this one key for
-    key; the defaults are the widths of ``benchmark/configs/
-    keye_vl2_tok128.json``.
+    layers, each a sequence mixer and a feed-forward.  Field names follow
+    the public language model configs such blocks come from
+    (``hidden_size``, ``num_experts``, ``layer_types``, ...), so a
+    benchmark configuration file maps onto this one key for key; the
+    defaults are the widths of ``benchmark/configs/keye_vl2_tok128.json``.
+
+    Which mixer a layer gets is its entry of ``layer_types``:
+    ``"sparse_attention"`` (every layer when the pattern is empty),
+    grouped-query attention with per-head RMSNorm and mRoPE over the
+    ``indexer_topk`` keys a lightning indexer selects; ``"attention"``,
+    grouped-query attention over all keys with no position of any kind
+    and scores times ``attention_multiplier``; ``"mamba"``, a Mamba-2
+    state-space mixer (``mamba_*``; one group, conv bias, no projection
+    bias).  Which feed-forward: routed experts where ``num_experts > 0``,
+    else a dense gated MLP of ``shared_intermediate_size``.
 
     ``experts_held`` is ``(first, count)``: the router scores all
     ``num_experts``, the layer holds the weights of experts ``first ..
@@ -122,6 +131,24 @@ class TokenModelConfig:
     indexer_num_heads: int = 16
     indexer_head_dim: int = 64
     indexer_topk: int = 2048
+    # One mixer kind per layer; () is "sparse_attention" throughout.
+    layer_types: Sequence[str] = ()
+    mamba_n_heads: int = 64
+    mamba_d_head: int = 64
+    mamba_d_state: int = 128
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2          # n_heads * d_head = expand * hidden_size
+    mamba_n_groups: int = 1
+    mamba_chunk_size: int = 256    # tokens of one chunk of the scan
+    shared_intermediate_size: int = 0
+    # The four scalars of a block with a rescaled residual path: on the
+    # embedding's sum, on each half-layer's output before its residual
+    # add, on the "attention" layers' scores (None: head_dim^-1/2), under
+    # the head's output.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    logits_scaling: float = 1.0
     # Tile sizes, not semantics: queries of one example attended at a
     # time; tokens routed at a time; rows of one expert's matmul.
     q_chunk: int = 512
@@ -135,6 +162,12 @@ class TokenModelConfig:
     def tokens(self) -> int:
         return 2 * (self.H // self.patch) * (self.W // self.patch)
 
+    @property
+    def mixers(self) -> Tuple[str, ...]:
+        """The mixer kind of every layer, the empty pattern filled in."""
+        return (tuple(self.layer_types)
+                or ("sparse_attention",) * self.num_hidden_layers)
+
     def validate(self) -> None:
         if self.H % self.patch or self.W % self.patch:
             raise ValueError(
@@ -144,6 +177,44 @@ class TokenModelConfig:
             raise ValueError(
                 f"num_key_value_heads={self.num_key_value_heads} must "
                 f"divide num_attention_heads={self.num_attention_heads}")
+        kinds = self.mixers
+        unknown = set(kinds) - {"sparse_attention", "attention", "mamba"}
+        if unknown or len(kinds) != self.num_hidden_layers:
+            raise ValueError(
+                f"layer_types={kinds} must name 'sparse_attention', "
+                f"'attention' or 'mamba' for each of the "
+                f"{self.num_hidden_layers} layers")
+        if "mamba" in kinds:
+            if (self.mamba_n_heads * self.mamba_d_head
+                    != self.mamba_expand * self.hidden_size):
+                raise ValueError(
+                    f"mamba_n_heads * mamba_d_head = "
+                    f"{self.mamba_n_heads * self.mamba_d_head} must be "
+                    f"mamba_expand * hidden_size = "
+                    f"{self.mamba_expand * self.hidden_size}")
+            if self.mamba_n_groups != 1:
+                raise ValueError(
+                    f"mamba_n_groups={self.mamba_n_groups}: the mixer "
+                    "shares one B and C among all heads")
+            if self.mamba_chunk_size < 1 or self.mamba_d_conv < 1:
+                raise ValueError("mamba_chunk_size and mamba_d_conv must "
+                                 "be >= 1")
+        if self.num_experts == 0:
+            if self.shared_intermediate_size < 1:
+                raise ValueError(
+                    "a model without routed experts (num_experts=0) needs "
+                    "shared_intermediate_size > 0 for its dense MLP")
+        else:
+            self._validate_experts()
+        if "sparse_attention" in kinds:
+            self._validate_sparse_attention()
+        if (set(kinds) - {"mamba"}
+                and self.tokens % min(self.q_chunk, self.tokens)):
+            raise ValueError(
+                f"q_chunk={self.q_chunk} must divide the {self.tokens} "
+                "tokens of an example (or exceed them)")
+
+    def _validate_sparse_attention(self) -> None:
         sec = tuple(self.mrope_section)
         if len(sec) != 3 or 2 * sum(sec) != self.head_dim:
             raise ValueError(
@@ -154,6 +225,10 @@ class TokenModelConfig:
                 "the indexer rotates by half the section sizes: "
                 f"mrope_section={sec} must be even and indexer_head_dim="
                 f"{self.indexer_head_dim} half of head_dim={self.head_dim}")
+        if self.indexer_topk < 1:
+            raise ValueError(f"indexer_topk={self.indexer_topk} must be >= 1")
+
+    def _validate_experts(self) -> None:
         first, count = self.experts_held
         if first < 0 or count < 1 or first + count > self.num_experts:
             raise ValueError(
@@ -163,12 +238,6 @@ class TokenModelConfig:
             raise ValueError(
                 f"num_experts_per_tok={self.num_experts_per_tok} not in "
                 f"1..{self.num_experts}")
-        if self.indexer_topk < 1:
-            raise ValueError(f"indexer_topk={self.indexer_topk} must be >= 1")
-        if self.tokens % min(self.q_chunk, self.tokens):
-            raise ValueError(
-                f"q_chunk={self.q_chunk} must divide the {self.tokens} "
-                "tokens of an example (or exceed them)")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -458,6 +527,31 @@ def token_test_config(imgsize: int = 16) -> Config:
     )
 
 
+def hybrid_test_config(imgsize: int = 16) -> Config:
+    """Tiny hybrid token-denoiser config for unit tests and CPU drives:
+    ``mamba, mamba, attention, mamba, mamba`` at hidden 64 with a dense
+    gated MLP, the 128 tokens of a 16x16 pair in chunks of 24 (five whole
+    chunks and a part of a sixth), a rescaled residual path."""
+    tokens = 2 * (imgsize // 2) ** 2
+    return Config(
+        model=TokenModelConfig(
+            H=imgsize, W=imgsize, hidden_size=64, num_hidden_layers=5,
+            layer_types=("mamba", "mamba", "attention", "mamba", "mamba"),
+            num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+            rms_norm_eps=1e-5, num_experts=0, num_experts_per_tok=0,
+            shared_intermediate_size=96, mamba_n_heads=8, mamba_d_head=16,
+            mamba_d_state=16, mamba_d_conv=4, mamba_expand=2,
+            mamba_chunk_size=24, embedding_multiplier=12.0,
+            residual_multiplier=0.22, attention_multiplier=1.0 / 16,
+            logits_scaling=8.0, q_chunk=tokens // 2, emb_ch=32,
+            dtype="float32"),
+        train=TrainConfig(global_batch=8, warmup_examples=1024,
+                          max_steps=4, ckpt_every=2, log_every=1),
+        data=DataConfig(imgsize=imgsize),
+        diffusion=DiffusionConfig(timesteps=4),
+    )
+
+
 def test_config(imgsize: int = 16, ch: int = 8,
                 shallow: bool = False) -> Config:
     """Tiny config for unit tests / CPU-mesh dry runs.
@@ -484,7 +578,8 @@ def test_config(imgsize: int = 16, ch: int = 8,
 #: The presets the CLIs' ``--config`` names, by the name of the function
 #: in this module that builds each.
 NAMED_CONFIGS = {"srn64": "srn64_config", "srn128": "srn128_config",
-                 "test": "test_config", "token_test": "token_test_config"}
+                 "test": "test_config", "token_test": "token_test_config",
+                 "hybrid_test": "hybrid_test_config"}
 
 
 def named_config(name: str) -> Config:

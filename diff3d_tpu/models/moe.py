@@ -36,7 +36,7 @@ objects).
 
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Callable, Tuple
 
 import flax.linen as nn
 import jax
@@ -52,6 +52,31 @@ def rms_norm(x: jnp.ndarray, scale: jnp.ndarray, eps: float) -> jnp.ndarray:
     y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1,
                                      keepdims=True) + eps)
     return (y * scale).astype(x.dtype)
+
+
+def add_residual(h: jnp.ndarray, y: jnp.ndarray, r: float = 1.0
+                 ) -> jnp.ndarray:
+    """``h + r y`` in ``h``'s dtype, ``r`` a model's residual multiplier
+    (applied in float32); at 1 the plain add, so that a model without
+    one lowers as it did."""
+    if r != 1.0:
+        y = r * y.astype(jnp.float32)
+    return h + y.astype(h.dtype)
+
+
+def residual_half(h: jnp.ndarray, norm_scale: jnp.ndarray, eps: float,
+                  r: float, fn: Callable[[jnp.ndarray], jnp.ndarray]
+                  ) -> jnp.ndarray:
+    """``h [B, L, D] -> h + r fn(norm(h))``, ``fn`` on one example's
+    normed tokens ``[L, D]`` at a time."""
+    def one_example(hb):
+        with scope("residual"):
+            u = rms_norm(hb, norm_scale, eps)
+        y = fn(u)
+        with scope("residual"):
+            return add_residual(hb, y, r)
+
+    return jax.lax.map(one_example, h)
 
 
 def route(logits: jnp.ndarray, k: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -145,6 +170,7 @@ class RoutedExperts(nn.Module):
     token_chunk: int
     block: int
     eps: float = 1e-6
+    residual: float = 1.0
     dtype: jnp.dtype = jnp.float32
 
     @nn.compact
@@ -195,7 +221,7 @@ class RoutedExperts(nn.Module):
                 y = expert_outputs(xc, ids, gates, w_gate, w_up, w_down,
                                    first=first, block=self.block)
             with scope("residual"):
-                return hc + y.astype(hc.dtype)
+                return add_residual(hc, y, self.residual)
 
         with scope("experts"):
             y = jax.lax.map(one_chunk, x.reshape(T // chunk, chunk, D))

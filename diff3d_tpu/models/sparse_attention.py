@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from diff3d_tpu.models.moe import rms_norm
+from diff3d_tpu.models.moe import residual_half, rms_norm
 from diff3d_tpu.ops.attention import sdpa
 from diff3d_tpu.utils.profiling import scope
 
@@ -149,6 +149,7 @@ class SparseAttention(nn.Module):
     rope_theta: float
     mrope_section: Tuple[int, int, int]
     eps: float = 1e-6
+    residual: float = 1.0
     dtype: jnp.dtype = jnp.float32
 
     def setup(self):
@@ -235,12 +236,6 @@ class SparseAttention(nn.Module):
                 f"q_chunk={self.q_chunk} must divide the {L} tokens")
         W = self._arrays()
 
-        def one_example(hb):
-            with scope("residual"):
-                u = rms_norm(hb, norm_scale, self.eps)
-            a = self.attend_example(u, W)
-            with scope("residual"):
-                return hb + a.astype(hb.dtype)
-
         with scope("sparse_attention"):
-            return jax.lax.map(one_example, h)
+            return residual_half(h, norm_scale, self.eps, self.residual,
+                                 lambda u: self.attend_example(u, W))

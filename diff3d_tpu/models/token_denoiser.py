@@ -1,5 +1,8 @@
 """A token denoiser: both frames as one sequence of patches through
-decoder layers of selected-key attention and routed experts.
+decoder layers, each a sequence mixer (selected-key attention, attention
+over all keys, or a Mamba-2 state-space mixer: the config's
+``layer_types``) and a feed-forward (routed experts, or a dense gated
+MLP).
 
 The second kind of denoiser beside the X-UNet, on the same forward
 contract (docs/DESIGN.md §1): batch dict with ``x [B,H,W,3]``,
@@ -17,11 +20,12 @@ computed at ``G`` rows and meet the examples in one broadcast add.
            pixels' ray encoding (``geometry/posenc.py``, as
            ``ConditioningProcessor`` computes it, zero where
            ``cond_mask`` drops it) + an MLP of the frame's logSNR
-           sinusoid.
-  layers   pre-norm, ``h += attention(norm(h)); h += experts(norm(h))``
-           (:mod:`.sparse_attention`, :mod:`.moe`).
+           sinusoid; the sum times ``embedding_multiplier``.
+  layers   pre-norm, ``h += r mixer(norm(h)); h += r ffn(norm(h))`` with
+           ``r = residual_multiplier`` (:mod:`.sparse_attention`,
+           :mod:`.token_layers`, :mod:`.mamba`; :mod:`.moe`).
   output   RMSNorm, a linear head to ``patch^2 * 3`` values per target
-           token, un-patchified.
+           token over ``logits_scaling``, un-patchified.
 
 ``deterministic`` and ``constrain`` are accepted for the contract's sake:
 the model has no dropout, and no activation sharding hook yet.
@@ -37,8 +41,10 @@ from diff3d_tpu.geometry import (pinhole_rays_cam, pinhole_rays_world,
                                  posenc_ddpm, posenc_nerf)
 from diff3d_tpu.models.conditioning import (DIR_DEG, POS_DEG,
                                             conditioning_rows)
+from diff3d_tpu.models.mamba import Mamba2Mixer
 from diff3d_tpu.models.moe import RoutedExperts, rms_norm
 from diff3d_tpu.models.sparse_attention import Scale, SparseAttention
+from diff3d_tpu.models.token_layers import FullAttention, GatedMLP
 from diff3d_tpu.utils.profiling import count, scope
 
 
@@ -61,38 +67,69 @@ def unpatchify(tok: jnp.ndarray, p: int, H: int, W: int) -> jnp.ndarray:
 
 
 class DecoderLayer(nn.Module):
-    """Pre-norm, ``h += attention(norm(h)); h += experts(norm(h))``.  The
-    two norms' scales live here; each half applies its norm and its
-    residual add itself, example by example and chunk by chunk."""
+    """Pre-norm, ``h += r mixer(norm(h)); h += r ffn(norm(h))``.  The two
+    norms' scales live here, each under its half's name (``attn_norm``
+    beside ``attn``, ...); each half applies its norm and its residual add
+    itself, example by example and chunk by chunk.  ``kind`` is the
+    layer's entry of ``cfg.mixers``; the feed-forward is the routed
+    experts where the config has experts, else the dense MLP."""
 
     cfg: TokenModelConfig
+    kind: str = "sparse_attention"
+
+    @property
+    def halves(self):
+        """The names of the layer's two halves in its parameter tree."""
+        return ("mamba" if self.kind == "mamba" else "attn",
+                "moe" if self.cfg.num_experts else "mlp")
 
     def setup(self):
         cfg = self.cfg
-        dtype = jnp.dtype(cfg.dtype)
-        self.attn_norm = Scale()
-        self.attn = SparseAttention(
-            hidden=cfg.hidden_size, num_heads=cfg.num_attention_heads,
-            num_kv_heads=cfg.num_key_value_heads, head_dim=cfg.head_dim,
-            indexer_heads=cfg.indexer_num_heads,
-            indexer_dim=cfg.indexer_head_dim, topk=cfg.indexer_topk,
-            q_chunk=cfg.q_chunk,
-            grid=(2, cfg.H // cfg.patch, cfg.W // cfg.patch),
-            rope_theta=cfg.rope_theta,
-            mrope_section=tuple(cfg.mrope_section), eps=cfg.rms_norm_eps,
-            dtype=dtype)
-        self.moe_norm = Scale()
-        self.moe = RoutedExperts(
-            num_experts=cfg.num_experts, top_k=cfg.num_experts_per_tok,
-            width=cfg.moe_intermediate_size,
-            held=tuple(cfg.experts_held),
-            token_chunk=cfg.expert_token_chunk, block=cfg.expert_block,
-            eps=cfg.rms_norm_eps, dtype=dtype)
+        for half in self.halves:
+            setattr(self, half + "_norm", Scale())
+        common = dict(eps=cfg.rms_norm_eps,
+                      residual=cfg.residual_multiplier,
+                      dtype=jnp.dtype(cfg.dtype))
+        if self.kind == "mamba":
+            self.mamba = Mamba2Mixer(
+                hidden=cfg.hidden_size, n_heads=cfg.mamba_n_heads,
+                d_head=cfg.mamba_d_head, d_state=cfg.mamba_d_state,
+                d_conv=cfg.mamba_d_conv, chunk=cfg.mamba_chunk_size,
+                **common)
+        elif self.kind == "attention":
+            self.attn = FullAttention(
+                hidden=cfg.hidden_size, num_heads=cfg.num_attention_heads,
+                num_kv_heads=cfg.num_key_value_heads,
+                head_dim=cfg.head_dim, q_chunk=cfg.q_chunk,
+                scale=cfg.attention_multiplier, **common)
+        else:
+            self.attn = SparseAttention(
+                hidden=cfg.hidden_size, num_heads=cfg.num_attention_heads,
+                num_kv_heads=cfg.num_key_value_heads,
+                head_dim=cfg.head_dim,
+                indexer_heads=cfg.indexer_num_heads,
+                indexer_dim=cfg.indexer_head_dim, topk=cfg.indexer_topk,
+                q_chunk=cfg.q_chunk,
+                grid=(2, cfg.H // cfg.patch, cfg.W // cfg.patch),
+                rope_theta=cfg.rope_theta,
+                mrope_section=tuple(cfg.mrope_section), **common)
+        if cfg.num_experts:
+            self.moe = RoutedExperts(
+                num_experts=cfg.num_experts, top_k=cfg.num_experts_per_tok,
+                width=cfg.moe_intermediate_size,
+                held=tuple(cfg.experts_held),
+                token_chunk=cfg.expert_token_chunk, block=cfg.expert_block,
+                **common)
+        else:
+            self.mlp = GatedMLP(hidden=cfg.hidden_size,
+                                width=cfg.shared_intermediate_size,
+                                **common)
 
     def __call__(self, h: jnp.ndarray) -> jnp.ndarray:
-        D = h.shape[-1]
-        h = self.attn(h, self.attn_norm(D))
-        return self.moe(h, self.moe_norm(D))
+        for half in self.halves:
+            norm_scale = getattr(self, half + "_norm")(h.shape[-1])
+            h = getattr(self, half)(h, norm_scale)
+        return h
 
 
 class TokenDenoiser(nn.Module):
@@ -107,8 +144,7 @@ class TokenDenoiser(nn.Module):
         self.ray_proj = nn.Dense(D, dtype=dtype)
         self.logsnr_mlp_0 = nn.Dense(D, dtype=dtype)
         self.logsnr_mlp_1 = nn.Dense(D, dtype=dtype)
-        self.layers = [DecoderLayer(cfg) for _ in
-                       range(cfg.num_hidden_layers)]
+        self.layers = [DecoderLayer(cfg, kind) for kind in cfg.mixers]
         self.final_norm = Scale()
         self.head = nn.Dense(cfg.patch * cfg.patch * 3, dtype=dtype,
                              kernel_init=nn.initializers.zeros)
@@ -150,6 +186,8 @@ class TokenDenoiser(nn.Module):
             pix = jnp.stack([batch["x"], batch["z"]], axis=1).astype(dtype)
             h = self.patch_embed(patchify(pix, p))         # [B,2,L/2,D]
             h = h.reshape(G, B // G, -1, h.shape[-1]) + cond[:, None]
+            if cfg.embedding_multiplier != 1.0:
+                h = h * jnp.asarray(cfg.embedding_multiplier, h.dtype)
             return h.reshape(B, -1, h.shape[-1])
 
     def __call__(self, batch: dict, *, cond_mask: jnp.ndarray,
@@ -164,5 +202,7 @@ class TokenDenoiser(nn.Module):
                          cfg.rms_norm_eps)
         with scope("patch_embed"):
             eps = self.head(h)
+            if cfg.logits_scaling != 1.0:
+                eps = eps / jnp.asarray(cfg.logits_scaling, eps.dtype)
             return unpatchify(eps, cfg.patch, cfg.H, cfg.W).astype(
                 jnp.float32)

@@ -212,6 +212,13 @@ def tp_param_sharding(mesh: Mesh, path, shape: Sequence[int],
         which scores all experts, replicated.  The layer itself is one
         device's program today (models/moe.py): on a mesh GSPMD gathers
         what its dynamic expert index needs, with no token exchange.
+      * the token denoiser's dense MLP ``mlp/w1`` ``[D, 2 F]`` column-
+        and ``mlp/w2`` ``[F, D]`` row-parallel; its plain attention's
+        ``q/k/v_proj`` and ``o_proj`` likewise.
+      * every leaf of a state-space mixer (``mamba/...``) — replicated,
+        explicitly: the fused ``[z | xBC | dt]`` projection does not
+        split at one column boundary, and the heads' state, conv and
+        gated norm would have to follow it (ROADMAP reach B3).
       * everything else (norm scales, learned pose embeddings, tiny
         leaves) — replicated.
 
@@ -227,14 +234,16 @@ def tp_param_sharding(mesh: Mesh, path, shape: Sequence[int],
         return len(shape) > dim and shape[dim] % tp == 0 and shape[dim] >= tp
 
     is_kernel = names and names[-1] == "kernel"
-    if tp > 1 and names and names[-1] in ("w_gate", "w_up", "w_down"):
+    if "mamba" in names:
+        pass                               # whole on every device
+    elif tp > 1 and names and names[-1] in ("w_gate", "w_up", "w_down"):
         if len(shape) == 3 and shardable(0):
             spec[0] = model_axis           # expert stacks: whole experts
     elif tp > 1 and is_kernel:
         if any(n in ("q_proj", "k_proj", "v_proj") for n in names):
             if shardable(len(shape) - 1):
                 spec[-1] = model_axis
-        elif "out_proj" in names:
+        elif any(n in ("out_proj", "o_proj", "w2") for n in names):
             if shardable(0):
                 spec[0] = model_axis
         elif shardable(len(shape) - 1) and shape[-1] > 4:

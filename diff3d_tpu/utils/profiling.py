@@ -54,7 +54,11 @@ SCOPES = ("conv", "film", "groupnorm", "attention", "conditioning",
           # the token denoiser's own (models/token_denoiser.py); it
           # shares "attention" (projections), "residual", "conditioning"
           "patch_embed", "moe_router", "experts", "indexer",
-          "sparse_attention", "rope")
+          "sparse_attention", "rope",
+          # a state-space mixer's four (models/mamba.py: projections,
+          # causal conv, the scan with its steps and decays, the gated
+          # norm) and the dense gated MLP (models/token_layers.py)
+          "ssm_proj", "ssm_conv", "ssm_scan", "ssm_gate", "mlp")
 
 
 def scope(tag: str):

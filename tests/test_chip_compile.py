@@ -26,6 +26,14 @@ of 2048 x 768, alone, under the sampler's ``vmap`` over one object (the
 cell's: the prefetched tables lose the axis) and over two (jax loops),
 and with the scan's VJP behind it (PR 29).
 
+The hybrid cell (``granite4_h_micro_tok128``, PR 30) has no kernel of
+its own: its whole view program, as ``Sampler`` builds it at full width
+(ten layers, 752 M parameters, sixteen 8192-token examples a call), is
+compiled once, 16 s, and its memory held to the chip's: a chunked scan
+whose decay tiles stood in HBM for every example at once, or attention
+that wrote ``[32, 8192, 8192]`` scores, would not fit and passes every
+CPU test.
+
 Fixture rules (on-chip-measurement guide, section 2): the topology is
 described inside a module-scoped, non-autouse fixture of THIS file, which
 skips where it cannot be described — never at import, in ``skipif``, in
@@ -294,3 +302,48 @@ def test_expert_ffn_gradient_compiles_for_v5e(one_chip, no_persistent_cache):
         return jax.grad(loss, argnums=(0, 1))(rows, w)
 
     _compile_for_chip(grads, *_expert_operands(one_chip, BF16))
+
+
+def test_the_hybrid_cells_view_program_compiles_and_fits_a_v5e(
+        one_chip, no_persistent_cache, monkeypatch):
+    """``Sampler._run_view_many`` of ``benchmark/configs/
+    granite4_h_micro_tok128.json`` on one object, as the cell calls it,
+    resolved as a TPU process resolves it."""
+    import json
+    import os
+
+    from benchmark import adapters_hybrid
+    from diff3d_tpu.models import build_model
+    from diff3d_tpu.ops import dispatch
+    from diff3d_tpu.sampling import Sampler
+    from diff3d_tpu.train.trainer import init_params
+
+    monkeypatch.setattr(dispatch, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(dispatch, "interpret_default", lambda: False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "granite4_h_micro_tok128.json")) as f:
+        cfg = adapters_hybrid.build_config(json.load(f))
+    model = build_model(cfg)
+    params = jax.eval_shape(
+        lambda: init_params(model, cfg, jax.random.PRNGKey(0)))
+    sampler = Sampler(model, params, cfg, sampler_kind="ddim", steps=8)
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=one_chip)
+    H, B = cfg.model.H, len(cfg.diffusion.guidance_weights)
+    compiled = sampler._run_view_many.lower(
+        jax.tree.map(lambda x: sds(x.shape, x.dtype), params),
+        sds((1, 2, B, H, H, 3), F32), sds((1, 2, 3, 3), F32),
+        sds((1, 2, 3), F32), sds((1,), "int32"), sds((1, 3, 3), F32),
+        sds((1, 2), "uint32")).compile()
+    mem = compiled.memory_analysis()
+    assert 4 * 752_425_932 < mem.argument_size_in_bytes < 3.02e9
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.generated_code_size_in_bytes)
+    # 6.95 GB when written (temporaries 3.86): under half the chip's 16.9
+    assert total < 8.5e9, mem
+    # no [heads, L, L] score array and no decay tile for all 16 examples
+    text = compiled.as_text()
+    assert not re.search(r"f32\[[\d,]*32,8192,8192\]", text)
+    assert not re.search(r"f32\[16,[\d,]*256,256\]", text)

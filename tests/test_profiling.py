@@ -251,6 +251,8 @@ def programs():
 #: the token denoiser's own classes (models/token_denoiser.py)
 TOKEN_SCOPES = {"patch_embed", "moe_router", "experts", "indexer",
                 "sparse_attention", "rope"}
+#: ... and those of its hybrid layers (models/mamba.py, token_layers.py)
+HYBRID_SCOPES = {"ssm_proj", "ssm_conv", "ssm_scan", "ssm_gate", "mlp"}
 
 
 def test_the_token_denoisers_classes_are_in_its_view_program():
@@ -281,6 +283,36 @@ def test_the_token_denoisers_classes_are_in_its_view_program():
     assert len(named_untagged) / len(tags) < 0.002, named_untagged[:5]
 
 
+def test_the_hybrid_layers_classes_are_in_the_view_program():
+    """Every device op of the hybrid token denoiser's view program falls
+    in a class: the state-space mixer's four, ``mlp``, and the shared
+    ``attention`` (the plain attention layer whole), ``residual``,
+    ``patch_embed``, ``conditioning``, ``sampler``, ``record``."""
+    from diff3d_tpu.config import hybrid_test_config
+    from diff3d_tpu.models import build_model
+    from diff3d_tpu.sampling import Sampler
+
+    cfg = hybrid_test_config()
+    model = build_model(cfg)
+    params = jax.eval_shape(
+        lambda: init_params(model, cfg, jax.random.PRNGKey(0)))
+    low = Sampler(model, params, cfg, sampler_kind="ddim",
+                  steps=2).lower_step_many(1, 2)
+    with no_compile_cache():
+        text = low.compile().as_text()
+    ops = op_names(text)
+    tags = [op_class(n)[0] for _, n in ops]
+    assert set(tags) - {None} == HYBRID_SCOPES | {
+        "attention", "residual", "patch_embed", "conditioning", "sampler",
+        "record"}
+    # by count; what has none is unnamed: copies, broadcasts and fusions
+    # the compiler made (0.122 at this size)
+    assert tags.count(None) / len(tags) < 0.15
+    named_untagged = [n for (_, n), t in zip(ops, tags)
+                      if t is None and "/" in n]
+    assert len(named_untagged) / len(tags) < 0.002, named_untagged[:5]
+
+
 def test_every_class_is_in_the_compiled_programs_and_few_ops_have_none(
         programs):
     train, view = programs["lower"]()
@@ -298,7 +330,7 @@ def test_every_class_is_in_the_compiled_programs_and_few_ops_have_none(
         named_untagged = [n for (_, n), t in zip(ops, tags)
                           if t is None and n]
         assert len(named_untagged) / len(tags) < 0.002, named_untagged[:5]
-    assert seen - {None} == set(SCOPES) - TOKEN_SCOPES
+    assert seen - {None} == set(SCOPES) - TOKEN_SCOPES - HYBRID_SCOPES
     # forward and backward share a tag; the backward is told by transpose(
     bwd = {op_class(n) for _, n in op_names(texts["train"])}
     for tag in ("conv", "film", "groupnorm", "attention", "conditioning",

@@ -34,6 +34,8 @@ from diff3d_tpu.ops.pallas_attention import selected_supports  # noqa: E402
 from diff3d_tpu.ops.pallas_moe import expert_ffn_supports  # noqa: E402
 from diff3d_tpu.utils.profiling import RECORDER  # noqa: E402
 
+from _token_helpers import make_batch, reference_loss  # noqa: E402
+
 with open(os.path.join(ROOT, "benchmark", "configs",
                        "keye_vl2_tok_tiny.json")) as f:
     TINY = json.load(f)
@@ -46,20 +48,6 @@ def tiny():
     flat = rt.make_params(mcfg, jax.random.PRNGKey(7))()
     return {"cfg": cfg, "mcfg": mcfg, "flat": flat,
             "model": build_model(cfg)}
-
-
-def make_batch(key, B, G, H=16):
-    k = jax.random.split(key, 6)
-    s = float(H)
-    K = jnp.array([[1.2 * s, 0, s / 2], [0, 1.2 * s, s / 2], [0, 0, 1.0]])
-    R = jnp.linalg.qr(jax.random.normal(k[2], (G, 2, 3, 3)))[0]
-    return {"x": jax.random.normal(k[0], (B, H, H, 3)),
-            "z": jax.random.normal(k[1], (B, H, H, 3)),
-            "logsnr": jnp.stack([jnp.full((G,), 20.0),
-                                 jax.random.uniform(k[3], (G,), minval=-5,
-                                                    maxval=5)], axis=1),
-            "R": R, "t": 2.0 * jax.random.normal(k[4], (G, 2, 3)),
-            "K": jnp.broadcast_to(K, (G, 3, 3))}
 
 
 def run_program(model, flat, batch, mask):
@@ -493,33 +481,6 @@ def test_one_synthesized_view_is_the_references(tiny):
     assert d.notes["expert_load_max_over_mean"] >= 1.0
 
 
-def _reference_loss(params, batch, key, mcfg, dcfg):
-    """The epsilon loss of one batch by the reference model, on the key
-    stream of ``train/step.py`` / ``diffusion.p_losses`` (the stream
-    ``reference/diffusion.py block_loss`` documents)."""
-    from benchmark.reference import diffusion as rd
-
-    imgs = batch["imgs"].astype(jnp.float32) / 127.5 - 1.0
-    B = imgs.shape[0]
-    x, z = imgs[:, 0], imgs[:, 1]
-    key, _ = jax.random.split(key)
-    k_t, k_noise, k_mask, k_xn = jax.random.split(key, 4)
-    logsnr = rd.logsnr_cosine(jax.random.uniform(k_t, (B,)), -20.0, 20.0)
-    noise = jax.random.normal(k_noise, z.shape, jnp.float32)
-    alpha, sigma = rd.alpha_sigma(logsnr)
-    z_noisy = (alpha[:, None, None, None] * z
-               + sigma[:, None, None, None] * noise)
-    cond_mask = jax.random.uniform(k_mask, (B,)) > dcfg["cond_prob"]
-    x_cond = jnp.where(cond_mask[:, None, None, None], x,
-                       jax.random.normal(k_xn, x.shape, jnp.float32))
-    mb = {"x": x_cond, "z": z_noisy,
-          "logsnr": jnp.stack([jnp.full((B,), 20.0), logsnr], axis=1),
-          "R": batch["R"], "t": batch["T"], "K": batch["K"]}
-    # the experts' literal loop has a gradient; the selection is a mask
-    eps, _ = rt.forward(params, mb, cond_mask, mcfg, literal={"experts"})
-    return jnp.mean(jnp.square(noise - eps))
-
-
 def test_three_train_steps_follow_the_references_loss_and_gradient(tiny):
     from diff3d_tpu.train.state import create_train_state
     from diff3d_tpu.train.step import make_train_step
@@ -537,7 +498,12 @@ def test_three_train_steps_follow_the_references_loss_and_gradient(tiny):
     state = create_train_state(nest(flat), cfg.train)
     base = jax.random.PRNGKey(11)
     ref_fn = jax.jit(jax.value_and_grad(
-        lambda p, k: _reference_loss(p, batch, k, mcfg, dcfg)))
+        lambda p, k: reference_loss(
+            # the experts' literal loop has a gradient; the selection is
+            # a mask
+            lambda mb, m: rt.forward(p, mb, m, mcfg,
+                                     literal={"experts"})[0],
+            batch, k, dcfg)))
     b1 = cfg.train.betas[0]
     for i in range(3):
         params = flatten(adapters_tokens.adapters._plain(state.params))
