@@ -15,9 +15,11 @@ size of the whole call.
              is not causal), ``W_o``.  ``q`` carries ``scale *
              head_dim^1/2`` into :func:`~diff3d_tpu.ops.attention.sdpa`,
              which divides by ``head_dim^1/2``.  One tile of ``q_chunk``
-             queries at a time: where the registry resolves to XLA (head
-             dims up to 64) a tile's float32 scores are ``[Hq, q_chunk,
-             L]``, not ``[Hq, L, L]``.
+             queries at a time: where the registry resolves to XLA (a
+             CPU process; fewer than 2048 keys) a tile's float32 scores
+             are ``[Hq, q_chunk, L]``, not ``[Hq, L, L]``; on a TPU
+             process the tile is one call of the Pallas kernel
+             ``plain_attention``, which writes no scores at all.
   MLP        ``[a | b] = W_1 u``, ``W_2 (silu(a) * b)``, no bias.
 """
 

@@ -8,14 +8,22 @@ epilogues):
 
   * ``'xla'``    — ``jax.nn.dot_product_attention``: the score tile is
     written to HBM and read back (XLA emits no flash kernel on the v5e:
-    under a selection at ``[32, 512, 8192]`` it makes three passes over
-    a 512 MB float32 tile, PERF.md section 6, PR 27).
+    at ``[32, 512, 8192]`` it makes three passes over a 512 MB float32
+    tile, with a selection or without, PERF.md section 6, PRs 27 and 31).
   * ``'pallas'`` — hand-written TPU Pallas kernels
-    (:mod:`diff3d_tpu.ops.pallas_attention`): ``flash_attention`` for
-    the plain core, ``selected_attention`` for the core under a
-    selection.
-  * ``'auto'``   — pallas on a TPU process when the operands qualify,
-    else xla.
+    (:mod:`diff3d_tpu.ops.pallas_attention`).  Under a selection,
+    ``selected_attention``.  Without one, two kernels behind the one
+    name, chosen by shape (:func:`_pallas_sdpa`): ``plain_attention``
+    (forward kernel, grouped queries, head dim 64 or whole lane tiles,
+    the XLA expression's gradient) from ``PLAIN_MIN_KEYS`` keys on where
+    it supports the operands, and for grouped heads at any length;
+    ``flash_attention`` (any lengths, head dims up to 512, its own
+    backward kernels, one key-value head per query head) everywhere
+    else — so the X-UNet's sites (``L <= 1024``) asked for ``'pallas'``
+    by hand get the kernel they always got.
+  * ``'auto'``   — on a TPU process ``plain_attention`` where the rule
+    above takes it, else xla: ``flash_attention`` is never chosen
+    (:func:`_pallas_auto` has the measurements).
 
 Two ops are registered: ``'sdpa'`` (plain) and ``'sdpa_selected'`` (with
 ``keep``); each has its own ``supports`` and ``auto`` policy.
@@ -31,34 +39,56 @@ import jax.numpy as jnp
 from diff3d_tpu.ops import dispatch
 from diff3d_tpu.utils.profiling import count
 
+# Keys from which ``plain_attention`` beats XLA's score tile on one v5e
+# (bf16, 32 query heads, all queries against L keys, ms; my chip run, PR
+# 31).  D 64, 8 kv heads: L 2048 2.35 -> 0.59, 4096 9.08 -> 2.09, 8192
+# 36.3 -> 7.6; D 128, 4 kv heads: 2.40 -> 0.60, 8.93 -> 2.09, 36.4 ->
+# 7.8.  At 1024 one example is a tie (0.26 / 0.23, 0.28 / 0.28) and at
+# 512 XLA wins (0.25 / 0.28): the X-UNet's sites stay with XLA.
+PLAIN_MIN_KEYS = 2048
+
 
 def _xla_sdpa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray) -> jnp.ndarray:
     return jax.nn.dot_product_attention(q, k, v)
 
 
+def _pallas_auto(q, k, v) -> bool:
+    """Where a kernel beats XLA without a selection: ``plain_attention``
+    on operands it supports from ``PLAIN_MIN_KEYS`` keys on.
+
+    ``flash_attention`` is not in the rule.  The one it had, ``D > 64
+    and L >= 4096``, came from the retired set-up; on this chip (PERF.md
+    section 5, PRs 30 and 31) it loses to XLA at head dim 64 (float32
+    dots, 128 x 128 tiles, the head dim padded to the lanes: 76.3 ms
+    against 36.3 at 32 heads x 8192 x 8192, where ``plain_attention``
+    reads 7.6), so ``D > 64`` was right; and at ``D`` 128 and ``L >=
+    4096``, the half that never had a caller, it reads 18.2 ms at L 4096
+    (XLA 15.8) and 74.5 at 8192 where ``plain_attention``, which takes
+    every whole-block shape there, reads 2.5 and 8.9.  What is left to
+    ``flash_attention`` is ``impl='pallas'`` by hand off that rule
+    (ragged lengths, head dims 32 / 96 / 160, a backward kernel) and
+    ``ring_sdpa(impl='pallas')``."""
+    from diff3d_tpu.ops.pallas_attention import plain_supports
+
+    return k.shape[1] >= PLAIN_MIN_KEYS and plain_supports(q, k, v)
+
+
 def _pallas_sdpa(q: jnp.ndarray, k: jnp.ndarray,
                  v: jnp.ndarray) -> jnp.ndarray:
-    from diff3d_tpu.ops.pallas_attention import flash_attention
+    """``plain_attention`` where it wins, and where ``flash_attention``
+    cannot run (grouped heads); ``flash_attention`` elsewhere."""
+    from diff3d_tpu.ops.pallas_attention import (flash_attention,
+                                                 plain_attention, supports)
 
+    if _pallas_auto(q, k, v) or not supports(q, k, v):
+        return plain_attention(q, k, v)
     return flash_attention(q, k, v)
 
 
 def _pallas_supports(q, k, v) -> bool:
-    from diff3d_tpu.ops.pallas_attention import supports
+    from diff3d_tpu.ops.pallas_attention import plain_supports, supports
 
-    return supports(q, k, v)
-
-
-def _pallas_auto(q, *args) -> bool:
-    """A rule carried over from the retired set-up's measurement, never
-    re-measured on this chip (ROADMAP.md design item 3): the Pallas
-    flash kernel zero-pads the head dim to the 128-lane MXU
-    tile, so at D=32/64 it wastes 4x/2x of every QK^T and PV matmul and
-    XLA's fused attention wins; only lane-filling heads (D > 64) with
-    sequences long enough that the materialised [L, L] logits' HBM traffic
-    dominates are worth the flash kernel."""
-    D, L = q.shape[-1], q.shape[1]
-    return D > 64 and L >= 4096
+    return plain_supports(q, k, v) or supports(q, k, v)
 
 
 def _xla_selected(q, k, v, keep) -> jnp.ndarray:
@@ -127,7 +157,9 @@ def sdpa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     attention layers scale past one device's tokens: set
     ``ModelConfig.attn_impl='ring:model'`` and run the step in a
     ``shard_map`` whose specs shard the spatial axis.  Everything else
-    ('auto' | 'pallas' | 'xla') goes through the shared kernel registry.
+    ('auto' | 'pallas' | 'xla') goes through the shared kernel registry;
+    each traced site adds 1 to the recorder's ``sdpa.plain.pallas`` or
+    ``sdpa.plain.xla``, by the core it resolved to.
     """
     if keep is not None:
         core = dispatch.resolve("sdpa_selected", impl, q, k, v, keep)
@@ -138,7 +170,9 @@ def sdpa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         kind, _, axis = impl.partition(":")
         fn = {"ring": ring_sdpa, "ulysses": ulysses_sdpa}[kind]
         return fn(q, k, v, axis_name=axis)
-    return dispatch.dispatch("sdpa", impl, q, k, v)
+    core = dispatch.resolve("sdpa", impl, q, k, v)
+    count(f"sdpa.plain.{core.name}")
+    return core.fn(q, k, v)
 
 
 def multi_head_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,

@@ -1,13 +1,17 @@
-"""TPU Pallas attention kernels: flash attention (forward + backward) and
-attention under a selection (forward).
+"""TPU Pallas attention kernels: flash attention (forward + backward),
+attention under a selection (forward) and plain grouped-query attention
+(forward).
 
-Two kernels share this file and its helpers and nothing of their bodies.
-:func:`flash_attention`, described first, is the X-UNet's: no mask, one
+Three kernels share this file and its helpers.  :func:`flash_attention`,
+described first, is the X-UNet's, asked for by hand: no mask, one
 key-value head per query head, float32 dots, 128 x 128 tiles, its own
-backward kernels.  :func:`selected_attention` (the last section) is the
-token denoiser's: a selection ``keep [B, Lq, Lk]``, grouped queries,
-operands to the MXU in the dtype given, blocks sized from the shape,
-the XLA expression's gradient.
+backward kernels.  :func:`selected_attention` is the token denoiser's: a
+selection ``keep [B, Lq, Lk]``, grouped queries, operands to the MXU in
+the dtype given, blocks sized from the shape, the XLA expression's
+gradient.  :func:`plain_attention` (the last section) is that algorithm
+without a selection and made to run at head dim 64: what ``sdpa``
+resolves to on a TPU process from 2048 keys on.  The last two share
+their online-softmax step and block rule.
 
 Replaces the reference's ``torch.nn.MultiheadAttention`` sdpa core
 (``/root/reference/xunet.py:154-177``, which delegates to cuDNN) with a
@@ -46,6 +50,7 @@ TPU process they are compiled or the call raises
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -81,13 +86,14 @@ def _out_struct(shape, dtype, like) -> jax.ShapeDtypeStruct:
 
 def supports(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray) -> bool:
     """Shapes/dtypes this kernel handles: ``[B, L, H, D]`` with
-    D <= MAX_D (512; covers srn128's deep-level D=256)."""
-    if q.ndim != 4 or k.ndim != 4 or v.ndim != 4:
+    D <= MAX_D (512; covers srn128's deep-level D=256) and one
+    key-value head per query head."""
+    if q.ndim != 4 or k.ndim != 4 or k.shape != v.shape:
         return False
     if q.dtype not in (jnp.float32, jnp.bfloat16):
         return False
     D = q.shape[-1]
-    return D <= MAX_D and k.shape[-1] == D and v.shape[-1] == D
+    return D <= MAX_D and k.shape[-1] == D and k.shape[2] == q.shape[2]
 
 
 def _block_sizes(Lq: int, Lk: int) -> tuple[int, int, int, int]:
@@ -465,40 +471,73 @@ SELECT_TILE = 1 << 19       # score elements of one head's block
 SELECT_VMEM_BYTES = 64 << 20   # of the v5e's 128 MiB; the default is 16
 
 
-def _selected_blocks(Lq: int, Lk: int) -> Optional[tuple[int, int]]:
+def _selected_blocks(Lq: int, Lk: int, tile: int = SELECT_TILE
+                     ) -> Optional[tuple[int, int]]:
     """(query block, key block) for ``Lq`` queries on ``Lk`` keys, or
     None when a length is no whole number of blocks.  Wide key blocks
     first: the row max and row sum cross the lanes once per row and key
     block, whatever the block's width (at 512 queries x 8192 keys on the
     v5e, 256 x 2048 ran at 8.3 ms a layer-example, 512 x 512 at 16.6:
     PERF.md section 6, PR 27); then as many queries as keep one head's
-    float32 score block at ``SELECT_TILE`` elements (2 MB)."""
+    float32 score block at ``tile`` elements (``SELECT_TILE``: 2 MB)."""
     bk = next((c for c in SELECT_BLOCK_K if Lk % c == 0), None)
     if bk is None:
         return None
     bq = next((c for c in SELECT_BLOCK_Q
-               if Lq % c == 0 and c * bk <= SELECT_TILE), None)
+               if Lq % c == 0 and c * bk <= tile), None)
     return None if bq is None else (bq, bk)
 
 
-def selected_supports(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                      keep: jnp.ndarray) -> bool:
-    """Shapes/dtypes :func:`selected_attention` handles: ``q [B, Lq, Hq,
-    D]``, ``k, v [B, Lk, Hkv, D]`` of one dtype (bf16 or float32), ``keep
-    [B, Lq, Lk]``; ``D`` whole lane tiles (no head-dim padding on this
-    path), ``Hkv`` dividing ``Hq``, ``Lq`` / ``Lk`` whole query / key
-    blocks."""
-    if q.ndim != 4 or k.ndim != 4 or k.shape != v.shape or keep.ndim != 3:
+def _grouped_operands(q, k, v) -> bool:
+    """``q [B, Lq, Hq, D]``, ``k, v [B, Lk, Hkv, D]`` of one dtype (bf16
+    or float32), ``Hkv`` dividing ``Hq``."""
+    if q.ndim != 4 or k.ndim != 4 or k.shape != v.shape:
         return False
     if q.dtype not in (jnp.float32, jnp.bfloat16) \
             or k.dtype != q.dtype or v.dtype != q.dtype:
         return False
-    B, Lq, Hq, D = q.shape
-    Lk, Hkv = k.shape[1], k.shape[2]
-    return (k.shape[0] == B and k.shape[3] == D and D % LANE == 0
-            and D <= MAX_D and Hq % Hkv == 0
-            and keep.shape == (B, Lq, Lk)
+    return (k.shape[0] == q.shape[0] and k.shape[3] == q.shape[3]
+            and q.shape[2] % k.shape[2] == 0)
+
+
+def selected_supports(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                      keep: jnp.ndarray) -> bool:
+    """Shapes/dtypes :func:`selected_attention` handles: grouped
+    operands (:func:`_grouped_operands`), ``keep [B, Lq, Lk]``; ``D``
+    whole lane tiles (no head-dim padding on this path), ``Lq`` / ``Lk``
+    whole query / key blocks."""
+    if not _grouped_operands(q, k, v) or keep.ndim != 3:
+        return False
+    B, Lq, _, D = q.shape
+    Lk = k.shape[1]
+    return (D % LANE == 0 and D <= MAX_D and keep.shape == (B, Lq, Lk)
             and _selected_blocks(Lq, Lk) is not None)
+
+
+def _init_running(m_scr, l_scr, acc_scr):
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+
+def _online_softmax(s, v, m_scr, l_scr, acc_scr, h: int):
+    """Fold the float32 score block ``s [bq, bk]`` of head ``h`` and its
+    values ``v [bk, W]`` into the head's running max, sum and output."""
+    m_prev = m_scr[h, :, :1]                               # [bq, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)
+    l_new = alpha * l_scr[h, :, :1] + jnp.sum(p, axis=-1, keepdims=True)
+    pv = jnp.dot(p.astype(v.dtype), v,
+                 preferred_element_type=jnp.float32)       # [bq, W]
+    acc_scr[h] = acc_scr[h] * alpha + pv
+    m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+    l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
+
+
+def _scores(q, k):
+    return jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
 
 
 def _selected_kernel(q_ref, k_ref, v_ref, keep_ref, o_ref, m_scr, l_scr,
@@ -514,11 +553,7 @@ def _selected_kernel(q_ref, k_ref, v_ref, keep_ref, o_ref, m_scr, l_scr,
     scale = float(1.0 / np.sqrt(D))
     ki = pl.program_id(3)
 
-    @pl.when(ki == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    pl.when(ki == 0)(lambda: _init_running(m_scr, l_scr, acc_scr))
 
     # 0 on a kept key, NEG_INF on the others: float32 absorbs any score
     # into NEG_INF, so a dropped key's probability is exp(NEG_INF - m) = 0
@@ -529,19 +564,8 @@ def _selected_kernel(q_ref, k_ref, v_ref, keep_ref, o_ref, m_scr, l_scr,
     v = v_ref[0]
     for g in range(group):
         q = q_ref[0, :, g * D:(g + 1) * D]                 # [bq, D]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = s * scale + bias                               # [bq, bk] f32
-        m_prev = m_scr[g, :, :1]                           # [bq, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_new = alpha * l_scr[g, :, :1] + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jnp.dot(p.astype(v.dtype), v,
-                     preferred_element_type=jnp.float32)   # [bq, D]
-        acc_scr[g] = acc_scr[g] * alpha + pv
-        m_scr[g] = jnp.broadcast_to(m_new, m_scr.shape[1:])
-        l_scr[g] = jnp.broadcast_to(l_new, l_scr.shape[1:])
+        s = _scores(q, k) * scale + bias                   # [bq, bk] f32
+        _online_softmax(s, v, m_scr, l_scr, acc_scr, g)
 
     @pl.when(ki == pl.num_programs(3) - 1)
     def _finalize():
@@ -625,3 +649,151 @@ def selected_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     if interpret is None:
         interpret = dispatch.interpret_default()
     return _selected(q, k, v, keep, bool(interpret))
+
+
+# --------------------------------------------------------------------------
+# plain grouped-query attention: forward kernel, head dim 64 or lane tiles
+# --------------------------------------------------------------------------
+
+PLAIN_TILE = 1 << 20        # no ``keep`` block in VMEM: twice the queries
+
+
+def plain_supports(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray) -> bool:
+    """Shapes/dtypes :func:`plain_attention` handles: grouped operands
+    (:func:`_grouped_operands`); ``D`` whole lane tiles, or 64 with an
+    even ``Hkv`` (two key-value heads to a lane tile); ``Lq`` / ``Lk``
+    whole query / key blocks."""
+    if not _grouped_operands(q, k, v):
+        return False
+    D, Hkv = k.shape[3], k.shape[2]
+    lanes = (D % LANE == 0 and D <= MAX_D) or \
+        (2 * D == LANE and Hkv % 2 == 0)
+    return lanes and _selected_blocks(q.shape[1], k.shape[1],
+                                      PLAIN_TILE) is not None
+
+
+def _plain_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
+                  D: int, group: int):
+    """One (example, key-value block of lanes, query block, key block)
+    step.  At ``D`` of whole lane tiles the ``k`` / ``v`` block is one
+    key-value head and the ``group`` query heads are lane slices of the
+    ``q`` block, as in :func:`_selected_kernel`.
+
+    At ``D = 64`` the block ``[bk, 128]`` is two key-value heads, one a
+    lane half, as ``[L, Hkv * 64]`` lies in memory, and the ``q`` block
+    their ``2 * group`` query heads: nothing is transposed or padded in
+    HBM.  Query head ``h`` is the half ``e = h % 2`` of its tile and
+    attends the half ``f = h // group`` of ``k`` / ``v``.  Its tile with
+    the other half zeroed contracts over all 128 lanes to the head's own
+    scores (a 64-deep pass costs the MXU what a 128-deep one does); ``p
+    @ v`` is ``[bq, 128]`` with the head's output in half ``f`` (the other
+    half is the neighbour's values under this head's probabilities,
+    dropped).  Where ``e != f`` the halves of ``q``, and of the output
+    when it is written, are swapped by a lane rotation in float32 (Mosaic
+    rotates 32-bit lanes alone), on ``[bq, 128]`` tiles: next to nothing
+    beside the ``[bq, bk]`` softmax."""
+    W = k_ref.shape[-1]
+    packed = W != D
+    heads = q_ref.shape[-1] // D
+    bq = q_ref.shape[1]
+    scale = float(1.0 / np.sqrt(D))
+    # a power of two (D = 64, 256) scales q exactly, in any float dtype:
+    # [bq, D] multiplications in place of [bq, bk]; the scores' bits stay
+    exact = math.frexp(scale)[0] == 0.5
+    ki = pl.program_id(3)
+    pl.when(ki == 0)(lambda: _init_running(m_scr, l_scr, acc_scr))
+    if packed:
+        half = jax.lax.broadcasted_iota(jnp.int32, (bq, LANE), 1) // D
+
+    def swap(x):
+        return pltpu.roll(x.astype(jnp.float32), D, 1)
+
+    k, v = k_ref[0], v_ref[0]                              # [bk, W]
+    for h in range(heads):
+        if packed:
+            tile = q_ref[0, :, h // 2 * LANE:(h // 2 + 1) * LANE]
+            q = jnp.where(half == h % 2, tile, jnp.zeros_like(tile))
+            if h % 2 != h // group:
+                q = swap(q).astype(tile.dtype)
+        else:
+            q = q_ref[0, :, h * D:(h + 1) * D]
+        if exact:
+            s = _scores(q * jnp.asarray(scale, q.dtype), k)
+        else:
+            s = _scores(q, k) * scale                      # [bq, bk] f32
+        _online_softmax(s, v, m_scr, l_scr, acc_scr, h)
+
+    @pl.when(ki == pl.num_programs(3) - 1)
+    def _finalize():
+        def out(h):
+            o = acc_scr[h] / l_scr[h, :, :1]
+            return swap(o) if packed and h % 2 != h // group else o
+        for t in range(heads * D // W):
+            o_ref[0, :, t * W:(t + 1) * W] = (
+                jnp.where(half == 0, out(2 * t), out(2 * t + 1))
+                if packed else out(t)).astype(o_ref.dtype)
+
+
+def _plain_fwd(q, k, v, interpret: bool):
+    B, Lq, Hq, D = q.shape
+    Lk, Hkv = k.shape[1], k.shape[2]
+    group = Hq // Hkv
+    W = max(D, LANE)                  # lanes of a k / v block
+    heads = W // D * group            # query heads that attend them
+    bq, bk = _selected_blocks(Lq, Lk, PLAIN_TILE)
+    qo_spec = pl.BlockSpec((1, bq, heads * D),
+                           lambda b, h, qi, ki: (b, qi, h))
+    kv_spec = pl.BlockSpec((1, bk, W), lambda b, h, qi, ki: (b, ki, h))
+    out = pl.pallas_call(
+        functools.partial(_plain_kernel, D=D, group=group),
+        grid=(B, Hkv * D // W, Lq // bq, Lk // bk),
+        in_specs=[qo_spec, kv_spec, kv_spec],
+        out_specs=qo_spec,
+        out_shape=_out_struct((B, Lq, Hq * D), q.dtype, q),
+        scratch_shapes=[_vmem((heads, bq, LANE)), _vmem((heads, bq, LANE)),
+                        _vmem((heads, bq, W))],
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=SELECT_VMEM_BYTES),
+        interpret=interpret,
+    )(q.reshape(B, Lq, Hq * D), k.reshape(B, Lk, Hkv * D),
+      v.reshape(B, Lk, Hkv * D))
+    return out.reshape(B, Lq, Hq, D)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _plain(q, k, v, interpret: bool):
+    return _plain_fwd(q, k, v, interpret)
+
+
+def _plain_vjp_fwd(q, k, v, interpret: bool):
+    return _plain_fwd(q, k, v, interpret), (q, k, v)
+
+
+def _plain_vjp_bwd(interpret, res, g):
+    return jax.vjp(jax.nn.dot_product_attention, *res)[1](g)
+
+
+_plain.defvjp(_plain_vjp_fwd, _plain_vjp_bwd)
+
+
+def plain_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                    interpret: Optional[bool] = None) -> jnp.ndarray:
+    """Attention of ``q [B, Lq, Hq, D]`` over all of ``k, v [B, Lk, Hkv,
+    D]``, scores over ``sqrt(D)``: ``jax.nn.dot_product_attention(q, k,
+    v)`` with no ``[Hq, Lq, Lk]`` array in HBM.
+
+    :func:`selected_attention`'s algorithm and block rule without a
+    selection (512 x 2048 where it has 256 x 2048: no ``keep`` block),
+    and made to run at head dim 64 (:func:`_plain_kernel`): operands to
+    the MXU in the dtype given, float32 accumulation and softmax, the
+    probabilities cast to the values' dtype for ``PV``.  The gradient is
+    the XLA expression's on the saved operands: there is no backward
+    kernel (:func:`flash_attention` has two, and float32 dots, 128 x 128
+    tiles and one key-value head per query head).
+    """
+    assert plain_supports(q, k, v), (q.shape, k.shape, v.shape, q.dtype)
+    if interpret is None:
+        interpret = dispatch.interpret_default()
+    return _plain(q, k, v, bool(interpret))

@@ -26,13 +26,16 @@ of 2048 x 768, alone, under the sampler's ``vmap`` over one object (the
 cell's: the prefetched tables lose the axis) and over two (jax loops),
 and with the scan's VJP behind it (PR 29).
 
-The hybrid cell (``granite4_h_micro_tok128``, PR 30) has no kernel of
-its own: its whole view program, as ``Sampler`` builds it at full width
-(ten layers, 752 M parameters, sixteen 8192-token examples a call), is
+The hybrid cell (``granite4_h_micro_tok128``, PR 30) has one kernel
+site, plain ``sdpa`` of its one attention layer: one tile of 512 queries
+(32 heads) against the 8192 keys of an example (8 heads) at head dim 64,
+``plain_attention`` (PR 31), compiled as the selected kernel's site is.
+Its whole view program, as ``Sampler`` builds it at full width (ten
+layers, 752 M parameters, sixteen 8192-token examples a call), is also
 compiled once, 16 s, and its memory held to the chip's: a chunked scan
 whose decay tiles stood in HBM for every example at once, or attention
-that wrote ``[32, 8192, 8192]`` scores, would not fit and passes every
-CPU test.
+that wrote its scores, would not fit or would show in the text, and
+passes every CPU test.
 
 Fixture rules (on-chip-measurement guide, section 2): the topology is
 described inside a module-scoped, non-autouse fixture of THIS file, which
@@ -51,6 +54,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from diff3d_tpu.ops.pallas_attention import (flash_attention,
+                                             plain_attention,
                                              selected_attention)
 from diff3d_tpu.ops.pallas_film import fused_groupnorm
 from diff3d_tpu.ops.pallas_moe import expert_ffn
@@ -207,6 +211,8 @@ def test_flash_attention_backward_compiles_for_v5e(
 
 # keye_vl2_tok128's sdpa(keep=) site: (Lq, Lk, Hq, Hkv, D)
 SELECTED_SITE = (512, 8192, 32, 4, 128)
+# a tile's scores, [.., Lq, Lk], the array neither attention kernel writes
+SCORES = r"(f32|bf16)\[[\d,]*512,8192\]"
 
 
 def _selected_operands(one_chip, dtype, lead=()):
@@ -229,7 +235,7 @@ def test_selected_attention_forward_compiles_for_v5e(
     compiled = _compile_for_chip(_selected,
                                  *_selected_operands(one_chip, dtype))
     # the score tile stays on chip: nothing of [Hq, Lq, Lk] in the program
-    assert not re.search(r"(f32|bf16)\[[\d,]*512,8192\]", compiled.as_text())
+    assert not re.search(SCORES, compiled.as_text())
 
 
 def test_selected_attention_under_the_samplers_vmap_compiles_for_v5e(
@@ -252,6 +258,50 @@ def test_selected_attention_gradient_compiles_for_v5e(
         return jnp.sum(_selected(q, k, v, keep).astype(jnp.float32) ** 2)
 
     _compile_for_chip(jax.grad(loss, argnums=(0, 1, 2)), q, k, v, keep)
+
+
+# granite4_h_micro_tok128's plain sdpa site: (Lq, Lk, Hq, Hkv, D)
+PLAIN_SITE = (512, 8192, 32, 8, 64)
+
+
+def _plain_operands(one_chip, dtype, lead=()):
+    Lq, Lk, Hq, Hkv, D = PLAIN_SITE
+
+    def sds(shape):
+        return jax.ShapeDtypeStruct(lead + shape, jnp.dtype(dtype),
+                                    sharding=one_chip)
+    return (sds((1, Lq, Hq, D)), sds((1, Lk, Hkv, D)), sds((1, Lk, Hkv, D)))
+
+
+def _plain(q, k, v):
+    return plain_attention(q, k, v, interpret=False)
+
+
+@pytest.mark.parametrize("dtype", [BF16, F32])
+def test_plain_attention_forward_compiles_for_v5e(
+        one_chip, no_persistent_cache, dtype):
+    compiled = _compile_for_chip(_plain, *_plain_operands(one_chip, dtype))
+    # the score tile stays on chip: nothing of [Hq, Lq, Lk] in the program
+    assert not re.search(SCORES, compiled.as_text())
+
+
+def test_plain_attention_under_the_samplers_vmap_compiles_for_v5e(
+        one_chip, no_persistent_cache):
+    _compile_for_chip(jax.vmap(_plain),
+                      *_plain_operands(one_chip, BF16, lead=(2,)))
+
+
+def test_plain_attention_gradient_compiles_for_v5e(
+        one_chip, no_persistent_cache):
+    """The hybrid train step's pair: the kernel forward, the XLA
+    expression's VJP backward (which does write the scores)."""
+    def loss(q, k, v):
+        # squared, so that the backward needs the kernel's output
+        return jnp.sum(_plain(q, k, v).astype(jnp.float32) ** 2)
+
+    compiled = _compile_for_chip(jax.grad(loss, argnums=(0, 1, 2)),
+                                 *_plain_operands(one_chip, BF16))
+    assert re.search(SCORES, compiled.as_text())
 
 
 # keye_vl2_tok128's expert_outputs site: (blocks, rows, D, experts, F)
@@ -341,9 +391,14 @@ def test_the_hybrid_cells_view_program_compiles_and_fits_a_v5e(
     assert 4 * 752_425_932 < mem.argument_size_in_bytes < 3.02e9
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
              + mem.generated_code_size_in_bytes)
-    # 6.95 GB when written (temporaries 3.86): under half the chip's 16.9
-    assert total < 8.5e9, mem
-    # no [heads, L, L] score array and no decay tile for all 16 examples
+    # 6.80 GB since the kernel (temporaries 3.70; 6.95 and 3.86 with
+    # XLA's score tile, held to 8.5): under half the chip's 16.9
+    assert total < 8.35e9, mem
+    # the attention layer's tile is the kernel's: one custom call, and no
+    # score array of a tile, let alone [heads, L, L]; no decay tile for
+    # all 16 examples
     text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert not re.search(r"f32\[[\d,]*512,8192\]", text)
     assert not re.search(r"f32\[[\d,]*32,8192,8192\]", text)
     assert not re.search(r"f32\[16,[\d,]*256,256\]", text)

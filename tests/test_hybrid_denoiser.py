@@ -29,6 +29,7 @@ from diff3d_tpu.config import (MeshConfig, hybrid_test_config,  # noqa: E402
 from diff3d_tpu.models import (TokenDenoiser, UnsupportedModelError,  # noqa: E402
                                build_model)
 from diff3d_tpu.models import mamba, token_layers  # noqa: E402
+from diff3d_tpu.ops import dispatch  # noqa: E402
 from diff3d_tpu.ops.ssd import ssd  # noqa: E402
 from diff3d_tpu.utils.profiling import RECORDER  # noqa: E402
 
@@ -109,6 +110,33 @@ def test_forward_bfloat16_is_near_and_nearer_than_the_control(tiny):
     assert gap < 0.03 * size, (gap, size)
     assert float(jnp.abs(low - ref).mean()) > 3 * gap
     assert float(jnp.abs(dropped - ref).mean()) > 2 * gap
+
+
+def test_forward_with_the_attention_kernel_forced_is_the_reference(
+        monkeypatch):
+    """The whole model at head dim 64, float32: the attention layer's
+    ``sdpa`` runs its Pallas core (interpret mode, this being a CPU
+    process) inside the layer's maps over examples and query tiles, on
+    a ``q`` that carries ``attention_multiplier``.  The registry's policy
+    is patched to what it resolves on a TPU process at the cell's
+    widths; no option of the program does this."""
+    from diff3d_tpu.ops.pallas_attention import plain_supports
+
+    config = dict(TINY, hidden_size=256, mamba_n_heads=32)
+    cfg, mcfg = adapters_hybrid.build_config(config), rh.model_dict(config)
+    assert mcfg["head_dim"] == 64 and plain_supports(
+        jnp.zeros((1, 64, 4, 64)), *[jnp.zeros((1, 128, 2, 64))] * 2)
+    flat = perturbed(rh.make_params(mcfg, jax.random.PRNGKey(7))(),
+                     jax.random.PRNGKey(8))
+    impls = dispatch._REGISTRY["sdpa"]
+    monkeypatch.setitem(impls, "xla", impls["pallas"])
+    before = RECORDER.counters().get("sdpa.plain.pallas", 0)
+    batch = make_batch(jax.random.PRNGKey(1), 4, 2)
+    got = run_program(build_model(cfg), flat, batch)
+    assert RECORDER.counters()["sdpa.plain.pallas"] == before + 1
+    ref = jax.jit(lambda p: rh.forward(p, batch, MASK, mcfg))(flat)
+    assert float(jnp.abs(ref).mean()) > 0.05
+    np.testing.assert_allclose(got, ref, atol=5e-5, rtol=0)
 
 
 def test_g_rows_equal_repeated_rows(tiny):
@@ -313,10 +341,12 @@ def test_the_residual_multiplier_reaches_the_keye_layers_too():
     np.testing.assert_allclose(part, 0.25 * whole, atol=1e-6, rtol=0)
 
 
-def test_counters_one_per_traced_scan_site(tiny):
+def test_counters_one_per_traced_scan_site(tiny, monkeypatch):
     """``ssm_scan.xla``: 4 in the tiny model's program, 9 in the
-    full-width cell's (its nine Mamba-2 layers, unrolled); no counter of
-    the Keye layers moves."""
+    full-width cell's (its nine Mamba-2 layers, unrolled);
+    ``sdpa.plain.<core>``: one, the attention layer's tile (``xla`` on
+    this CPU process, ``pallas`` as a TPU process resolves the cell's);
+    no counter of the Keye layers moves."""
     def traced(cfg, model, params, B):
         before = RECORDER.counters()
         batch = jax.eval_shape(lambda: make_batch(
@@ -327,7 +357,7 @@ def test_counters_one_per_traced_scan_site(tiny):
         return {k: after[k] - before.get(k, 0) for k in after
                 if after[k] != before.get(k, 0)}
     d = traced(tiny["cfg"], tiny["model"], nest(tiny["flat"]), 4)
-    assert d.pop("ssm_scan.xla") == 4
+    assert d.pop("ssm_scan.xla") == 4 and d.pop("sdpa.plain.xla") == 1
     assert set(d) <= {"conditioning.groups", "conditioning.examples"}, d
     with open(os.path.join(ROOT, "benchmark", "configs",
                            "granite4_h_micro_tok128.json")) as f:
@@ -336,7 +366,11 @@ def test_counters_one_per_traced_scan_site(tiny):
     model = build_model(cfg)
     params = jax.eval_shape(
         lambda: init_params(model, cfg, jax.random.PRNGKey(0)))
-    assert traced(cfg, model, params, 16)["ssm_scan.xla"] == 9
+    d = traced(cfg, model, params, 16)
+    assert d["ssm_scan.xla"] == 9 and d["sdpa.plain.xla"] == 1
+    monkeypatch.setattr(dispatch, "default_backend", lambda: "tpu")
+    d = traced(cfg, model, params, 16)
+    assert d["sdpa.plain.pallas"] == 1 and "sdpa.plain.xla" not in d
 
 
 # ------------------------------------------------------ sampler and trainer

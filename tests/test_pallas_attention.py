@@ -143,7 +143,8 @@ def test_multi_head_attention_wrapper():
 
 def test_resolve_auto_policy(monkeypatch):
     """'auto' routes per measured policy: XLA off-TPU always; on TPU the
-    flash kernel only for lane-filling heads (D > 64) at L >= 4096."""
+    plain kernel from 2048 keys on at the head dims it has (64, whole
+    lane tiles), never ``flash_attention``."""
     from diff3d_tpu.ops import attention as att
 
     def q(L, D):
@@ -153,10 +154,12 @@ def test_resolve_auto_policy(monkeypatch):
     assert att._resolve_auto(q(16384, 128)) == "xla"  # off-TPU: always xla
 
     monkeypatch.setattr(att.jax, "default_backend", lambda: "tpu")
-    assert att._resolve_auto(q(4096, 32)) == "xla"    # 4x lane padding
-    assert att._resolve_auto(q(4096, 64)) == "xla"    # 2x lane padding
+    assert att._resolve_auto(q(4096, 32)) == "xla"    # no kernel wins here
+    assert att._resolve_auto(q(4096, 64)) == "pallas"
     assert att._resolve_auto(q(4096, 128)) == "pallas"
+    assert att._resolve_auto(q(2048, 128)) == "pallas"
     assert att._resolve_auto(q(1024, 128)) == "xla"   # short seq
+    assert att._resolve_auto(q(4096, 160)) == "xla"   # flash_attention's
 
 
 # --------------------------------------------------------------------------
@@ -343,3 +346,214 @@ def test_sdpa_with_keep_on_a_cpu_process_lowers_to_the_xla_expression():
     forced = jax.jit(lambda *a: sdpa(*a[:3], impl="pallas", keep=a[3])
                      ).lower(q, k, v, keep).as_text()
     assert forced != mine
+
+
+# --------------------------------------------------------------------------
+# plain grouped-query attention (plain_attention, sdpa without keep)
+# --------------------------------------------------------------------------
+
+from diff3d_tpu.ops import attention as att  # noqa: E402
+from diff3d_tpu.ops.pallas_attention import (plain_attention,  # noqa: E402
+                                             plain_supports)
+from diff3d_tpu.utils.profiling import RECORDER  # noqa: E402
+
+MULTIPLIER = 1.0 / 64       # granite-4.0-h-micro's attention_multiplier
+
+
+def _plain_operands(dtype, group, D, B=2, Lq=64, Lk=384, Hkv=2, seed=0):
+    """``q`` carries ``attention_multiplier * D^1/2``, as ``FullAttention``
+    hands it to ``sdpa``; Lq != Lk; 384 keys are three key blocks."""
+    rng = np.random.RandomState(seed)
+    q = jnp.asarray(rng.randn(B, Lq, Hkv * group, D) * 8, dtype)
+    k = jnp.asarray(rng.randn(B, Lk, Hkv, D), dtype)
+    v = jnp.asarray(rng.randn(B, Lk, Hkv, D), dtype)
+    return q * jnp.asarray(MULTIPLIER * D ** 0.5, dtype), k, v
+
+
+@pytest.mark.parametrize("D", [64, 128])
+@pytest.mark.parametrize("group", [1, 4])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_plain_attention_matches_xla(dtype, group, D):
+    q, k, v = _plain_operands(dtype, group, D)
+    assert plain_supports(q, k, v)
+    out = plain_attention(q, k, v, interpret=True)
+    ref = jax.nn.dot_product_attention(q, k, v)
+    assert out.shape == ref.shape and out.dtype == dtype
+    # float32: only the order of the sums differs; bf16: the unnormalised
+    # probabilities are rounded for PV where XLA rounds the normalised
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(_f32(out), _f32(ref), atol=tol, rtol=0)
+    # and against the float32 softmax over all keys, written out
+    qf, kf, vf = (a.astype(jnp.float32) for a in (q, k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", qf, jnp.repeat(kf, group, axis=2),
+                   precision="highest") / np.sqrt(float(D))
+    want = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1),
+                      jnp.repeat(vf, group, axis=2), precision="highest")
+    assert float(jnp.abs(want).max()) > 0.1        # not a uniform average
+    np.testing.assert_allclose(_f32(out), want, atol=tol, rtol=0)
+
+
+def test_plain_attention_odd_group_at_head_dim_64():
+    """Three query heads to a key-value head: the heads of one lane tile
+    then attend different key-value heads."""
+    q, k, v = _plain_operands(jnp.float32, 3, 64)
+    np.testing.assert_allclose(
+        plain_attention(q, k, v, interpret=True),
+        jax.nn.dot_product_attention(q, k, v), atol=2e-5, rtol=0)
+
+
+@pytest.mark.parametrize("how", ["vmap", "map", "vmap_of_map"])
+def test_plain_attention_under_the_call_paths_transformations(how):
+    """As ``FullAttention`` under the sampler: a leading axis by
+    ``jax.vmap`` (objects), by ``lax.map`` (examples), and ``lax.map``
+    over query tiles inside the vmap."""
+    q, k, v = _plain_operands(jnp.float32, 4, 64, B=3)
+    one = lambda q, k, v: plain_attention(                    # noqa: E731
+        q[None], k[None], v[None], interpret=True)[0]
+    tiles = lambda q, k, v: jax.lax.map(                      # noqa: E731
+        lambda a: one(a, k, v), q.reshape(2, 32, *q.shape[1:])
+    ).reshape(q.shape)
+    if how == "vmap":
+        out = jax.jit(jax.vmap(one))(q, k, v)
+    elif how == "map":
+        out = jax.jit(lambda *a: jax.lax.map(lambda b: one(*b), a))(q, k, v)
+    else:
+        out = jax.jit(jax.vmap(tiles))(q, k, v)
+    np.testing.assert_allclose(out, jax.nn.dot_product_attention(q, k, v),
+                               atol=2e-5, rtol=0)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_plain_attention_gradient_is_the_xla_expressions(dtype):
+    """No backward kernel: the cotangents are the XLA expression's VJP at
+    the saved operands, to the last bit, also under ``jax.grad``."""
+    q, k, v = _plain_operands(dtype, 4, 64)
+    g = jnp.asarray(np.random.RandomState(5).randn(*q.shape), dtype)
+    _, vjp = jax.vjp(lambda *a: plain_attention(*a, interpret=True), q, k, v)
+    _, want = jax.vjp(jax.nn.dot_product_attention, q, k, v)
+    for a, b in zip(vjp(g), want(g)):
+        assert a.dtype == dtype and float(jnp.abs(_f32(b)).max()) > 0
+        np.testing.assert_array_equal(_f32(a), _f32(b))
+    loss = lambda fn: jax.grad(lambda q: jnp.sum(                 # noqa: E731
+        fn(q, k, v).astype(jnp.float32) * g.astype(jnp.float32)))
+    np.testing.assert_array_equal(
+        _f32(loss(lambda *a: plain_attention(*a, interpret=True))(q)),
+        _f32(loss(jax.nn.dot_product_attention)(q)))
+
+
+# the hybrid cell's site and the X-UNet's (tests/test_chip_compile.py
+# ATTN_SITES): (Lq, Lk, Hq, Hkv, D)
+HYBRID_SITE = (512, 8192, 32, 8, 64)
+XUNET_SITES = [(256, 256, 4, 4, 64), (64, 64, 4, 4, 128),
+               (1024, 1024, 4, 4, 128), (256, 256, 4, 4, 256)]
+
+
+def _site(Lq, Lk, Hq, Hkv, D, dtype=jnp.bfloat16):
+    return (jax.ShapeDtypeStruct((1, Lq, Hq, D), dtype),
+            *[jax.ShapeDtypeStruct((1, Lk, Hkv, D), dtype)] * 2)
+
+
+PLAIN_UNSUPPORTED = {
+    "keys_not_whole_blocks": dict(Lk=200),
+    "queries_not_whole_blocks": dict(Lq=40),
+    "kv_heads_do_not_divide": dict(Hq=4, Hkv=3),
+    "head_dim_64_odd_kv_heads": dict(Hq=4, Hkv=1, D=64),
+    "head_dim_32": dict(D=32),
+}
+
+
+@pytest.mark.parametrize("case", list(PLAIN_UNSUPPORTED))
+def test_sdpa_pallas_on_operands_neither_kernel_supports_raises(case):
+    """Grouped heads are ``plain_attention``'s alone: where it cannot run
+    (``flash_attention`` wants a key-value head per query head) an
+    explicit ``'pallas'`` raises and ``'auto'`` falls to XLA, also on a
+    TPU process."""
+    p = dict(Lq=64, Lk=2048, Hq=4, Hkv=2, D=128)
+    p.update(PLAIN_UNSUPPORTED[case])
+    q, k, v = _site(**p, dtype=jnp.float32)
+    assert not plain_supports(q, k, v) and not supports(q, k, v)
+    with pytest.raises(ValueError, match="sdpa.*'pallas' was requested "
+                                         "explicitly"):
+        jax.eval_shape(lambda *a: sdpa(*a, impl="pallas"), q, k, v)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(dispatch, "default_backend", lambda: "tpu")
+        assert dispatch.resolve("sdpa", "auto", q, k, v).name == "xla"
+
+
+def _counted(fn, *args):
+    before = RECORDER.counters()
+    jax.eval_shape(lambda *a: fn(*a), *args)    # a new trace every time
+    after = RECORDER.counters()
+    return {k: after[k] - before.get(k, 0) for k in after
+            if k.startswith("sdpa.") and after[k] != before.get(k, 0)}
+
+
+def test_the_rule_on_a_tpu_process(monkeypatch):
+    """Backend TPU, whole blocks, ``Hkv`` dividing ``Hq``, 2048 keys or
+    more: the hybrid site takes the kernel and counts it once per traced
+    site; the X-UNet's four sites and ``sdpa(keep=)`` resolve as they
+    did."""
+    monkeypatch.setattr(dispatch, "default_backend", lambda: "tpu")
+    site = _site(*HYBRID_SITE)
+    assert dispatch.resolve("sdpa", "auto", *site).name == "pallas"
+    assert _counted(sdpa, *site) == {"sdpa.plain.pallas": 1}
+    for Lk in (2048, 4096):
+        assert dispatch.resolve("sdpa", "auto", *_site(
+            512, Lk, 32, 8, 64)).name == "pallas"
+    assert dispatch.resolve("sdpa", "auto", *_site(
+        512, 1024, 32, 8, 64)).name == "xla"
+    for xunet in XUNET_SITES:
+        assert dispatch.resolve("sdpa", "auto", *_site(*xunet)).name == "xla"
+        assert _counted(sdpa, *_site(*xunet)) == {"sdpa.plain.xla": 1}
+    q, k, v, keep = _selected_operands(jnp.bfloat16, 8, B=1)
+    assert _counted(lambda *a: sdpa(*a[:3], keep=a[3]), q, k, v, keep) \
+        == {"sdpa.selected.pallas": 1}
+
+
+def test_the_rule_on_a_cpu_process():
+    """Every site resolves to XLA here, counts ``sdpa.plain.xla``, and
+    lowers to the text of ``jax.nn.dot_product_attention``: no kernel, in
+    interpret mode or otherwise."""
+    for site in [HYBRID_SITE] + XUNET_SITES:
+        assert dispatch.resolve("sdpa", "auto", *_site(*site)).name == "xla"
+        assert _counted(sdpa, *_site(*site)) == {"sdpa.plain.xla": 1}
+    q, k, v = _plain_operands(jnp.float32, 4, 64, B=1, Lk=2048)
+    mine = jax.jit(lambda *a: sdpa(*a)).lower(q, k, v).as_text()
+    want = jax.jit(lambda *a: jax.nn.dot_product_attention(*a)).lower(
+        q, k, v).as_text()
+    assert mine == want
+    assert "custom_call" not in mine and "pallas" not in mine
+
+
+def test_sdpa_pallas_by_hand_picks_the_kernel_by_shape(monkeypatch):
+    """``impl='pallas'`` at the X-UNet's sites is ``flash_attention``
+    with its backward kernels; from 2048 keys on, on operands it
+    supports, and for grouped heads at any length, ``plain_attention``."""
+    from diff3d_tpu.ops import pallas_attention as pa
+
+    called = []
+    for name in ("flash_attention", "plain_attention"):
+        monkeypatch.setattr(pa, name, lambda q, k, v, name=name: (
+            called.append(name), q)[1])
+    for site in XUNET_SITES:
+        jax.eval_shape(lambda *a: sdpa(*a, impl="pallas"), *_site(*site))
+    assert called == ["flash_attention"] * 4
+    jax.eval_shape(lambda *a: sdpa(*a, impl="pallas"), *_site(*HYBRID_SITE))
+    jax.eval_shape(lambda *a: sdpa(*a, impl="pallas"),
+                   *_site(200, 4096, 4, 4, 128))       # ragged queries
+    jax.eval_shape(lambda *a: sdpa(*a, impl="pallas"),
+                   *_site(64, 256, 4, 2, 64))          # grouped heads
+    assert called[4:] == ["plain_attention", "flash_attention",
+                          "plain_attention"]
+
+
+def test_sdpa_without_keep_routes_by_request():
+    q, k, v = _plain_operands(jnp.float32, 4, 64, B=1, Lk=2048)
+    ref = jax.nn.dot_product_attention(q, k, v)
+    np.testing.assert_array_equal(sdpa(q, k, v, impl="xla"), ref)
+    np.testing.assert_array_equal(sdpa(q, k, v), ref)         # CPU: xla
+    np.testing.assert_allclose(sdpa(q, k, v, impl="pallas"), ref,
+                               atol=2e-5, rtol=0)
+    assert att.PLAIN_MIN_KEYS == 2048
