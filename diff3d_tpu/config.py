@@ -103,8 +103,12 @@ class TokenModelConfig:
     grouped-query attention over all keys with no position of any kind
     and scores times ``attention_multiplier``; ``"mamba"``, a Mamba-2
     state-space mixer (``mamba_*``; one group, conv bias, no projection
-    bias).  Which feed-forward: routed experts where ``num_experts > 0``,
-    else a dense gated MLP of ``shared_intermediate_size``.
+    bias).  Which feed-forward: routed experts where ``num_experts > 0``
+    (each ``moe_intermediate_size`` wide: the ``intermediate_size`` of a
+    published config that has no key of its own for it), else a dense
+    gated MLP of ``shared_intermediate_size``; with both, the MLP is a
+    shared expert beside the routed ones, ``h += r (routed(u) +
+    mlp(u))`` on one normed ``u``.
 
     ``experts_held`` is ``(first, count)``: the router scores all
     ``num_experts``, the layer holds the weights of experts ``first ..
@@ -552,6 +556,19 @@ def hybrid_test_config(imgsize: int = 16) -> Config:
     )
 
 
+def hybrid_moe_test_config(imgsize: int = 16) -> Config:
+    """:func:`hybrid_test_config` with the feed-forward of two branches:
+    routed experts (24, top-4, 32 wide) beside a shared expert (48 wide)
+    under one norm, and of the experts one of eight chips' share, the
+    first three."""
+    base = hybrid_test_config(imgsize)
+    return dataclasses.replace(base, model=dataclasses.replace(
+        base.model, num_experts=24, num_experts_per_tok=4,
+        moe_intermediate_size=32, shared_intermediate_size=48,
+        experts_held=(0, 3), expert_token_chunk=2 * base.model.tokens,
+        expert_block=16))
+
+
 def test_config(imgsize: int = 16, ch: int = 8,
                 shallow: bool = False) -> Config:
     """Tiny config for unit tests / CPU-mesh dry runs.
@@ -579,7 +596,8 @@ def test_config(imgsize: int = 16, ch: int = 8,
 #: in this module that builds each.
 NAMED_CONFIGS = {"srn64": "srn64_config", "srn128": "srn128_config",
                  "test": "test_config", "token_test": "token_test_config",
-                 "hybrid_test": "hybrid_test_config"}
+                 "hybrid_test": "hybrid_test_config",
+                 "hybrid_moe_test": "hybrid_moe_test_config"}
 
 
 def named_config(name: str) -> Config:

@@ -9,6 +9,13 @@ experts held that is the whole layer; the shares of a set of chips that
 together hold every expert add up to it (tests/test_token_denoiser.py).
 No token is dropped: there is no capacity.
 
+A block whose feed-forward has a second branch on the same normed tokens
+(a shared expert: ``h += r (routed(u) + shared(u))``, one norm, one
+residual add) hands that branch to the layer as ``beside``, a function
+of a chunk's normed tokens: it runs inside the chunk map, between the
+norm and the add, and is counted whole on every chip where the routed
+part is a share (tests/test_hybrid_moe_denoiser.py adds the shares up).
+
 How the experts' matmuls are laid out (:func:`expert_outputs`): the
 ``T x k`` assignments of a chunk of ``T`` tokens are sorted by expert and
 each expert's run is padded to a multiple of ``block`` rows, so that
@@ -36,7 +43,7 @@ objects).
 
 from __future__ import annotations
 
-from typing import Callable, Tuple
+from typing import Callable, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -158,10 +165,15 @@ def expert_outputs(x: jnp.ndarray, ids: jnp.ndarray, gates: jnp.ndarray,
 
 
 class RoutedExperts(nn.Module):
-    """``h [..., D] -> h + experts(norm(h))``: the layer's second half, the
-    held experts' part of it.  Norm, routing, experts and the residual
-    add run on one chunk of ``token_chunk`` tokens at a time, so only the
-    layer's input and output exist at the size of the whole call."""
+    """``h [..., D] -> h + r (experts(u) + beside(u))``, ``u = norm(h)``:
+    the layer's second half, the held experts' part of it and, where the
+    block has one, a second branch ``beside`` (``[T, D]`` normed tokens in
+    the compute dtype ``-> [T, D]``) on the same ``u`` under the same
+    residual add.  Norm, routing, experts, ``beside`` and the residual add
+    run on one chunk of ``token_chunk`` tokens at a time, so only the
+    layer's input and output exist at the size of the whole call.  Each
+    traced site with a ``beside`` adds 1 to the recorder's
+    ``experts.shared``."""
 
     num_experts: int
     top_k: int
@@ -174,8 +186,9 @@ class RoutedExperts(nn.Module):
     dtype: jnp.dtype = jnp.float32
 
     @nn.compact
-    def __call__(self, h: jnp.ndarray, norm_scale: jnp.ndarray
-                 ) -> jnp.ndarray:
+    def __call__(self, h: jnp.ndarray, norm_scale: jnp.ndarray,
+                 beside: Optional[Callable[[jnp.ndarray], jnp.ndarray]]
+                 = None) -> jnp.ndarray:
         D = h.shape[-1]
         first, held = self.held
         x = h.reshape(-1, D)
@@ -220,9 +233,13 @@ class RoutedExperts(nn.Module):
             with scope("experts"):
                 y = expert_outputs(xc, ids, gates, w_gate, w_up, w_down,
                                    first=first, block=self.block)
+            if beside is not None:
+                y = y.astype(jnp.float32) + beside(xc)
             with scope("residual"):
                 return add_residual(hc, y, self.residual)
 
+        if beside is not None:
+            count("experts.shared")
         with scope("experts"):
             y = jax.lax.map(one_chunk, x.reshape(T // chunk, chunk, D))
             return y.reshape(h.shape)

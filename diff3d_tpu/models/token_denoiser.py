@@ -1,8 +1,9 @@
 """A token denoiser: both frames as one sequence of patches through
 decoder layers, each a sequence mixer (selected-key attention, attention
 over all keys, or a Mamba-2 state-space mixer: the config's
-``layer_types``) and a feed-forward (routed experts, or a dense gated
-MLP).
+``layer_types``) and a feed-forward (routed experts, a dense gated MLP,
+or both on one normed input under one residual add: routed experts
+beside a shared expert).
 
 The second kind of denoiser beside the X-UNet, on the same forward
 contract (docs/DESIGN.md §1): batch dict with ``x [B,H,W,3]``,
@@ -23,7 +24,8 @@ computed at ``G`` rows and meet the examples in one broadcast add.
            sinusoid; the sum times ``embedding_multiplier``.
   layers   pre-norm, ``h += r mixer(norm(h)); h += r ffn(norm(h))`` with
            ``r = residual_multiplier`` (:mod:`.sparse_attention`,
-           :mod:`.token_layers`, :mod:`.mamba`; :mod:`.moe`).
+           :mod:`.token_layers`, :mod:`.mamba`; :mod:`.moe`); with both
+           feed-forwards, ``ffn(u) = routed(u) + mlp(u)``.
   output   RMSNorm, a linear head to ``patch^2 * 3`` values per target
            token over ``logits_scaling``, un-patchified.
 
@@ -72,7 +74,11 @@ class DecoderLayer(nn.Module):
     beside ``attn``, ...); each half applies its norm and its residual add
     itself, example by example and chunk by chunk.  ``kind`` is the
     layer's entry of ``cfg.mixers``; the feed-forward is the routed
-    experts where the config has experts, else the dense MLP."""
+    experts where the config has experts, else the dense MLP.  A config
+    with both widths (``num_experts`` and ``shared_intermediate_size``)
+    has both under the experts' norm and residual add: the MLP is then
+    no half of its own (no ``mlp_norm``) but the branch ``moe`` computes
+    beside the experts, chunk by chunk, on the same normed tokens."""
 
     cfg: TokenModelConfig
     kind: str = "sparse_attention"
@@ -120,16 +126,24 @@ class DecoderLayer(nn.Module):
                 held=tuple(cfg.experts_held),
                 token_chunk=cfg.expert_token_chunk, block=cfg.expert_block,
                 **common)
-        else:
+        if cfg.shared_intermediate_size:
             self.mlp = GatedMLP(hidden=cfg.hidden_size,
                                 width=cfg.shared_intermediate_size,
                                 **common)
 
     def __call__(self, h: jnp.ndarray) -> jnp.ndarray:
-        for half in self.halves:
-            norm_scale = getattr(self, half + "_norm")(h.shape[-1])
-            h = getattr(self, half)(h, norm_scale)
-        return h
+        mixer = self.halves[0]
+        h = getattr(self, mixer)(h, getattr(self, mixer + "_norm")(
+            h.shape[-1]))
+        return self.feed_forward(h)
+
+    def feed_forward(self, h: jnp.ndarray) -> jnp.ndarray:
+        """The layer's second half, ``h + r ffn(norm(h))``."""
+        half = self.halves[1]
+        norm_scale = getattr(self, half + "_norm")(h.shape[-1])
+        if half == "moe" and self.cfg.shared_intermediate_size:
+            return self.moe(h, norm_scale, beside=self.mlp.branch())
+        return getattr(self, half)(h, norm_scale)
 
 
 class TokenDenoiser(nn.Module):
@@ -160,6 +174,9 @@ class TokenDenoiser(nn.Module):
         # once per trace, like the compile.* events
         count("conditioning.groups", G)
         count("conditioning.examples", B)
+        if cfg.num_experts:
+            count("experts.held", cfg.experts_held[1])
+            count("experts.of", cfg.num_experts)
 
         with scope("conditioning"):
             logsnr = jnp.clip(batch["logsnr"], -cfg.logsnr_clip,

@@ -104,8 +104,12 @@ class GatedMLP(nn.Module):
         self.w1 = Kernel(2 * self.width)
         self.w2 = Kernel(self.hidden)
 
-    def __call__(self, h: jnp.ndarray, norm_scale: jnp.ndarray
-                 ) -> jnp.ndarray:
+    def branch(self):
+        """``u [T, D] -> W_2 (silu(a) * b)`` float32, a plain function of
+        the layer's kernels: what a map's body may call (no module can
+        be), here over examples and, where the MLP is a shared expert
+        beside routed ones, in their chunk map
+        (:class:`~diff3d_tpu.models.moe.RoutedExperts` ``beside``)."""
         W1, W2 = self.w1(self.hidden), self.w2(self.width)
 
         def mlp(u):
@@ -114,6 +118,11 @@ class GatedMLP(nn.Module):
                 g = nn.silu(a.astype(jnp.float32)) * b.astype(jnp.float32)
                 return dense(g, W2, self.dtype, jnp.float32)
 
+        return mlp
+
+    def __call__(self, h: jnp.ndarray, norm_scale: jnp.ndarray
+                 ) -> jnp.ndarray:
+        mlp = self.branch()
         with scope("mlp"):
             return residual_half(h, norm_scale, self.eps, self.residual,
                                  mlp)

@@ -214,7 +214,10 @@ def tp_param_sharding(mesh: Mesh, path, shape: Sequence[int],
         what its dynamic expert index needs, with no token exchange.
       * the token denoiser's dense MLP ``mlp/w1`` ``[D, 2 F]`` column-
         and ``mlp/w2`` ``[F, D]`` row-parallel; its plain attention's
-        ``q/k/v_proj`` and ``o_proj`` likewise.
+        ``q/k/v_proj`` and ``o_proj`` likewise.  The same two leaves
+        where the MLP is a shared expert beside an expert stack (one
+        layer then has ``moe/...`` by whole experts, ``mlp/...`` split
+        inside, and a replicated mixer).
       * every leaf of a state-space mixer (``mamba/...``) — replicated,
         explicitly: the fused ``[z | xBC | dt]`` projection does not
         split at one column boundary, and the heads' state, conv and

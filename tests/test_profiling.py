@@ -313,6 +313,37 @@ def test_the_hybrid_layers_classes_are_in_the_view_program():
     assert len(named_untagged) / len(tags) < 0.002, named_untagged[:5]
 
 
+def test_a_hybrid_layer_with_both_feed_forwards_splits_into_eight_classes():
+    """The view program of the hybrid preset with routed experts beside a
+    shared expert: the mixers' classes, and the one feed-forward half as
+    ``moe_router | experts | mlp`` (the shared expert is class ``mlp``
+    though it runs inside the experts' chunk map)."""
+    from diff3d_tpu.config import hybrid_moe_test_config
+    from diff3d_tpu.models import build_model
+    from diff3d_tpu.sampling import Sampler
+
+    cfg = hybrid_moe_test_config()
+    model = build_model(cfg)
+    params = jax.eval_shape(
+        lambda: init_params(model, cfg, jax.random.PRNGKey(0)))
+    low = Sampler(model, params, cfg, sampler_kind="ddim",
+                  steps=2).lower_step_many(1, 2)
+    with no_compile_cache():
+        text = low.compile().as_text()
+    ops = op_names(text)
+    tags = [op_class(n)[0] for _, n in ops]
+    assert set(tags) - {None} == HYBRID_SCOPES | {
+        "moe_router", "experts", "attention", "residual", "patch_embed",
+        "conditioning", "sampler", "record"}
+    # the shared expert's two matmuls are ``mlp`` under ``experts``
+    inside = [n for _, n in ops if "d3d.experts" in n and "d3d.mlp" in n]
+    assert inside and all(op_class(n)[0] == "mlp" for n in inside)
+    assert tags.count(None) / len(tags) < 0.15
+    named_untagged = [n for (_, n), t in zip(ops, tags)
+                      if t is None and "/" in n]
+    assert len(named_untagged) / len(tags) < 0.002, named_untagged[:5]
+
+
 def test_every_class_is_in_the_compiled_programs_and_few_ops_have_none(
         programs):
     train, view = programs["lower"]()
