@@ -22,23 +22,41 @@ each expert's run is padded to a multiple of ``block`` rows, so that
 every block of rows belongs to one expert; each block is multiplied by
 that expert's three matrices (the number of blocks is a static bound,
 ``T k / block + experts``, which covers any routing: no capacity, and a
-call's shape does not depend on how the tokens were routed).  Rows come
-in by one gather and go back by one gather and a gated sum over the ``k``
-slots; the ``[T x k, D]`` dispatch buffer exists for one chunk at a time
+call's shape does not depend on how the tokens were routed).  The ``[T x
+k, D]`` dispatch buffer exists for one chunk at a time
 (:class:`RoutedExperts` maps over chunks of ``token_chunk`` tokens).
 
-The blocks' matmuls are op ``'expert_ffn'`` of the kernel registry
-(:mod:`diff3d_tpu.ops.pallas_moe`), two cores chosen from what the
-process and the shapes are, by no option: on a TPU process, where the
-widths are whole lane tiles, one grouped Pallas kernel that fetches an
-expert's matrices once per run of blocks, fuses gate, up and down per
-block and skips the blocks of the bound past the last run; everywhere
-else (CPU processes: tests, the analysis passes, ``token_test``) a
-``lax.scan`` over all the blocks, which is also the kernel's gradient.
-The sort, the layout's index arithmetic, the gathers and the gated sum
-are plain XLA on both.  Either way the layer is differentiable and
-indifferent to ``vmap`` (the sampler maps its view program over
-objects).
+Three ops of the kernel registry (:mod:`diff3d_tpu.ops.pallas_moe`) do
+the work on that layout, each with an XLA core and a Pallas core chosen
+from what the process, the shapes and the layer are, by no option:
+
+  * ``'expert_rows'`` - the rows into the layout.  XLA: one gather over
+    every row of the static bound.  Pallas: one row copy (a DMA) for each
+    valid row of a block in use; a block past the last run costs a grid
+    step and nothing else.
+  * ``'expert_ffn'`` - the blocks' matmuls.  On a TPU process, where the
+    widths are whole lane tiles, one grouped Pallas kernel that fetches
+    an expert's matrices once per run of blocks, fuses gate, up and down
+    per block and skips the blocks of the bound past the last run;
+    everywhere else a ``lax.scan`` over all the blocks, which is also the
+    kernel's gradient.
+  * ``'expert_combine'`` - the rows back out as the gated sum over the
+    ``k`` slots.  XLA: one gather over all ``T k`` assignments (those
+    held elsewhere read a zero row), the picked rows in float32, the sum.
+    Pallas: one row copy for each assignment held here, the sum in
+    float32 in slot order, no ``[T, k, D]`` tile.
+
+The two ways rows move cost XLA by the static bound and the kernels by
+the rows that land here, so the kernels are taken **where the layer holds
+at most half of the experts it routes over** (``of=``: a share of eight
+chips' experts fills an eighth of the bound) on a TPU process; with
+every expert held the bound is tight and XLA's bulk gathers are as fast
+or faster (PERF.md section 5 has both cells' readings).  CPU processes
+(tests, the analysis passes, the tiny presets) take the XLA cores of all
+three.  The sort and the layout's index arithmetic are plain XLA on
+both.  Either way the layer is differentiable (each kernel's gradient is
+its XLA expression's) and indifferent to ``vmap`` (the sampler maps its
+view program over objects).
 """
 
 from __future__ import annotations
@@ -50,7 +68,7 @@ import jax
 import jax.numpy as jnp
 
 from diff3d_tpu.ops import dispatch
-from diff3d_tpu.ops import pallas_moe  # noqa: F401 - registers 'expert_ffn'
+from diff3d_tpu.ops import pallas_moe  # noqa: F401 - registers the ops
 from diff3d_tpu.utils.profiling import count, scope
 
 
@@ -98,6 +116,7 @@ def route(logits: jnp.ndarray, k: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
 def expert_outputs(x: jnp.ndarray, ids: jnp.ndarray, gates: jnp.ndarray,
                    w_gate: jnp.ndarray, w_up: jnp.ndarray,
                    w_down: jnp.ndarray, *, first: int, block: int,
+                   of: Optional[int] = None,
                    impl: str = "auto") -> jnp.ndarray:
     """Gated sum of the held experts' outputs for one chunk of tokens.
 
@@ -106,11 +125,15 @@ def expert_outputs(x: jnp.ndarray, ids: jnp.ndarray, gates: jnp.ndarray,
     ``w_down [E, F, D]`` the held experts ``first .. first + E - 1``, in
     the compute dtype.
     Expert ``e``: ``w_down_e (silu(w_gate_e x) * w_up_e x)``.
+    ``of`` is the number of experts ``ids`` range over (``None``: the
+    ``E`` held are all), which the rows' two ops read beside ``E``.
     ``impl`` ('auto' | 'pallas' | 'xla') is the request to the registry
-    for the blocks' matmuls; the layer leaves it at 'auto'.  Each traced
-    site adds 1 to the recorder's ``experts.pallas`` or ``experts.xla``.
+    for all three ops; the layer leaves it at 'auto'.  Each traced site
+    adds 1 to the recorder's ``experts.<core>`` (the blocks' matmuls),
+    ``experts.rows.<core>`` and ``experts.combine.<core>`` by the core
+    each resolved to.
     """
-    T, D = x.shape
+    T = x.shape[0]
     K = ids.shape[1]
     E = w_gate.shape[0]
     A, m = T * K, block
@@ -139,9 +162,11 @@ def expert_outputs(x: jnp.ndarray, ids: jnp.ndarray, gates: jnp.ndarray,
     r = jnp.arange(n_blocks * m) - spread(poff[e_blk])
     valid = r < spread(cnt[e_blk])
     src = order[jnp.clip(spread(off[e_blk]) + r, 0, A - 1)]
-    token = jnp.where(valid, src // K, T)            # T: the zero row
-    x0 = jnp.concatenate([x, jnp.zeros((1, D), x.dtype)])
-    rows = x0[token].reshape(n_blocks, m, D)
+    token = jnp.where(valid, src // K, T)            # T: a padding row
+    share = dict(held=E, of=E if of is None else of)
+    core = dispatch.resolve("expert_rows", impl, x, token, ends, m, **share)
+    count(f"experts.rows.{core.name}")
+    rows = core.fn(x, token, ends, m)
 
     core = dispatch.resolve("expert_ffn", impl, rows, e_blk, ends,
                             w_gate, w_up, w_down)
@@ -158,10 +183,10 @@ def expert_outputs(x: jnp.ndarray, ids: jnp.ndarray, gates: jnp.ndarray,
     at = jnp.where(e_of < E,
                    pos + (onehot * shift[None, :]).sum(axis=1),
                    n_blocks * m)
-    y0 = jnp.concatenate([ys.reshape(n_blocks * m, D),
-                          jnp.zeros((1, D), ys.dtype)])
-    picked = y0[at].reshape(T, K, D).astype(jnp.float32)
-    return (picked * gates[..., None]).sum(axis=1).astype(x.dtype)
+    core = dispatch.resolve("expert_combine", impl, ys, at, gates, ends,
+                            **share)
+    count(f"experts.combine.{core.name}")
+    return core.fn(ys, at, gates, ends)
 
 
 class RoutedExperts(nn.Module):
@@ -232,7 +257,8 @@ class RoutedExperts(nn.Module):
                 ids, gates = route(logits, self.top_k)
             with scope("experts"):
                 y = expert_outputs(xc, ids, gates, w_gate, w_up, w_down,
-                                   first=first, block=self.block)
+                                   first=first, block=self.block,
+                                   of=self.num_experts)
             if beside is not None:
                 y = y.astype(jnp.float32) + beside(xc)
             with scope("residual"):

@@ -45,6 +45,16 @@ chunk of 4096 tokens at top-10, the configuration's tile; 329 a chunk of
 layers, 2.02 B parameters) is compiled once and its memory held to the
 chip's, 29 s.
 
+Since PR 33 the rows of that cell's experts move by three more kernels
+a layer (``expert_rows``, the re-tiling of the blocks in use, and
+``expert_combine``: per-row DMAs addressed through ``[rows, D / 128,
+128]``, SMEM operands by their own block specs, the object axis a grid
+axis): compiled at both token cells' shapes in bf16 and float32, under
+the sampler's ``vmap`` over one and two objects, and with the XLA
+expressions' VJPs behind them; the view program holds 41 custom calls and
+neither the gather over all ``T k`` assignments nor the float32 tile of
+picked rows.
+
 Fixture rules (on-chip-measurement guide, section 2): the topology is
 described inside a module-scoped, non-autouse fixture of THIS file, which
 skips where it cannot be described — never at import, in ``skipif``, in
@@ -65,7 +75,8 @@ from diff3d_tpu.ops.pallas_attention import (flash_attention,
                                              plain_attention,
                                              selected_attention)
 from diff3d_tpu.ops.pallas_film import fused_groupnorm
-from diff3d_tpu.ops.pallas_moe import expert_ffn
+from diff3d_tpu.ops.pallas_moe import (expert_combine, expert_ffn,
+                                       expert_rows)
 
 F32, BF16 = "float32", "bfloat16"
 
@@ -433,6 +444,75 @@ def test_expert_ffn_on_a_share_at_hidden_4096_compiles_for_v5e(
     assert not re.search(r"(f32|bf16)\[[\d,]*256,768\]", compiled.as_text())
 
 
+# the chunk whose rows move: (tokens, top-k, D, blocks, rows a block) at
+# granite4_h_small_tok128 (9 of 72 held: where the kernels run) and at
+# keye_vl2_tok128 (all held: where ``auto`` leaves them, by hand here)
+MOVE_SITES = {"h_small": (4096, 10, 4096, 169, 256),
+              "keye": (8192, 8, 2048, 384, 256)}
+
+
+def _move_operands(one_chip, site, dtype, lead=()):
+    T, K, D, n, m = MOVE_SITES[site]
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(lead + shape, jnp.dtype(dt),
+                                    sharding=one_chip)
+    ends = sds((9,), "int32")
+    return ((sds((T, D), dtype), sds((n * m,), "int32"), ends),
+            (sds((n, m, D), dtype), sds((T * K,), "int32"),
+             sds((T, K), F32), ends))
+
+
+def _rows_in(x, token, ends):
+    return expert_rows(x, token, ends, 256, interpret=False)
+
+
+def _sum_back(ys, at, gates, ends):
+    return expert_combine(ys, at, gates, ends, interpret=False)
+
+
+@pytest.mark.parametrize("dtype", [BF16, F32])
+@pytest.mark.parametrize("site", list(MOVE_SITES))
+def test_the_rows_way_in_and_back_compile_for_v5e(one_chip,
+                                                  no_persistent_cache,
+                                                  site, dtype):
+    rows, back = _move_operands(one_chip, site, dtype)
+    T, K, D, n, m = MOVE_SITES[site]
+    text = _compile_for_chip(_rows_in, *rows).as_text()
+    # no gather over the static bound beside the kernel
+    assert not re.search(rf"(f32|bf16)\[{n * m},{D}\]", text)
+    text = _compile_for_chip(_sum_back, *back).as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert not re.search(rf"(f32|bf16)\[{T * K},{D}\]", text)
+    assert not re.search(rf"f32\[{T},{K},{D}\]", text)
+
+
+@pytest.mark.parametrize("objects", [1, 2])
+def test_the_rows_way_in_and_back_under_the_samplers_vmap_compile_for_v5e(
+        one_chip, no_persistent_cache, objects):
+    """The object axis is a grid axis of each kernel: no loop that slices
+    an object's operands out (at two objects ``expert_ffn``'s prefetched
+    tables make jax loop; these do not)."""
+    rows, back = _move_operands(one_chip, "h_small", BF16, lead=(objects,))
+    for fn, operands in [(_rows_in, rows), (_sum_back, back)]:
+        text = _compile_for_chip(jax.vmap(fn), *operands).as_text()
+        assert "while" not in text
+
+
+def test_the_rows_way_in_and_back_gradients_compile_for_v5e(
+        one_chip, no_persistent_cache):
+    """The token train step's pairs: each kernel forward, its XLA
+    expression's VJP backward."""
+    rows, back = _move_operands(one_chip, "h_small", BF16)
+
+    def square(y):
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+    _compile_for_chip(jax.grad(lambda x, t, e: square(_rows_in(x, t, e))),
+                      *rows)
+    _compile_for_chip(jax.grad(lambda y, a, g, e: square(
+        _sum_back(y, a, g, e)), argnums=(0, 2)), *back)
+
+
 def test_the_hybrid_moe_cells_view_program_compiles_and_fits_a_v5e(
         one_chip, no_persistent_cache, monkeypatch):
     """``Sampler._run_view_many`` of ``benchmark/configs/
@@ -474,10 +554,15 @@ def test_the_hybrid_moe_cells_view_program_compiles_and_fits_a_v5e(
     # over the chip at an expert chunk of 8192 tokens) of the chip's 16.91:
     # the compiler does not always refuse a program that is over
     assert total < 15.6e9, mem
-    # ten expert sites and the attention site on their kernels, no score
-    # array of a tile, no decay tile for all 16 examples
+    # ten expert sites (rows in, blocks, re-tiling, gated sum back) and
+    # the attention site on their kernels; no gather over all ``T k``
+    # assignments or over the static bound of rows, no float32 tile of
+    # picked rows; no score array of a tile, no decay tile for all 16
+    # examples
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == 11
+    assert text.count("tpu_custom_call") == 41
+    assert not re.search(r"bf16\[(40960|43264|43265),4096\]", text)
+    assert not re.search(r"f32\[4096,10,4096\]", text)
     assert not re.search(r"f32\[[\d,]*512,8192\]", text)
     assert not re.search(r"f32\[[\d,]*32,8192,8192\]", text)
     assert not re.search(r"f32\[16,[\d,]*256,256\]", text)

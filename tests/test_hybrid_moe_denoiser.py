@@ -203,10 +203,12 @@ def test_the_shared_expert_reads_the_norm_of_the_layers_input(tiny):
 @pytest.fixture
 def kernel_forced(monkeypatch):
     """The registry's policy patched to what it resolves on a TPU process:
-    the experts' blocks at ``impl='auto'`` take their Pallas core (in
-    interpret mode, this being a CPU process)."""
-    impls = dispatch._REGISTRY["expert_ffn"]
-    monkeypatch.setitem(impls, "xla", impls["pallas"])
+    the experts' blocks and, the layer holding a share, the rows' way in
+    and back at ``impl='auto'`` take their Pallas cores (in interpret
+    mode, this being a CPU process)."""
+    for op in ("expert_rows", "expert_ffn", "expert_combine"):
+        impls = dispatch._REGISTRY[op]
+        monkeypatch.setitem(impls, "xla", impls["pallas"])
 
 
 @pytest.mark.parametrize("core", ["xla", "pallas"])
@@ -232,7 +234,9 @@ def test_the_eight_shares_and_the_shared_expert_once_are_the_uncut_layer(
     want = jnp.stack([rm.feed_forward(hb, mine.__getitem__, uncut,
                                       "float32", True)[0] for hb in h]) - h
     assert float(jnp.abs(want).mean()) > 0.05
-    before = RECORDER.counters().get(f"experts.{core}", 0)
+    names = [f"experts.{core}", f"experts.rows.{core}",
+             f"experts.combine.{core}"]
+    before = [RECORDER.counters().get(n, 0) for n in names]
 
     def share(first, shared: bool):
         m = adapters_hybrid_moe.build_config(
@@ -246,7 +250,8 @@ def test_the_eight_shares_and_the_shared_expert_once_are_the_uncut_layer(
             {"params": nest(p)}, h, method="feed_forward") - h
     routed = [share(9 * s, False) for s in range(8)]
     both = [share(9 * s, True) for s in range(8)]
-    assert RECORDER.counters()[f"experts.{core}"] == before + 16
+    assert [RECORDER.counters()[n] for n in names] == [b + 16
+                                                       for b in before]
     alike = [b - a for a, b in zip(routed, both)]
     for s in range(8):
         assert float(jnp.abs(routed[s]).mean()) > 1e-3, s
@@ -269,9 +274,11 @@ def test_the_eight_shares_and_the_shared_expert_once_are_the_uncut_layer(
 def test_counters_once_per_traced_site(tiny, monkeypatch):
     """A traced program counts ``experts.held`` / ``experts.of`` once
     (like ``conditioning.groups``), ``experts.shared`` and
-    ``experts.<core>`` once per layer, beside the mixers' counters; at
-    the cell's widths, resolved as a TPU process resolves them: ten
-    expert sites and the attention site on their kernels."""
+    ``experts.<core>``, ``experts.rows.<core>`` and
+    ``experts.combine.<core>`` once per layer, beside the mixers'
+    counters; at the cell's widths, resolved as a TPU process resolves
+    them: ten expert sites (blocks, rows in, gated sum back) and the
+    attention site on their kernels."""
     def traced(cfg, model, params, B):
         before = RECORDER.counters()
         batch = jax.eval_shape(lambda: make_batch(
@@ -284,7 +291,9 @@ def test_counters_once_per_traced_site(tiny, monkeypatch):
     d = traced(tiny["cfg"], tiny["model"], nest(tiny["flat"]), 4)
     assert d == {"conditioning.groups": 2, "conditioning.examples": 4,
                  "experts.held": 3, "experts.of": 24, "experts.shared": 5,
-                 "experts.xla": 5, "ssm_scan.xla": 4, "sdpa.plain.xla": 1}
+                 "experts.xla": 5, "experts.rows.xla": 5,
+                 "experts.combine.xla": 5, "ssm_scan.xla": 4,
+                 "sdpa.plain.xla": 1}
     with open(os.path.join(CONFIGS, "granite4_h_small_tok128.json")) as f:
         cfg = adapters_hybrid_moe.build_config(json.load(f))
     from diff3d_tpu.train.trainer import init_params
@@ -295,8 +304,21 @@ def test_counters_once_per_traced_site(tiny, monkeypatch):
     d = traced(cfg, model, params, 16)
     assert d == {"conditioning.groups": 2, "conditioning.examples": 16,
                  "experts.held": 9, "experts.of": 72, "experts.shared": 10,
-                 "experts.pallas": 10, "ssm_scan.xla": 9,
+                 "experts.pallas": 10, "experts.rows.pallas": 10,
+                 "experts.combine.pallas": 10, "ssm_scan.xla": 9,
                  "sdpa.plain.pallas": 1}
+    # Keye's layers hold every expert they route over: the rows move by
+    # XLA's gathers there, the blocks through the kernel as before
+    from benchmark import adapters_tokens
+    with open(os.path.join(CONFIGS, "keye_vl2_tok128.json")) as f:
+        kcfg = adapters_tokens.build_config(json.load(f))
+    kmodel = build_model(kcfg)
+    d = traced(kcfg, kmodel, jax.eval_shape(
+        lambda: init_params(kmodel, kcfg, jax.random.PRNGKey(0))), 16)
+    assert {k: v for k, v in d.items() if k.startswith("experts.")} == {
+        "experts.held": 128, "experts.of": 128, "experts.pallas": 4,
+        "experts.rows.xla": 4, "experts.combine.xla": 4}
+    assert d["sdpa.selected.pallas"] == 4
     # a model without experts counts none of them; one without a shared
     # expert no ``experts.shared``
     hcfg = hybrid_test_config()
@@ -430,6 +452,11 @@ def test_xunet_only_entry_points_refuse_the_config(entry, tmp_path):
 #: moves the texts: take them anew from a tree whose tests pass.
 HYBRID_TREE = "433087474870df4f95d4120fd607127dadca1f0a37613f8a1fca1fa6a77dd73b"
 HYBRID_TEXT = "cfd14b67f3ba5bccf4edd754aa6c8772558b2c0eef92760ee36038b638b31275"
+#: the same of ``hybrid_moe_test`` (3 of 24 experts held), taken from
+#: commit c132263 before the rows' way in and back became ops of the
+#: registry: a CPU process resolves both to the expressions it had
+MOE_TREE = "89f38fcfa91661377fe93c69a9e72023020d673ba996c36796bee200ff78751c"
+MOE_TEXT = "736d90cd019782bd217b9378e8daa7779467ab9b97f7c71cf6473f36a258eb08"
 
 
 def digests(cfg):
@@ -445,13 +472,15 @@ def digests(cfg):
             hashlib.sha256(text.encode()).hexdigest())
 
 
-@pytest.mark.parametrize("preset", ["token_test", "hybrid_test"])
+@pytest.mark.parametrize("preset", ["token_test", "hybrid_test",
+                                    "hybrid_moe_test"])
 def test_a_presets_tree_and_lowered_text_are_the_parents(preset):
     from diff3d_tpu.config import named_config
     from test_hybrid_denoiser import KEYE_TEXT, KEYE_TREE
 
     want = {"token_test": (KEYE_TREE, KEYE_TEXT),
-            "hybrid_test": (HYBRID_TREE, HYBRID_TEXT)}[preset]
+            "hybrid_test": (HYBRID_TREE, HYBRID_TEXT),
+            "hybrid_moe_test": (MOE_TREE, MOE_TEXT)}[preset]
     assert digests(named_config(preset)) == want
 
 

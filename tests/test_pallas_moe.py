@@ -1,9 +1,12 @@
-"""The routed experts' grouped Pallas kernel (ops/pallas_moe.py, op
-``'expert_ffn'``) against the XLA scan it stands for, in interpret mode on
-the CPU: through ``models/moe.expert_outputs`` (the layout, the kernel,
-the gated sum) on routings chosen by hand, under the sampler's ``vmap``,
-under ``grad``; what ``supports`` refuses and what the registry does with
-an explicit ``'pallas'`` there; what ``'auto'`` resolves to by backend.
+"""The routed experts' Pallas cores (ops/pallas_moe.py: op ``'expert_ffn'``,
+the grouped kernel; ops ``'expert_rows'`` and ``'expert_combine'``, how
+rows move into the padded layout and back out as the gated sum) against
+the XLA expressions they stand for, in interpret mode on the CPU: through
+``models/moe.expert_outputs`` (``impl='pallas'`` asks all three) and each
+alone, on routings chosen by hand, under the sampler's ``vmap``, under
+``grad``; what ``supports`` refuses and what the registry does with an
+explicit ``'pallas'`` there; what ``'auto'`` resolves to by backend and
+by the share of the experts a layer holds.
 """
 
 import jax
@@ -243,7 +246,7 @@ def test_an_explicit_pallas_raises_through_expert_outputs():
     x = jnp.zeros((32, 64))
     ids = jnp.zeros((32, K), jnp.int32)
     w = [jnp.zeros(s) for s in [(E, 64, 32), (E, 64, 32), (E, 32, 64)]]
-    with pytest.raises(ValueError, match="expert_ffn.*float32\\[12, 16, 64\\]"):
+    with pytest.raises(ValueError, match="expert_rows.*float32\\[32, 64\\]"):
         moe.expert_outputs(x, ids, jnp.ones((32, K)), *w, first=0, block=M,
                            impl="pallas")
     assert moe.expert_outputs(x, ids, jnp.ones((32, K)), *w, first=0,
@@ -278,3 +281,272 @@ def test_cpu_lowering_of_a_chunk_is_the_scans_and_holds_no_kernel():
     auto = text("auto")
     assert auto == text("xla")
     assert "while" in auto and "custom_call" not in auto
+
+
+# ------------------------------------------------- how rows move, alone
+
+# name -> (ids [T, k] or counts over all experts, held = (first, count),
+# experts routed over)
+MOVES = {
+    "a_share_with_an_expert_without_a_row":
+        (np.array([30, 0, 20, 14] + [16] * 12), (0, 4), 16),
+    "no_assignment_held_here": (np.array([64] * 4 + [0] * 4), (4, 4), 8),
+    # token 0 has both slots held, token 1 none, the rest one of two
+    "a_token_with_every_slot_held_and_one_with_none":
+        (np.array([[4, 5], [0, 1]] + [[2, 6], [7, 3]] * 63), (4, 4), 16),
+    # runs of 32, 16 and 48 rows: each ends on a block boundary
+    "runs_that_end_on_a_block_boundary":
+        (np.array([32, 16, 48, 0] + [40] * 4), (0, 4), 8),
+    "every_assignment_to_one_held_expert":
+        (np.array([0, 0, T * K, 0]), (1, 3), 4),
+    "all_experts_held": (np.full(E, T * K // E), (0, E), E),
+}
+
+
+def _moving(name, dtype, monkeypatch, seed=0):
+    """The operands ``expert_outputs`` hands the two ops on routing
+    ``name``: ``(x, token, ends)`` and ``(ys, at, gates, ends)``, read
+    off its own XLA cores."""
+    ids, (first, held), of = MOVES[name]
+    ids = _ids(ids, seed) if ids.ndim == 1 else jnp.asarray(ids, jnp.int32)
+    k = jax.random.split(jax.random.PRNGKey(seed), 5)
+    x = jax.random.normal(k[0], (ids.shape[0], D)).astype(dtype)
+    gates = jax.nn.softmax(jax.random.normal(k[1], ids.shape), axis=-1)
+    w = [(jax.random.normal(kk, s) / np.sqrt(s[1])).astype(dtype) for kk, s
+         in zip(k[2:], [(held, D, F), (held, D, F), (held, F, D)])]
+    seen = {}
+    for op in ("expert_rows", "expert_combine"):
+        impls = dispatch._REGISTRY[op]
+
+        def spy(*a, _op=op, _fn=impls["xla"].fn):
+            seen[_op] = a
+            return _fn(*a)
+        monkeypatch.setitem(impls, "xla", dispatch.KernelImpl(op, "xla", spy))
+    moe.expert_outputs(x, ids, gates, *w, first=first, block=M, of=of,
+                       impl="xla")
+    return seen["expert_rows"], seen["expert_combine"]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("routing", list(MOVES))
+def test_rows_in_are_the_gathers_on_every_block_in_use(routing, dtype,
+                                                       monkeypatch):
+    """Copies: equal to the last bit, the padding rows of a run's last
+    block zero; the blocks past the last run are the kernel's to leave."""
+    (x, token, ends, m), _ = _moving(routing, dtype, monkeypatch)
+    want = pallas_moe.expert_rows_reference(x, token, ends, m)
+    got = pallas_moe.expert_rows(x, token, ends, m)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    used = int(ends[-1]) // m
+    assert (used == 0) == (routing == "no_assignment_held_here")
+    np.testing.assert_array_equal(np.asarray(got[:used], np.float32),
+                                  np.asarray(want[:used], np.float32))
+    if used:
+        assert float(jnp.abs(want[:used].astype(jnp.float32)).mean()) > 0.1
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("routing", list(MOVES))
+def test_the_gated_combine_is_the_gather_and_sum(routing, dtype,
+                                                 monkeypatch):
+    """The same float32 products summed in slot order and cast once:
+    float32 within an ulp of the XLA expression (whose compiler may fuse
+    a product into the sum), bf16 no farther from the sum written out in
+    float64 than the XLA expression is.  The blocks ``expert_ffn`` leaves
+    unwritten (here NaN) are never read."""
+    _, (ys, at, gates, ends) = _moving(routing, dtype, monkeypatch)
+    used = int(ends[-1]) // M
+    want = pallas_moe.expert_combine_reference(ys, at, gates, ends)
+    got = pallas_moe.expert_combine(ys.at[used:].set(jnp.nan), at, gates,
+                                    ends)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    y0 = np.concatenate([np.asarray(ys, np.float64).reshape(-1, D),
+                         np.zeros((1, D))])
+    exact = (y0[np.asarray(at)].reshape(*gates.shape, D)
+             * np.asarray(gates, np.float64)[..., None]).sum(axis=1)
+
+    def gap(a):
+        return np.abs(np.asarray(a, np.float64) - exact).mean()
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(got, want, atol=2.4e-7, rtol=1.2e-7)
+    assert gap(got) <= gap(want) * 1.02 + 1e-9, (gap(got), gap(want))
+    held = (at < ys.shape[0] * M).reshape(gates.shape)
+    none = ~held.any(axis=1)
+    assert not bool(jnp.abs(got[none]).any())
+    if routing == "a_token_with_every_slot_held_and_one_with_none":
+        assert bool(held[0].all()) and bool(none[1])
+    if routing != "no_assignment_held_here":
+        assert float(jnp.abs(want.astype(jnp.float32)).mean()) > 0.01
+
+
+def test_ten_slots_sum_in_slot_order():
+    """The cell's top-10: every slot's product added in turn to a float32
+    sum that starts at the first."""
+    k = jax.random.split(jax.random.PRNGKey(3), 3)
+    Kk, rows = 10, 6 * M
+    ys = jax.random.normal(k[0], (6, M, D))
+    at = jax.random.randint(k[1], (T * Kk,), 0, 8 * M)   # a quarter absent
+    at = jnp.where(at < rows, at, rows)
+    gates = jax.nn.softmax(jax.random.normal(k[2], (T, Kk)), axis=-1)
+    ends = jnp.array([rows], jnp.int32)
+    got = pallas_moe.expert_combine(ys, at, gates, ends)
+    picked = jnp.concatenate([ys.reshape(rows, D), jnp.zeros((1, D))])[
+        at].reshape(T, Kk, D) * gates[..., None]
+    want = picked[:, 0]
+    for s in range(1, Kk):
+        want = want + picked[:, s]
+    np.testing.assert_allclose(got, want, atol=2.4e-7, rtol=1.2e-7)
+    np.testing.assert_allclose(
+        got, pallas_moe.expert_combine_reference(ys, at, gates, ends),
+        atol=1e-6, rtol=0)
+
+
+@pytest.mark.parametrize("op", ["expert_rows", "expert_combine"])
+def test_two_objects_are_one_call_with_an_object_axis(op, monkeypatch):
+    """Under the sampler's ``vmap`` each op is its kernel once more with
+    the objects as a grid axis: no loop over objects that slices every
+    operand out, as jax's own rule for prefetched scalars is."""
+    a = _moving("a_share_with_an_expert_without_a_row", jnp.float32,
+                monkeypatch, seed=1)
+    b = _moving("runs_that_end_on_a_block_boundary", jnp.float32,
+                monkeypatch, seed=2)
+    i = ["expert_rows", "expert_combine"].index(op)
+    static = a[i][3:] if op == "expert_rows" else ()
+    stacked = [jnp.stack([p, q]) for p, q in zip(a[i][:3 + i], b[i][:3 + i])]
+    fn = {"expert_rows": lambda *o: pallas_moe.expert_rows(*o, *static),
+          "expert_combine": pallas_moe.expert_combine}[op]
+    ref = {"expert_rows": lambda *o: pallas_moe.expert_rows_reference(
+        *o, *static), "expert_combine":
+        pallas_moe.expert_combine_reference}[op]
+    got, want = jax.vmap(fn)(*stacked), jax.vmap(ref)(*stacked)
+    for o, ends in enumerate(stacked[-1]):
+        used = int(ends[-1]) // M if op == "expert_rows" else None
+        np.testing.assert_allclose(got[o][:used], want[o][:used],
+                                   atol=2.4e-7, rtol=1.2e-7)
+    text = str(jax.make_jaxpr(jax.vmap(fn))(*stacked))
+    assert "pallas_call" in text and "grid=(2, " in text
+    assert "grid=(1, " not in text and "dynamic_update_slice" not in text
+    assert float(jnp.abs(got[0][:1] - got[1][:1]).mean()) > 0.01
+
+
+def test_gradient_through_each_custom_vjp_is_the_xla_expressions(
+        monkeypatch):
+    (x, token, ends, m), (ys, at, gates, _) = _moving(
+        "a_share_with_an_expert_without_a_row", jnp.float32, monkeypatch,
+        seed=4)
+    used = int(ends[-1]) // m
+    w = jax.random.normal(jax.random.PRNGKey(5), (used, m, D))
+
+    def rows(fn):
+        return jax.grad(lambda x: jnp.sum(
+            fn(x, token, ends, m)[:used] ** 2 * w))(x)
+    got, want = rows(pallas_moe.expert_rows), rows(
+        pallas_moe.expert_rows_reference)
+    assert float(jnp.abs(want).mean()) > 1e-3
+    np.testing.assert_allclose(got, want, atol=1e-6, rtol=1e-6)
+
+    def combine(fn):
+        return jax.grad(lambda y, g: jnp.sum(fn(y, at, g, ends) ** 2),
+                        argnums=(0, 1))(ys, gates)
+    for g, r in zip(combine(pallas_moe.expert_combine),
+                    combine(pallas_moe.expert_combine_reference)):
+        assert float(jnp.abs(r).mean()) > 1e-4
+        np.testing.assert_allclose(g, r, atol=1e-5, rtol=1e-5)
+
+
+MOVE_SUPPORTS = {"expert_rows": pallas_moe.expert_rows_supports,
+                 "expert_combine": pallas_moe.expert_combine_supports}
+
+
+def _move_shapes(op, d=D, m=M, dtype=jnp.float32, t=T, k=K, n=24,
+                 gdtype=jnp.float32):
+    sds = jax.ShapeDtypeStruct
+    ends = sds((E,), jnp.int32)
+    if op == "expert_rows":
+        return (sds((t, d), dtype), sds((n * m,), jnp.int32), ends, m)
+    return (sds((n, m, d), dtype), sds((t * k,), jnp.int32),
+            sds((t, k), gdtype), ends)
+
+
+def test_the_moves_support_both_cells_shapes():
+    for op, supports in MOVE_SUPPORTS.items():
+        assert supports(*_move_shapes(op))
+        # granite4_h_small_tok128: 169 blocks a chunk of 4096 at top-10
+        assert supports(*_move_shapes(op, d=4096, m=256, t=4096, k=10,
+                                      n=169, dtype=jnp.bfloat16))
+        # keye_vl2_tok128: 384 blocks a chunk of 8192 at top-8
+        assert supports(*_move_shapes(op, d=2048, m=256, t=8192, k=8,
+                                      n=384, dtype=jnp.bfloat16))
+
+
+MOVES_REFUSED = {
+    "token_test_widths": dict(d=64),
+    "hidden_not_whole_lane_tiles": dict(d=192),
+    "block_not_a_whole_sublane_tile": dict(m=12),
+    "bf16_block_of_8_rows": dict(m=8, dtype=jnp.bfloat16),
+    "float16": dict(dtype=jnp.float16),
+}
+
+
+@pytest.mark.parametrize("op", ["expert_rows", "expert_combine"])
+@pytest.mark.parametrize("why", list(MOVES_REFUSED))
+def test_the_moves_refuse_and_an_explicit_pallas_raises(why, op,
+                                                        monkeypatch):
+    shapes = _move_shapes(op, **MOVES_REFUSED[why])
+    assert not MOVE_SUPPORTS[op](*shapes)
+    with pytest.raises(ValueError, match=f"{op}.*requested explicitly"):
+        dispatch.resolve(op, "pallas", *shapes, held=9, of=72)
+    monkeypatch.setattr(dispatch, "default_backend", lambda: "tpu")
+    assert dispatch.resolve(op, "auto", *shapes, held=9,
+                            of=72).name == "xla"
+
+
+def test_the_combine_refuses_gates_that_are_not_float32():
+    shapes = _move_shapes("expert_combine", gdtype=jnp.bfloat16)
+    assert not pallas_moe.expert_combine_supports(*shapes)
+
+
+@pytest.mark.parametrize("backend,held,of,core", [
+    ("tpu", 9, 72, "pallas"), ("tpu", 64, 128, "pallas"),
+    ("tpu", 65, 128, "xla"), ("tpu", 128, 128, "xla"),
+    ("tpu", 9, None, "xla"), ("cpu", 9, 72, "xla"), ("cpu", 8, 8, "xla")])
+def test_auto_moves_rows_by_kernel_on_a_tpu_process_at_a_share_alone(
+        monkeypatch, backend, held, of, core):
+    """The rule reads the static held share: at most half of the experts
+    routed over.  ``expert_ffn`` does not ask (it wins wherever it runs)."""
+    monkeypatch.setattr(dispatch, "default_backend", lambda: backend)
+    monkeypatch.setattr(dispatch, "interpret_default", lambda: True)
+    for op in ("expert_rows", "expert_combine"):
+        assert dispatch.resolve(op, "auto", *_move_shapes(op), held=held,
+                                of=of).name == core
+        assert dispatch.resolve(op, "xla", *_move_shapes(op), held=held,
+                                of=of).name == "xla"
+    # and the counters say which core each traced site took
+    x = jax.ShapeDtypeStruct((T, D), jnp.float32)
+    ids = jax.ShapeDtypeStruct((T, K), jnp.int32)
+    w = [jax.ShapeDtypeStruct(s, jnp.float32) for s in
+         [(held, D, F), (held, D, F), (held, F, D)]]
+    before = RECORDER.counters()
+    out = jax.eval_shape(lambda *a: moe.expert_outputs(
+        *a, first=0, block=M, of=of), x, ids, x.update(shape=(T, K)), *w)
+    assert out.shape == (T, D)
+    after = RECORDER.counters()
+    d = {k: after[k] - before.get(k, 0) for k in after
+         if k.startswith("experts.") and after[k] != before.get(k, 0)}
+    ffn = "pallas" if backend == "tpu" else "xla"
+    assert d == {f"experts.rows.{core}": 1, f"experts.combine.{core}": 1,
+                 f"experts.{ffn}": 1}
+
+
+def test_cpu_lowering_of_a_share_holds_no_kernel_and_is_all_helds_text():
+    """On a CPU process ``of`` decides nothing: the text of a chunk at a
+    share is the text with ``of`` left out, the parent's expression."""
+    (x, ids, gates, *w), first = _operands("held_elsewhere")
+
+    def text(**kw):
+        return jax.jit(lambda *a: moe.expert_outputs(
+            *a, first=first, block=M, **kw)).lower(
+                x, ids, gates, *w).as_text()
+    auto = text(of=16)
+    assert auto == text() == text(of=16, impl="xla")
+    assert "custom_call" not in auto

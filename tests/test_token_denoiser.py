@@ -112,10 +112,12 @@ def test_g_rows_equal_repeated_rows_and_must_divide(tiny):
 @pytest.fixture
 def kernel_forced(monkeypatch):
     """The registry's policy patched to what it resolves on a TPU process:
-    ``sdpa(keep=)`` and the experts' blocks at ``impl='auto'`` take their
-    Pallas cores (in interpret mode, this being a CPU process).  No
-    option of the program does this."""
-    for op in ("sdpa_selected", "expert_ffn"):
+    ``sdpa(keep=)``, the experts' blocks and (as where a layer holds a
+    share) the rows' way in and back at ``impl='auto'`` take their Pallas
+    cores (in interpret mode, this being a CPU process).  No option of
+    the program does this."""
+    for op in ("sdpa_selected", "expert_rows", "expert_ffn",
+               "expert_combine"):
         impls = dispatch._REGISTRY[op]
         monkeypatch.setitem(impls, "xla", impls["pallas"])
 
@@ -163,8 +165,9 @@ def test_counters_of_a_traced_program(tiny, core, request):
     assert not d.get(f"sdpa.selected.{other}")
     # and one per traced expert_outputs site, the chunk map's body: a
     # layer
-    assert d[f"experts.{core}"] == 2
-    assert not d.get(f"experts.{other}")
+    for site in ("experts", "experts.rows", "experts.combine"):
+        assert d[f"{site}.{core}"] == 2
+        assert not d.get(f"{site}.{other}")
     # the X-UNet's two, the selection's two, the experts' two, no other
     assert not [k for k, v in d.items() if v and not k.startswith(
         ("conditioning.", "compile.", "sdpa.selected.", "experts."))], d
@@ -174,8 +177,9 @@ def test_token_test_model_lowers_as_the_parent_did_on_a_cpu_process(
         monkeypatch):
     """``auto`` resolves to the XLA cores here: the program of the
     ``token_test`` preset is, to the letter, the program with the parent's
-    ``sdpa(keep=)`` body and the parent's block scan in the layer, and
-    holds no Pallas call."""
+    ``sdpa(keep=)`` body and, in the layer, the parent's row gather,
+    block scan and gather-and-sum written out, and holds no Pallas
+    call."""
     cfg = token_test_config()
     model = build_model(cfg)
     batch = make_batch(jax.random.PRNGKey(5), 2, 2)
@@ -203,12 +207,26 @@ def test_token_test_model_lowers_as_the_parent_did_on_a_cpu_process(
                  * jnp.dot(xb, w_up[e], preferred_element_type=f32))
             return None, jnp.dot(h.astype(xb.dtype), w_down[e])
         return jax.lax.scan(one_block, None, (rows, e_blk))[1]
+
+    def parents_rows(x, token, ends, m):
+        x0 = jnp.concatenate([x, jnp.zeros((1, x.shape[1]), x.dtype)])
+        return x0[token].reshape(token.shape[0] // m, m, x.shape[1])
+
+    def parents_sum(ys, at, gates, ends):
+        n_blocks, m, D = ys.shape
+        T, K = gates.shape
+        y0 = jnp.concatenate([ys.reshape(n_blocks * m, D),
+                              jnp.zeros((1, D), ys.dtype)])
+        picked = y0[at].reshape(T, K, D).astype(jnp.float32)
+        return (picked * gates[..., None]).sum(axis=1).astype(ys.dtype)
+    parents = {"expert_rows": parents_rows, "expert_ffn": parents_scan,
+               "expert_combine": parents_sum}
     took = []
     monkeypatch.setattr(
-        moe.dispatch, "resolve", lambda op, impl, *a: took.append(
-            (op, impl)) or dispatch.KernelImpl(op, "xla", parents_scan))
+        moe.dispatch, "resolve", lambda op, impl, *a, **kw: took.append(
+            (op, impl)) or dispatch.KernelImpl(op, "xla", parents[op]))
     assert mine == text()
-    assert took == [("expert_ffn", "auto")] * 2
+    assert took == [(op, "auto") for op in parents] * 2
 
 
 # ------------------------------------------------- the layers by themselves
@@ -301,7 +319,8 @@ def test_forward_with_the_kernel_forced_is_the_reference(wide, kernel_forced):
     mask = jnp.array([True, False])
     before = RECORDER.counters()
     got = run_program(wide["model"], wide["flat"], batch, mask)
-    for name in ("sdpa.selected.pallas", "experts.pallas"):
+    for name in ("sdpa.selected.pallas", "experts.pallas",
+                 "experts.rows.pallas", "experts.combine.pallas"):
         assert RECORDER.counters()[name] > before.get(name, 0)
     ref, _ = jax.jit(lambda p: rt.forward(p, batch, mask, wide["mcfg"]))(
         wide["flat"])
@@ -421,10 +440,12 @@ def test_expert_layer_with_the_kernel_forced_is_the_layer(held, request):
         run = lambda p, h: layer.apply({"params": p}, h, scale) - h  # noqa
         return run(p, h), jax.vmap(run, in_axes=(None, 0))(p, two)
     want, want_two = both()
-    before = RECORDER.counters().get("experts.pallas", 0)
+    names = ("experts.pallas", "experts.rows.pallas",
+             "experts.combine.pallas")
+    before = [RECORDER.counters().get(n, 0) for n in names]
     request.getfixturevalue("kernel_forced")
     got, got_two = both()
-    assert RECORDER.counters()["experts.pallas"] == before + 2
+    assert [RECORDER.counters()[n] for n in names] == [b + 2 for b in before]
     assert float(jnp.abs(want).mean()) > 0.003
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
     np.testing.assert_allclose(got_two, want_two, atol=2e-5, rtol=0)
