@@ -55,6 +55,14 @@ expressions' VJPs behind them; the view program holds 41 custom calls and
 neither the gather over all ``T k`` assignments nor the float32 tile of
 picked rows.
 
+Both hybrid cells run their state-space scan through one kernel an
+example (``ops/ssd.ssd_pallas``): compiled at h-small's shapes
+(8192 tokens, 128 heads of 64, state 128, chunk 256) alone, under the
+sampler's ``vmap`` over two objects and with the XLA core's VJP behind
+it; neither cell's view program holds a chunk-batched decay tile or
+``M`` (``[32, H, 256, 256]`` in either float dtype), and they hold 10 and
+50 custom calls.
+
 Fixture rules (on-chip-measurement guide, section 2): the topology is
 described inside a module-scoped, non-autouse fixture of THIS file, which
 skips where it cannot be described — never at import, in ``skipif``, in
@@ -77,6 +85,7 @@ from diff3d_tpu.ops.pallas_attention import (flash_attention,
 from diff3d_tpu.ops.pallas_film import fused_groupnorm
 from diff3d_tpu.ops.pallas_moe import (expert_combine, expert_ffn,
                                        expert_rows)
+from diff3d_tpu.ops.ssd import ssd_pallas
 
 F32, BF16 = "float32", "bfloat16"
 
@@ -373,6 +382,55 @@ def test_expert_ffn_gradient_compiles_for_v5e(one_chip, no_persistent_cache):
     _compile_for_chip(grads, *_expert_operands(one_chip, BF16))
 
 
+# granite4_h_small_tok128's state-space scan site: (L, H, P, N, chunk);
+# h-micro's is the same at 64 heads
+SCAN_SITE = (8192, 128, 64, 128, 256)
+# a chunk-batched decay tile or M, [n, H, Q, Q] in either float dtype
+DECAYS = r"(f32|bf16)\[32,(64|128),256,256\]"
+
+
+def _scan_operands(one_chip, lead=()):
+    L, H, P, N, _ = SCAN_SITE
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(lead + shape, jnp.dtype(dt),
+                                    sharding=one_chip)
+    return (sds((L, H, P), BF16), sds((L, H), F32), sds((H,), F32),
+            sds((L, N), BF16), sds((L, N), BF16), sds((H,), F32))
+
+
+def _scan(x, dt, A, B, C, D):
+    return ssd_pallas(x, dt, A, B, C, D, SCAN_SITE[-1], interpret=False)
+
+
+def test_the_scan_kernel_compiles_for_v5e(one_chip, no_persistent_cache):
+    compiled = _compile_for_chip(_scan, *_scan_operands(one_chip))
+    # the decay tiles and M stay on chip
+    assert not re.search(DECAYS, compiled.as_text())
+
+
+def test_the_scan_kernel_under_the_samplers_vmap_compiles_for_v5e(
+        one_chip, no_persistent_cache):
+    """Two objects: Pallas adds the object axis in front of the kernel's
+    grid; no loop over objects."""
+    text = _compile_for_chip(jax.vmap(_scan), *_scan_operands(
+        one_chip, lead=(2,))).as_text()
+    assert "while" not in text
+
+
+def test_the_scan_kernel_gradient_compiles_for_v5e(one_chip,
+                                                   no_persistent_cache):
+    """The hybrid train step's pair: the kernel forward, the XLA core's
+    VJP backward (which does write the decay tiles)."""
+    def loss(*a):
+        # squared, so that the backward needs the kernel's output
+        return jnp.sum(_scan(*a).astype(jnp.float32) ** 2)
+
+    compiled = _compile_for_chip(jax.grad(loss, argnums=tuple(range(6))),
+                                 *_scan_operands(one_chip))
+    assert re.search(DECAYS, compiled.as_text())
+
+
 def test_the_hybrid_cells_view_program_compiles_and_fits_a_v5e(
         one_chip, no_persistent_cache, monkeypatch):
     """``Sampler._run_view_many`` of ``benchmark/configs/
@@ -410,17 +468,19 @@ def test_the_hybrid_cells_view_program_compiles_and_fits_a_v5e(
     assert 4 * 752_425_932 < mem.argument_size_in_bytes < 3.02e9
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
              + mem.generated_code_size_in_bytes)
-    # 6.80 GB since the kernel (temporaries 3.70; 6.95 and 3.86 with
-    # XLA's score tile, held to 8.5): under half the chip's 16.9
-    assert total < 8.35e9, mem
-    # the attention layer's tile is the kernel's: one custom call, and no
-    # score array of a tile, let alone [heads, L, L]; no decay tile for
-    # all 16 examples
+    # 6.24 GB since the scan's kernel (temporaries 3.17; 6.80 and 3.70
+    # with XLA's chunk-batched scan, 6.95 and 3.86 with XLA's score tile
+    # too): under half the chip's 16.9
+    assert total < 6.6e9, mem
+    # the attention layer's tile and the nine scans are the kernels': ten
+    # custom calls, no score array of a tile, let alone [heads, L, L]; no
+    # decay tile, for one example or for all 16
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == 1
+    assert text.count("tpu_custom_call") == 10
     assert not re.search(r"f32\[[\d,]*512,8192\]", text)
     assert not re.search(r"f32\[[\d,]*32,8192,8192\]", text)
     assert not re.search(r"f32\[16,[\d,]*256,256\]", text)
+    assert not re.search(DECAYS, text)
 
 
 # granite4_h_small_tok128's expert_outputs site at a chunk of 4096 tokens
@@ -550,19 +610,21 @@ def test_the_hybrid_moe_cells_view_program_compiles_and_fits_a_v5e(
     assert 4 * 2_023_950_988 < mem.argument_size_in_bytes < 8.11e9
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
              + mem.generated_code_size_in_bytes)
-    # 15.39 GB (temporaries 7.26: the state-space layers' peak; 9.67 and
-    # over the chip at an expert chunk of 8192 tokens) of the chip's 16.91:
-    # the compiler does not always refuse a program that is over
-    assert total < 15.6e9, mem
-    # ten expert sites (rows in, blocks, re-tiling, gated sum back) and
-    # the attention site on their kernels; no gather over all ``T k``
-    # assignments or over the static bound of rows, no float32 tile of
-    # picked rows; no score array of a tile, no decay tile for all 16
-    # examples
+    # 14.40 GB since the scan's kernel (temporaries 6.28; 15.39 and 7.26
+    # with XLA's chunk-batched scan, the state-space layers' peak; 9.67
+    # and over the chip at an expert chunk of 8192 tokens) of the chip's
+    # 16.91: the compiler does not always refuse a program that is over
+    assert total < 14.8e9, mem
+    # ten expert sites (rows in, blocks, re-tiling, gated sum back), the
+    # attention site and the nine scans on their kernels; no gather over
+    # all ``T k`` assignments or over the static bound of rows, no float32
+    # tile of picked rows; no score array of a tile, no decay tile for one
+    # example or for all 16
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == 41
+    assert text.count("tpu_custom_call") == 50
     assert not re.search(r"bf16\[(40960|43264|43265),4096\]", text)
     assert not re.search(r"f32\[4096,10,4096\]", text)
     assert not re.search(r"f32\[[\d,]*512,8192\]", text)
     assert not re.search(r"f32\[[\d,]*32,8192,8192\]", text)
     assert not re.search(r"f32\[16,[\d,]*256,256\]", text)
+    assert not re.search(DECAYS, text)

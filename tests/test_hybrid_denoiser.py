@@ -33,6 +33,7 @@ from diff3d_tpu.ops import dispatch  # noqa: E402
 from diff3d_tpu.ops.ssd import ssd  # noqa: E402
 from diff3d_tpu.utils.profiling import RECORDER  # noqa: E402
 
+from _scan_helpers import scan_inputs, sequential  # noqa: E402
 from _token_helpers import make_batch, reference_loss  # noqa: E402
 
 with open(os.path.join(ROOT, "benchmark", "configs",
@@ -149,33 +150,6 @@ def test_g_rows_equal_repeated_rows(tiny):
 
 
 # ------------------------------------------------------ the scan by itself
-
-def sequential(x, dt, A, B, C, D, reset=0):
-    """The recurrence, token by token (float32)."""
-    def step(S, inp):
-        t, xt, dtt, Bt, Ct = inp
-        if reset:
-            S = jnp.where(t % reset == 0, 0.0, S)
-        S = (jnp.exp(dtt * A)[:, None, None] * S
-             + (dtt[:, None] * xt)[:, :, None] * Bt[None, None, :])
-        return S, (S * Ct).sum(-1) + D[:, None] * xt
-    H, P = x.shape[1:]
-    return jax.lax.scan(step, jnp.zeros((H, P, B.shape[-1])),
-                        (jnp.arange(x.shape[0]), x, dt, B, C))[1]
-
-
-def scan_inputs(L=128, H=4, P=8, N=16, decay="mixed", seed=0):
-    k = jax.random.split(jax.random.PRNGKey(seed), 4)
-    x = jax.random.normal(k[0], (L, H, P))
-    B = jax.random.normal(k[1], (L, N))
-    C = jax.random.normal(k[2], (L, N))
-    # per-token decay exp(dt A): near 1 (0.9999), near 0 (e^-8), a memory
-    # of some fifty tokens (0.98), or each head its own between the ends
-    dt = {"near_one": 1e-4, "near_zero": 8.0, "slow": 0.02,
-          "mixed": jnp.logspace(-4, 0.9, H)}[decay] * jnp.exp(
-              0.3 * jax.random.normal(k[3], (L, H)))
-    return x, dt, -jnp.ones((H,)), B, C, jnp.linspace(0.5, 1.5, H)
-
 
 @pytest.mark.parametrize("decay", ["near_one", "near_zero", "mixed"])
 @pytest.mark.parametrize("chunk", [32, 48, 128, 1000, 1])
@@ -342,11 +316,12 @@ def test_the_residual_multiplier_reaches_the_keye_layers_too():
 
 
 def test_counters_one_per_traced_scan_site(tiny, monkeypatch):
-    """``ssm_scan.xla``: 4 in the tiny model's program, 9 in the
+    """``ssm_scan.<core>``: 4 in the tiny model's program, 9 in either
     full-width cell's (its nine Mamba-2 layers, unrolled);
-    ``sdpa.plain.<core>``: one, the attention layer's tile (``xla`` on
-    this CPU process, ``pallas`` as a TPU process resolves the cell's);
-    no counter of the Keye layers moves."""
+    ``sdpa.plain.<core>``: one, the attention layer's tile; ``xla`` alone
+    on this CPU process, ``pallas`` alone as a TPU process resolves the
+    cells' (the tiny model's scan, chunk 24 at head dim 16, stays on
+    ``xla`` there); no counter of the Keye layers moves."""
     def traced(cfg, model, params, B):
         before = RECORDER.counters()
         batch = jax.eval_shape(lambda: make_batch(
@@ -368,9 +343,21 @@ def test_counters_one_per_traced_scan_site(tiny, monkeypatch):
         lambda: init_params(model, cfg, jax.random.PRNGKey(0)))
     d = traced(cfg, model, params, 16)
     assert d["ssm_scan.xla"] == 9 and d["sdpa.plain.xla"] == 1
+    assert "ssm_scan.pallas" not in d
     monkeypatch.setattr(dispatch, "default_backend", lambda: "tpu")
     d = traced(cfg, model, params, 16)
     assert d["sdpa.plain.pallas"] == 1 and "sdpa.plain.xla" not in d
+    assert d["ssm_scan.pallas"] == 9 and "ssm_scan.xla" not in d
+    d = traced(tiny["cfg"], tiny["model"], nest(tiny["flat"]), 4)
+    assert d["ssm_scan.xla"] == 4 and "ssm_scan.pallas" not in d
+    from benchmark import adapters_hybrid_moe
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "granite4_h_small_tok128.json")) as f:
+        cfg = adapters_hybrid_moe.build_config(json.load(f))
+    model = build_model(cfg)
+    d = traced(cfg, model, jax.eval_shape(
+        lambda: init_params(model, cfg, jax.random.PRNGKey(0))), 16)
+    assert d["ssm_scan.pallas"] == 9 and "ssm_scan.xla" not in d
 
 
 # ------------------------------------------------------ sampler and trainer

@@ -305,7 +305,7 @@ def test_counters_once_per_traced_site(tiny, monkeypatch):
     assert d == {"conditioning.groups": 2, "conditioning.examples": 16,
                  "experts.held": 9, "experts.of": 72, "experts.shared": 10,
                  "experts.pallas": 10, "experts.rows.pallas": 10,
-                 "experts.combine.pallas": 10, "ssm_scan.xla": 9,
+                 "experts.combine.pallas": 10, "ssm_scan.pallas": 9,
                  "sdpa.plain.pallas": 1}
     # Keye's layers hold every expert they route over: the rows move by
     # XLA's gathers there, the blocks through the kernel as before
