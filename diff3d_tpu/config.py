@@ -110,6 +110,18 @@ class TokenModelConfig:
     shared expert beside the routed ones, ``h += r (routed(u) +
     mlp(u))`` on one normed ``u``.
 
+    Multi-head latent attention (``"latent_attention"``, every layer
+    when the pattern is empty and ``kv_lora_rank > 0``): queries from a
+    ``q_lora_rank`` latent and keys and values from a ``kv_lora_rank``
+    latent, each with its own RMSNorm, a ``qk_rope_head_dim`` rotary key
+    shared by all heads, values ``v_head_dim`` wide.  The first
+    ``first_k_dense_replace`` layers have a dense gated MLP of
+    ``dense_intermediate_size`` in place of the experts; ``scoring_func``
+    ``"sigmoid"`` gates the top-k by sigmoid scores normalised over the
+    k and times ``routed_scaling_factor``; ``sandwich_norm`` adds a norm
+    on each half's output before its residual add.  ``param_dtype`` is
+    the dtype the parameters are made and held in.
+
     ``experts_held`` is ``(first, count)``: the router scores all
     ``num_experts``, the layer holds the weights of experts ``first ..
     first + count - 1`` and computes their part of the result (all of
@@ -153,6 +165,18 @@ class TokenModelConfig:
     residual_multiplier: float = 1.0
     attention_multiplier: Optional[float] = None
     logits_scaling: float = 1.0
+    # Multi-head latent attention (0: none).
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # Leading layers whose feed-forward is a dense MLP of this width.
+    first_k_dense_replace: int = 0
+    dense_intermediate_size: int = 0
+    scoring_func: str = "softmax"  # the router's: 'softmax' | 'sigmoid'
+    routed_scaling_factor: float = 1.0
+    sandwich_norm: bool = False
     # Tile sizes, not semantics: queries of one example attended at a
     # time; tokens routed at a time; rows of one expert's matmul.
     q_chunk: int = 512
@@ -160,7 +184,8 @@ class TokenModelConfig:
     expert_block: int = 256
     emb_ch: int = 256              # width of the logSNR sinusoid
     logsnr_clip: float = 20.0      # as ModelConfig.logsnr_clip
-    dtype: str = "bfloat16"        # compute dtype; params stay float32
+    dtype: str = "bfloat16"        # compute dtype
+    param_dtype: str = "float32"   # float32, or the compute dtype
 
     @property
     def tokens(self) -> int:
@@ -169,8 +194,13 @@ class TokenModelConfig:
     @property
     def mixers(self) -> Tuple[str, ...]:
         """The mixer kind of every layer, the empty pattern filled in."""
-        return (tuple(self.layer_types)
-                or ("sparse_attention",) * self.num_hidden_layers)
+        default = ("latent_attention" if self.kv_lora_rank
+                   else "sparse_attention")
+        return tuple(self.layer_types) or (default,) * self.num_hidden_layers
+
+    def dense_layer(self, i: int) -> bool:
+        """Whether layer ``i`` is one of the leading dense layers."""
+        return bool(self.num_experts) and i < self.first_k_dense_replace
 
     def validate(self) -> None:
         if self.H % self.patch or self.W % self.patch:
@@ -182,12 +212,33 @@ class TokenModelConfig:
                 f"num_key_value_heads={self.num_key_value_heads} must "
                 f"divide num_attention_heads={self.num_attention_heads}")
         kinds = self.mixers
-        unknown = set(kinds) - {"sparse_attention", "attention", "mamba"}
+        unknown = set(kinds) - {"sparse_attention", "attention", "mamba",
+                                "latent_attention"}
         if unknown or len(kinds) != self.num_hidden_layers:
             raise ValueError(
                 f"layer_types={kinds} must name 'sparse_attention', "
-                f"'attention' or 'mamba' for each of the "
-                f"{self.num_hidden_layers} layers")
+                f"'attention', 'mamba' or 'latent_attention' for each of "
+                f"the {self.num_hidden_layers} layers")
+        if "latent_attention" in kinds:
+            self._validate_latent_attention()
+        if self.param_dtype not in ("float32", self.dtype):
+            raise ValueError(
+                f"param_dtype={self.param_dtype!r} must be 'float32' or "
+                f"the compute dtype {self.dtype!r}")
+        if self.param_dtype != "float32" and set(kinds) != {
+                "latent_attention"}:
+            raise ValueError(
+                f"param_dtype={self.param_dtype!r}: only a stack of "
+                "'latent_attention' layers makes its parameters in it (the "
+                "other mixers make theirs in float32)")
+        if self.first_k_dense_replace:
+            if not (self.num_experts and self.dense_intermediate_size > 0
+                    and self.first_k_dense_replace
+                    < self.num_hidden_layers):
+                raise ValueError(
+                    f"first_k_dense_replace={self.first_k_dense_replace} "
+                    "needs routed experts, dense_intermediate_size > 0 "
+                    "and expert layers after the dense ones")
         if "mamba" in kinds:
             if (self.mamba_n_heads * self.mamba_d_head
                     != self.mamba_expand * self.hidden_size):
@@ -242,6 +293,21 @@ class TokenModelConfig:
             raise ValueError(
                 f"num_experts_per_tok={self.num_experts_per_tok} not in "
                 f"1..{self.num_experts}")
+        if self.scoring_func not in ("softmax", "sigmoid"):
+            raise ValueError(f"scoring_func={self.scoring_func!r} not in "
+                             "('softmax', 'sigmoid')")
+        if self.scoring_func == "softmax" and self.routed_scaling_factor != 1:
+            raise ValueError("routed_scaling_factor scales sigmoid gates "
+                             "only: the softmax's top-k sum to one")
+
+    def _validate_latent_attention(self) -> None:
+        ranks = (self.q_lora_rank, self.kv_lora_rank, self.qk_nope_head_dim,
+                 self.qk_rope_head_dim, self.v_head_dim)
+        if min(ranks) < 1 or self.qk_rope_head_dim % 2:
+            raise ValueError(
+                "latent attention needs q_lora_rank, kv_lora_rank, "
+                "qk_nope_head_dim, v_head_dim >= 1 and an even "
+                f"qk_rope_head_dim (have {ranks})")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -569,6 +635,32 @@ def hybrid_moe_test_config(imgsize: int = 16) -> Config:
         expert_block=16))
 
 
+def mla_moe_test_config(imgsize: int = 16) -> Config:
+    """Tiny token-denoiser config with latent attention under sandwich
+    norms: one dense layer, then two layers of sigmoid-routed experts (8
+    of 16 held, top-4, times 2.5) beside a shared expert, hidden 256,
+    value heads narrower than query / key heads (32 against 48)."""
+    tokens = 2 * (imgsize // 2) ** 2
+    return Config(
+        model=TokenModelConfig(
+            H=imgsize, W=imgsize, hidden_size=256, num_hidden_layers=3,
+            num_attention_heads=4, num_key_value_heads=4, head_dim=48,
+            rms_norm_eps=1e-5, rope_theta=25_600_000.0, q_lora_rank=64,
+            kv_lora_rank=32, qk_nope_head_dim=32, qk_rope_head_dim=16,
+            v_head_dim=32, num_experts=16, num_experts_per_tok=4,
+            moe_intermediate_size=64, experts_held=(0, 8),
+            shared_intermediate_size=64, first_k_dense_replace=1,
+            dense_intermediate_size=384, scoring_func="sigmoid",
+            routed_scaling_factor=2.5, sandwich_norm=True,
+            q_chunk=tokens // 2, expert_token_chunk=2 * tokens,
+            expert_block=16, emb_ch=32, dtype="float32"),
+        train=TrainConfig(global_batch=8, warmup_examples=1024,
+                          max_steps=4, ckpt_every=2, log_every=1),
+        data=DataConfig(imgsize=imgsize),
+        diffusion=DiffusionConfig(timesteps=4),
+    )
+
+
 def test_config(imgsize: int = 16, ch: int = 8,
                 shallow: bool = False) -> Config:
     """Tiny config for unit tests / CPU-mesh dry runs.
@@ -597,7 +689,8 @@ def test_config(imgsize: int = 16, ch: int = 8,
 NAMED_CONFIGS = {"srn64": "srn64_config", "srn128": "srn128_config",
                  "test": "test_config", "token_test": "token_test_config",
                  "hybrid_test": "hybrid_test_config",
-                 "hybrid_moe_test": "hybrid_moe_test_config"}
+                 "hybrid_moe_test": "hybrid_moe_test_config",
+                 "mla_moe_test": "mla_moe_test_config"}
 
 
 def named_config(name: str) -> Config:

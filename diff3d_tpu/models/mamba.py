@@ -26,6 +26,8 @@ example:
 
 from __future__ import annotations
 
+from typing import Optional
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
@@ -87,8 +89,8 @@ class Mamba2Mixer(nn.Module):
         self.norm = Scale()
         self.out_proj = Kernel(self.hidden)
 
-    def __call__(self, h: jnp.ndarray, norm_scale: jnp.ndarray
-                 ) -> jnp.ndarray:
+    def __call__(self, h: jnp.ndarray, norm_scale: jnp.ndarray,
+                 post_scale: Optional[jnp.ndarray] = None) -> jnp.ndarray:
         L = h.shape[1]
         H, P, N = self.n_heads, self.d_head, self.d_state
         d_inner = H * P
@@ -119,4 +121,4 @@ class Mamba2Mixer(nn.Module):
 
         with scope("ssm_scan"):
             return residual_half(h, norm_scale, self.eps, self.residual,
-                                 mix)
+                                 mix, post_scale)

@@ -1,6 +1,12 @@
 """Routed experts: a router over all experts, top-k renormalised gates, and
 the part of the result that the experts held here give.
 
+The gates are the softmax's top-k renormalised, or (``scoring="sigmoid"``)
+the top-k sigmoid scores normalised over the k and times a scale; the
+same experts either way (:func:`route`).  A block with a sandwich norm
+norms the half's output again before its residual add (:func:`post_norm`,
+``residual_half(post_scale=)``).
+
 The layer is told which experts it holds (``held = (first, count)``).  It
 routes every token over all ``num_experts``, and computes, for the tokens
 routed to an expert it holds, that expert's gated output; what the absent
@@ -89,26 +95,48 @@ def add_residual(h: jnp.ndarray, y: jnp.ndarray, r: float = 1.0
     return h + y.astype(h.dtype)
 
 
+def post_norm(y: jnp.ndarray, scale: Optional[jnp.ndarray], eps: float
+              ) -> jnp.ndarray:
+    """A half's output before its residual add: as it is, or (a sandwich
+    norm) RMS-normed in float32 times ``scale``."""
+    if scale is None:
+        return y
+    with scope("residual"):
+        return rms_norm(y.astype(jnp.float32), scale, eps)
+
+
 def residual_half(h: jnp.ndarray, norm_scale: jnp.ndarray, eps: float,
-                  r: float, fn: Callable[[jnp.ndarray], jnp.ndarray]
-                  ) -> jnp.ndarray:
-    """``h [B, L, D] -> h + r fn(norm(h))``, ``fn`` on one example's
-    normed tokens ``[L, D]`` at a time."""
+                  r: float, fn: Callable[[jnp.ndarray], jnp.ndarray],
+                  post_scale: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """``h [B, L, D] -> h + r post(fn(norm(h)))``, ``fn`` on one
+    example's normed tokens ``[L, D]`` at a time; ``post`` is
+    :func:`post_norm` by ``post_scale``."""
+    if post_scale is not None:
+        count("residual.post_norm")
+
     def one_example(hb):
         with scope("residual"):
             u = rms_norm(hb, norm_scale, eps)
-        y = fn(u)
+        y = post_norm(fn(u), post_scale, eps)
         with scope("residual"):
             return add_residual(hb, y, r)
 
     return jax.lax.map(one_example, h)
 
 
-def route(logits: jnp.ndarray, k: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+def route(logits: jnp.ndarray, k: int, scoring: str = "softmax",
+          scale: float = 1.0) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """``[T, E]`` float32 router logits -> (``[T, k]`` expert ids,
-    ``[T, k]`` float32 gates): softmax over all experts, the ``k``
-    largest, renormalised to sum to one."""
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    ``[T, k]`` float32 gates).  ``softmax``: softmax over all experts,
+    the ``k`` largest, renormalised to sum to one.  ``sigmoid``: the
+    ``k`` largest sigmoid scores, normalised to sum to one, times
+    ``scale`` (the same experts as the softmax picks: both are monotone
+    in the logit)."""
+    logits = logits.astype(jnp.float32)
+    if scoring == "sigmoid":
+        gates, ids = jax.lax.top_k(jax.nn.sigmoid(logits), k)
+        return ids, scale * gates / gates.sum(axis=-1, keepdims=True)
+    probs = jax.nn.softmax(logits, axis=-1)
     gates, ids = jax.lax.top_k(probs, k)
     return ids, gates / gates.sum(axis=-1, keepdims=True)
 
@@ -194,11 +222,14 @@ class RoutedExperts(nn.Module):
     the layer's second half, the held experts' part of it and, where the
     block has one, a second branch ``beside`` (``[T, D]`` normed tokens in
     the compute dtype ``-> [T, D]``) on the same ``u`` under the same
-    residual add.  Norm, routing, experts, ``beside`` and the residual add
-    run on one chunk of ``token_chunk`` tokens at a time, so only the
-    layer's input and output exist at the size of the whole call.  Each
-    traced site with a ``beside`` adds 1 to the recorder's
-    ``experts.shared``."""
+    residual add.  ``scoring`` and ``routed_scale`` are :func:`route`'s;
+    ``post_scale`` norms the sum of both branches before the add
+    (:func:`post_norm`).  Norm, routing, experts, ``beside`` and the
+    residual add run on one chunk of ``token_chunk`` tokens at a time, so
+    only the layer's input and output exist at the size of the whole
+    call.  Each traced site with a ``beside`` adds 1 to the recorder's
+    ``experts.shared``, and one with sigmoid scores 1 to
+    ``router.sigmoid``."""
 
     num_experts: int
     top_k: int
@@ -209,11 +240,15 @@ class RoutedExperts(nn.Module):
     eps: float = 1e-6
     residual: float = 1.0
     dtype: jnp.dtype = jnp.float32
+    scoring: str = "softmax"
+    routed_scale: float = 1.0
+    param_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
     def __call__(self, h: jnp.ndarray, norm_scale: jnp.ndarray,
                  beside: Optional[Callable[[jnp.ndarray], jnp.ndarray]]
-                 = None) -> jnp.ndarray:
+                 = None, post_scale: Optional[jnp.ndarray] = None
+                 ) -> jnp.ndarray:
         D = h.shape[-1]
         first, held = self.held
         x = h.reshape(-1, D)
@@ -226,11 +261,12 @@ class RoutedExperts(nn.Module):
 
         init = nn.initializers.variance_scaling(
             1.0, "fan_in", "normal", in_axis=-2, out_axis=-1, batch_axis=0)
+        pd = self.param_dtype
         router = self.param("router", nn.initializers.lecun_normal(),
-                            (D, self.num_experts))
-        w_gate = self.param("w_gate", init, (held, D, self.width))
-        w_up = self.param("w_up", init, (held, D, self.width))
-        w_down = self.param("w_down", init, (held, self.width, D))
+                            (D, self.num_experts), pd)
+        w_gate = self.param("w_gate", init, (held, D, self.width), pd)
+        w_up = self.param("w_up", init, (held, D, self.width), pd)
+        w_down = self.param("w_down", init, (held, self.width, D), pd)
 
         # The experts' matrices in the compute dtype, made when the layer
         # runs and dropped after it.  ``tie`` is zero, but it is computed
@@ -241,12 +277,14 @@ class RoutedExperts(nn.Module):
         # ``optimization_barrier`` nor a cast of the one expert inside
         # the scan stops that (compiled for the v5e, PR 26).  The token is
         # made finite first, so that a NaN or inf in it stays that
-        # token's own and does not reach every expert's weights.
-        with scope("experts"):
-            x00 = x[0, 0].astype(jnp.float32)
-            tie = jnp.where(jnp.isfinite(x00), x00, 0.0) * 0.0
-            w_gate, w_up, w_down = ((w + tie).astype(self.dtype)
-                                    for w in (w_gate, w_up, w_down))
+        # token's own and does not reach every expert's weights.  Stacks
+        # held in the (narrower) compute dtype need no cast and get none.
+        if w_gate.dtype == jnp.float32:
+            with scope("experts"):
+                x00 = x[0, 0].astype(jnp.float32)
+                tie = jnp.where(jnp.isfinite(x00), x00, 0.0) * 0.0
+                w_gate, w_up, w_down = ((w + tie).astype(self.dtype)
+                                        for w in (w_gate, w_up, w_down))
 
         def one_chunk(hc):
             with scope("residual"):
@@ -254,18 +292,24 @@ class RoutedExperts(nn.Module):
             with scope("moe_router"):
                 logits = jnp.dot(xc, router.astype(self.dtype),
                                  preferred_element_type=jnp.float32)
-                ids, gates = route(logits, self.top_k)
+                ids, gates = route(logits, self.top_k, self.scoring,
+                                   self.routed_scale)
             with scope("experts"):
                 y = expert_outputs(xc, ids, gates, w_gate, w_up, w_down,
                                    first=first, block=self.block,
                                    of=self.num_experts)
             if beside is not None:
                 y = y.astype(jnp.float32) + beside(xc)
+            y = post_norm(y, post_scale, self.eps)
             with scope("residual"):
                 return add_residual(hc, y, self.residual)
 
         if beside is not None:
             count("experts.shared")
+        if self.scoring == "sigmoid":
+            count("router.sigmoid")
+        if post_scale is not None:
+            count("residual.post_norm")
         with scope("experts"):
             y = jax.lax.map(one_chunk, x.reshape(T // chunk, chunk, D))
             return y.reshape(h.shape)

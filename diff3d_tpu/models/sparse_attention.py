@@ -31,7 +31,7 @@ by XLA, which is also the kernel's gradient.
 
 from __future__ import annotations
 
-from typing import Sequence, Tuple
+from typing import Optional, Sequence, Tuple
 
 import flax.linen as nn
 import jax
@@ -119,17 +119,21 @@ class Kernel(nn.Module):
     inside a map over examples where no module can be called."""
 
     features: int
+    param_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
     def __call__(self, fan_in: int) -> jnp.ndarray:
         return self.param("kernel", nn.initializers.lecun_normal(),
-                          (fan_in, self.features))
+                          (fan_in, self.features), self.param_dtype)
 
 
 class Scale(nn.Module):
+    param_dtype: jnp.dtype = jnp.float32
+
     @nn.compact
     def __call__(self, n: int) -> jnp.ndarray:
-        return self.param("scale", nn.initializers.ones, (n,))
+        return self.param("scale", nn.initializers.ones, (n,),
+                          self.param_dtype)
 
 
 class SparseAttention(nn.Module):
@@ -227,8 +231,8 @@ class SparseAttention(nn.Module):
         with scope("attention"):
             return self._dense(out, W["o"])
 
-    def __call__(self, h: jnp.ndarray, norm_scale: jnp.ndarray
-                 ) -> jnp.ndarray:
+    def __call__(self, h: jnp.ndarray, norm_scale: jnp.ndarray,
+                 post_scale: Optional[jnp.ndarray] = None) -> jnp.ndarray:
         L = h.shape[1]
         assert L == int(np.prod(self.grid)), (L, self.grid)
         if L % min(self.q_chunk, L):
@@ -238,4 +242,5 @@ class SparseAttention(nn.Module):
 
         with scope("sparse_attention"):
             return residual_half(h, norm_scale, self.eps, self.residual,
-                                 lambda u: self.attend_example(u, W))
+                                 lambda u: self.attend_example(u, W),
+                                 post_scale)

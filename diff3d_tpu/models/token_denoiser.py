@@ -24,8 +24,11 @@ computed at ``G`` rows and meet the examples in one broadcast add.
            sinusoid; the sum times ``embedding_multiplier``.
   layers   pre-norm, ``h += r mixer(norm(h)); h += r ffn(norm(h))`` with
            ``r = residual_multiplier`` (:mod:`.sparse_attention`,
-           :mod:`.token_layers`, :mod:`.mamba`; :mod:`.moe`); with both
-           feed-forwards, ``ffn(u) = routed(u) + mlp(u)``.
+           :mod:`.token_layers`, :mod:`.mamba`, :mod:`.latent_attention`;
+           :mod:`.moe`); with both feed-forwards, ``ffn(u) = routed(u) +
+           mlp(u)``; with ``sandwich_norm``, each half's output normed
+           again before its add; the first ``first_k_dense_replace``
+           layers with a dense MLP for ``ffn``.
   output   RMSNorm, a linear head to ``patch^2 * 3`` values per target
            token over ``logits_scaling``, un-patchified.
 
@@ -43,6 +46,7 @@ from diff3d_tpu.geometry import (pinhole_rays_cam, pinhole_rays_world,
                                  posenc_ddpm, posenc_nerf)
 from diff3d_tpu.models.conditioning import (DIR_DEG, POS_DEG,
                                             conditioning_rows)
+from diff3d_tpu.models.latent_attention import LatentAttention
 from diff3d_tpu.models.mamba import Mamba2Mixer
 from diff3d_tpu.models.moe import RoutedExperts, rms_norm
 from diff3d_tpu.models.sparse_attention import Scale, SparseAttention
@@ -78,21 +82,29 @@ class DecoderLayer(nn.Module):
     with both widths (``num_experts`` and ``shared_intermediate_size``)
     has both under the experts' norm and residual add: the MLP is then
     no half of its own (no ``mlp_norm``) but the branch ``moe`` computes
-    beside the experts, chunk by chunk, on the same normed tokens."""
+    beside the experts, chunk by chunk, on the same normed tokens.  A
+    ``dense`` layer (one of the config's ``first_k_dense_replace``) has
+    a dense MLP of ``dense_intermediate_size`` in place of both.  With
+    ``sandwich_norm`` each half's output is normed again before its
+    residual add, by a scale of its own (``attn_post_norm``, ...)."""
 
     cfg: TokenModelConfig
     kind: str = "sparse_attention"
+    dense: bool = False
 
     @property
     def halves(self):
         """The names of the layer's two halves in its parameter tree."""
         return ("mamba" if self.kind == "mamba" else "attn",
-                "moe" if self.cfg.num_experts else "mlp")
+                "moe" if self.cfg.num_experts and not self.dense else "mlp")
 
     def setup(self):
         cfg = self.cfg
+        pd = jnp.dtype(cfg.param_dtype)
         for half in self.halves:
-            setattr(self, half + "_norm", Scale())
+            setattr(self, half + "_norm", Scale(pd))
+            if cfg.sandwich_norm:
+                setattr(self, half + "_post_norm", Scale(pd))
         common = dict(eps=cfg.rms_norm_eps,
                       residual=cfg.residual_multiplier,
                       dtype=jnp.dtype(cfg.dtype))
@@ -108,6 +120,14 @@ class DecoderLayer(nn.Module):
                 num_kv_heads=cfg.num_key_value_heads,
                 head_dim=cfg.head_dim, q_chunk=cfg.q_chunk,
                 scale=cfg.attention_multiplier, **common)
+        elif self.kind == "latent_attention":
+            self.attn = LatentAttention(
+                hidden=cfg.hidden_size, num_heads=cfg.num_attention_heads,
+                q_lora_rank=cfg.q_lora_rank, kv_lora_rank=cfg.kv_lora_rank,
+                qk_nope_head_dim=cfg.qk_nope_head_dim,
+                qk_rope_head_dim=cfg.qk_rope_head_dim,
+                v_head_dim=cfg.v_head_dim, q_chunk=cfg.q_chunk,
+                rope_theta=cfg.rope_theta, param_dtype=pd, **common)
         else:
             self.attn = SparseAttention(
                 hidden=cfg.hidden_size, num_heads=cfg.num_attention_heads,
@@ -119,31 +139,43 @@ class DecoderLayer(nn.Module):
                 grid=(2, cfg.H // cfg.patch, cfg.W // cfg.patch),
                 rope_theta=cfg.rope_theta,
                 mrope_section=tuple(cfg.mrope_section), **common)
-        if cfg.num_experts:
+        if self.halves[1] == "moe":
             self.moe = RoutedExperts(
                 num_experts=cfg.num_experts, top_k=cfg.num_experts_per_tok,
                 width=cfg.moe_intermediate_size,
                 held=tuple(cfg.experts_held),
                 token_chunk=cfg.expert_token_chunk, block=cfg.expert_block,
+                scoring=cfg.scoring_func,
+                routed_scale=cfg.routed_scaling_factor, param_dtype=pd,
                 **common)
-        if cfg.shared_intermediate_size:
-            self.mlp = GatedMLP(hidden=cfg.hidden_size,
-                                width=cfg.shared_intermediate_size,
-                                **common)
+        width = (cfg.dense_intermediate_size if self.dense
+                 else cfg.shared_intermediate_size)
+        if width:
+            self.mlp = GatedMLP(hidden=cfg.hidden_size, width=width,
+                                param_dtype=pd, **common)
+
+    def _norms(self, half: str, D: int):
+        """The half's norm scale and its post-norm's (``None`` without
+        ``sandwich_norm``)."""
+        post = (getattr(self, half + "_post_norm")(D)
+                if self.cfg.sandwich_norm else None)
+        return getattr(self, half + "_norm")(D), post
 
     def __call__(self, h: jnp.ndarray) -> jnp.ndarray:
         mixer = self.halves[0]
-        h = getattr(self, mixer)(h, getattr(self, mixer + "_norm")(
-            h.shape[-1]))
+        pre, post = self._norms(mixer, h.shape[-1])
+        h = getattr(self, mixer)(h, pre, post_scale=post)
         return self.feed_forward(h)
 
     def feed_forward(self, h: jnp.ndarray) -> jnp.ndarray:
         """The layer's second half, ``h + r ffn(norm(h))``."""
         half = self.halves[1]
-        norm_scale = getattr(self, half + "_norm")(h.shape[-1])
-        if half == "moe" and self.cfg.shared_intermediate_size:
-            return self.moe(h, norm_scale, beside=self.mlp.branch())
-        return getattr(self, half)(h, norm_scale)
+        pre, post = self._norms(half, h.shape[-1])
+        if half == "mlp":
+            return self.mlp(h, pre, post_scale=post)
+        beside = (self.mlp.branch() if self.cfg.shared_intermediate_size
+                  else None)
+        return self.moe(h, pre, beside=beside, post_scale=post)
 
 
 class TokenDenoiser(nn.Module):
@@ -153,14 +185,17 @@ class TokenDenoiser(nn.Module):
         cfg = self.cfg
         cfg.validate()
         dtype = jnp.dtype(cfg.dtype)
+        pd = jnp.dtype(cfg.param_dtype)
         D = cfg.hidden_size
-        self.patch_embed = nn.Dense(D, dtype=dtype)
-        self.ray_proj = nn.Dense(D, dtype=dtype)
-        self.logsnr_mlp_0 = nn.Dense(D, dtype=dtype)
-        self.logsnr_mlp_1 = nn.Dense(D, dtype=dtype)
-        self.layers = [DecoderLayer(cfg, kind) for kind in cfg.mixers]
-        self.final_norm = Scale()
+        self.patch_embed = nn.Dense(D, dtype=dtype, param_dtype=pd)
+        self.ray_proj = nn.Dense(D, dtype=dtype, param_dtype=pd)
+        self.logsnr_mlp_0 = nn.Dense(D, dtype=dtype, param_dtype=pd)
+        self.logsnr_mlp_1 = nn.Dense(D, dtype=dtype, param_dtype=pd)
+        self.layers = [DecoderLayer(cfg, kind, cfg.dense_layer(i))
+                       for i, kind in enumerate(cfg.mixers)]
+        self.final_norm = Scale(pd)
         self.head = nn.Dense(cfg.patch * cfg.patch * 3, dtype=dtype,
+                             param_dtype=pd,
                              kernel_init=nn.initializers.zeros)
 
     def embed(self, batch: dict, cond_mask: jnp.ndarray) -> jnp.ndarray:

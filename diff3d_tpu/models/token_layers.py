@@ -62,8 +62,8 @@ class FullAttention(nn.Module):
         self.v_proj = Kernel(self.num_kv_heads * self.head_dim)
         self.o_proj = Kernel(self.hidden)
 
-    def __call__(self, h: jnp.ndarray, norm_scale: jnp.ndarray
-                 ) -> jnp.ndarray:
+    def __call__(self, h: jnp.ndarray, norm_scale: jnp.ndarray,
+                 post_scale: Optional[jnp.ndarray] = None) -> jnp.ndarray:
         L, D = h.shape[1:]
         d, C = self.head_dim, min(self.q_chunk, L)
         if L % C:
@@ -90,7 +90,7 @@ class FullAttention(nn.Module):
 
         with scope("attention"):
             return residual_half(h, norm_scale, self.eps, self.residual,
-                                 attend)
+                                 attend, post_scale)
 
 
 class GatedMLP(nn.Module):
@@ -99,10 +99,11 @@ class GatedMLP(nn.Module):
     eps: float = 1e-6
     residual: float = 1.0
     dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32
 
     def setup(self):
-        self.w1 = Kernel(2 * self.width)
-        self.w2 = Kernel(self.hidden)
+        self.w1 = Kernel(2 * self.width, self.param_dtype)
+        self.w2 = Kernel(self.hidden, self.param_dtype)
 
     def branch(self):
         """``u [T, D] -> W_2 (silu(a) * b)`` float32, a plain function of
@@ -120,9 +121,9 @@ class GatedMLP(nn.Module):
 
         return mlp
 
-    def __call__(self, h: jnp.ndarray, norm_scale: jnp.ndarray
-                 ) -> jnp.ndarray:
+    def __call__(self, h: jnp.ndarray, norm_scale: jnp.ndarray,
+                 post_scale: Optional[jnp.ndarray] = None) -> jnp.ndarray:
         mlp = self.branch()
         with scope("mlp"):
             return residual_half(h, norm_scale, self.eps, self.residual,
-                                 mlp)
+                                 mlp, post_scale)

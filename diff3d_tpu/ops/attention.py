@@ -49,7 +49,31 @@ PLAIN_MIN_KEYS = 2048
 
 
 def _xla_sdpa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray) -> jnp.ndarray:
+    if v.shape[-1] != q.shape[-1]:
+        return _xla_narrow_values(q, k, v)
     return jax.nn.dot_product_attention(q, k, v)
+
+
+def _xla_narrow_values(q, k, v) -> jnp.ndarray:
+    """``jax.nn.dot_product_attention``'s XLA expression where ``v``'s
+    head dim differs from ``q`` / ``k``'s (latent attention: 128 against
+    192), which that function refuses: float32 scores over ``D_qk^-1/2``,
+    the softmax in float32, probabilities in ``k``'s dtype, ``v`` as it
+    is (nothing padded).
+
+    The row max is kept out of the softmax's fusion by a barrier: given
+    ``jax.nn.softmax`` whole, the v5e's compiler turns max-and-broadcast
+    into a ``reduce-window`` 16383 keys wide over the whole score tile,
+    3172 ms a layer-example at 128 heads x 8192 x 8192 against 210 with
+    the barrier (one v5e, PERF.md section 5)."""
+    s = jnp.einsum("bthd,bshd->bhts", q, k,
+                   preferred_element_type=jnp.float32)
+    s = s * jnp.float32(q.shape[-1] ** -0.5)
+    m = jax.lax.optimization_barrier(
+        jax.lax.stop_gradient(jnp.max(s, axis=-1, keepdims=True)))
+    e = jnp.exp(s - m)
+    p = e / jnp.sum(e, axis=-1, keepdims=True)
+    return jnp.einsum("bhts,bshd->bthd", p.astype(k.dtype), v)
 
 
 def _pallas_auto(q, k, v) -> bool:
@@ -133,7 +157,9 @@ def _resolve_auto(q: jnp.ndarray) -> str:
 
 def sdpa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
          impl: str = "auto", keep: jnp.ndarray | None = None) -> jnp.ndarray:
-    """Scaled dot-product attention over ``[B, L, H, D]`` tensors.
+    """Scaled dot-product attention over ``[B, L, H, D]`` tensors.  ``v``
+    may have a head dim of its own (the output's): no Pallas core takes
+    such operands, so ``'auto'`` resolves to the XLA expression.
 
     ``keep`` (optional ``[B, Lq, Lk]`` bool, shared by all heads) is a
     selection: the softmax runs over the kept keys alone, as the sparse

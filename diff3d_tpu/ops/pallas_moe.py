@@ -58,7 +58,8 @@ held here.  The Pallas cores move a row by one DMA, and only rows in use:
 Mosaic lets a DMA slice the tiled second-minor dimension of an array by
 eight rows, not one, so a row is addressed through ``[rows, D / 128,
 128]`` (:func:`_as_rows`: a row is one contiguous run of whole tiles,
-its index an untiled dimension): ``x`` goes there by an XLA relayout of
+its index an untiled dimension; in bf16 not every ``D / 128`` can be
+sliced so, and there the XLA cores move the rows): ``x`` goes there by an XLA relayout of
 the chunk's ``T`` rows, the rows that landed in VMEM are re-tiled in the
 kernel, and ``ys`` by a third small kernel that re-tiles the blocks in
 use alone.  Each kernel has the sampler's object axis as a leading grid
@@ -285,9 +286,16 @@ def expert_combine_reference(ys: jnp.ndarray, at: jnp.ndarray,
 
 
 def _movable(dtype, D: int, m: int) -> bool:
+    """D whole lane tiles, a block whole sublane tiles, and a row ``[D /
+    128, 128]`` of :func:`_as_rows` that a DMA can slice: in bf16, where
+    rows are packed in pairs, ``D / 128`` a multiple of 8 or 2 or 4 (the
+    v5e's compiler, asked at 1 to 64 lane tiles, refuses 1, 3, 5, 6, 9,
+    12, 20, 36 and 60, a row of 7680; float32 takes every count)."""
     sublane = 32 // jnp.dtype(dtype).itemsize
+    n = D // LANE
     return (dtype in (jnp.float32, jnp.bfloat16) and D % LANE == 0
-            and m % sublane == 0)
+            and m % sublane == 0
+            and (dtype == jnp.float32 or n % 8 == 0 or n in (2, 4)))
 
 
 def expert_rows_supports(x, token, ends, block, **_) -> bool:

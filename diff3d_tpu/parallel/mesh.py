@@ -218,6 +218,12 @@ def tp_param_sharding(mesh: Mesh, path, shape: Sequence[int],
         where the MLP is a shared expert beside an expert stack (one
         layer then has ``moe/...`` by whole experts, ``mlp/...`` split
         inside, and a replicated mixer).
+      * latent attention: the latent projections ``q_a_proj``,
+        ``kv_a_proj`` replicated, explicitly (their outputs are normed
+        whole, and ``kv_a_proj``'s carries the rotary key all heads
+        share); the expansions ``q_b_proj``, ``kv_b_proj`` column-
+        parallel (whole heads where the head count divides), ``o_proj``
+        row-parallel as above.
       * every leaf of a state-space mixer (``mamba/...``) — replicated,
         explicitly: the fused ``[z | xBC | dt]`` projection does not
         split at one column boundary, and the heads' state, conv and
@@ -237,13 +243,15 @@ def tp_param_sharding(mesh: Mesh, path, shape: Sequence[int],
         return len(shape) > dim and shape[dim] % tp == 0 and shape[dim] >= tp
 
     is_kernel = names and names[-1] == "kernel"
-    if "mamba" in names:
+    if "mamba" in names or any(n in ("q_a_proj", "kv_a_proj")
+                               for n in names):
         pass                               # whole on every device
     elif tp > 1 and names and names[-1] in ("w_gate", "w_up", "w_down"):
         if len(shape) == 3 and shardable(0):
             spec[0] = model_axis           # expert stacks: whole experts
     elif tp > 1 and is_kernel:
-        if any(n in ("q_proj", "k_proj", "v_proj") for n in names):
+        if any(n in ("q_proj", "k_proj", "v_proj", "q_b_proj",
+                     "kv_b_proj") for n in names):
             if shardable(len(shape) - 1):
                 spec[-1] = model_axis
         elif any(n in ("out_proj", "o_proj", "w2") for n in names):

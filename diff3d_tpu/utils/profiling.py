@@ -63,7 +63,12 @@ SCOPES = ("conv", "film", "groupnorm", "attention", "conditioning",
           # a state-space mixer's four (models/mamba.py: projections,
           # causal conv, the scan with its steps and decays, the gated
           # norm) and the dense gated MLP (models/token_layers.py)
-          "ssm_proj", "ssm_conv", "ssm_scan", "ssm_gate", "mlp")
+          "ssm_proj", "ssm_conv", "ssm_scan", "ssm_gate", "mlp",
+          # latent attention's two projection paths (models/
+          # latent_attention.py: W_qa, its norm, W_qb; W_kva, its norm,
+          # W_kvb); its rotary turn is "rope", its core and W_o
+          # "attention"
+          "mla_q", "mla_kv")
 
 
 def scope(tag: str):

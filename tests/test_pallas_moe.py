@@ -18,7 +18,9 @@ from diff3d_tpu.models import moe
 from diff3d_tpu.ops import dispatch, pallas_moe
 from diff3d_tpu.utils.profiling import RECORDER
 
-E, K, D, F, M = 8, 2, 128, 128, 16          # one lane tile, bf16's sublanes
+# D two lane tiles: the least bf16 row the chip's DMA slices (one is
+# refused); F one lane tile; M bf16's sublanes
+E, K, D, F, M = 8, 2, 256, 128, 16
 T = 128
 HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -471,6 +473,8 @@ def _move_shapes(op, d=D, m=M, dtype=jnp.float32, t=T, k=K, n=24,
 def test_the_moves_support_both_cells_shapes():
     for op, supports in MOVE_SUPPORTS.items():
         assert supports(*_move_shapes(op))
+        # float32 rows of any whole number of lane tiles slice
+        assert supports(*_move_shapes(op, d=7680, m=256))
         # granite4_h_small_tok128: 169 blocks a chunk of 4096 at top-10
         assert supports(*_move_shapes(op, d=4096, m=256, t=4096, k=10,
                                       n=169, dtype=jnp.bfloat16))
@@ -485,6 +489,10 @@ MOVES_REFUSED = {
     "block_not_a_whole_sublane_tile": dict(m=12),
     "bf16_block_of_8_rows": dict(m=8, dtype=jnp.bfloat16),
     "float16": dict(dtype=jnp.float16),
+    # bf16 rows the v5e's compiler will not slice by a DMA: one lane tile,
+    # and pangu_ultra_moe_tok128's 60 (hidden 7680)
+    "bf16_row_of_one_lane_tile": dict(d=128, dtype=jnp.bfloat16),
+    "bf16_row_of_60_lane_tiles": dict(d=7680, m=256, dtype=jnp.bfloat16),
 }
 
 
