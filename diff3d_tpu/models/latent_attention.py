@@ -21,9 +21,13 @@ an RMSNorm with a weight of its own::
            qk_rope)^1/2, times v_h; W_o over the heads' v_head-wide
            outputs.
 
-The core is :func:`~diff3d_tpu.ops.attention.sdpa` with values narrower
-than queries and keys, one tile of ``q_chunk`` queries at a time: its XLA
-expression (no Pallas core takes such operands), whose float32 scores are
+The core is :func:`~diff3d_tpu.ops.attention.sdpa_latent` on the split
+operands (``q_nope``, the turned ``q_pe``, ``k_nope``, the one turned
+``k_pe``, ``v``).  On a TPU process it runs the Pallas kernel
+``latent_attention`` on all ``L`` queries of an example at once, which
+reads the shared rotary key once a key block for all heads and keeps the
+scores in VMEM; elsewhere (every CPU process) the XLA expression, one
+tile of ``q_chunk`` queries at a time, whose float32 scores are
 ``[heads, q_chunk, L]`` a tile.  Scope tags: ``mla_q`` (``W_qa``,
 ``n_qa``, ``W_qb``), ``mla_kv`` (``W_kva``, ``n_kva``, ``W_kvb``),
 ``rope``, ``attention`` (the core and ``W_o``).  Each traced site adds 1
@@ -35,14 +39,13 @@ from __future__ import annotations
 from typing import Optional
 
 import flax.linen as nn
-import jax
 import jax.numpy as jnp
 
 from diff3d_tpu.models.moe import residual_half, rms_norm
 from diff3d_tpu.models.sparse_attention import (Kernel, Scale,
                                                 mrope_tables, rotate)
 from diff3d_tpu.models.token_layers import dense
-from diff3d_tpu.ops.attention import sdpa
+from diff3d_tpu.ops.attention import sdpa_latent
 from diff3d_tpu.utils.profiling import count, scope
 
 
@@ -94,27 +97,25 @@ class LatentAttention(nn.Module):
         dt, eps = self.dtype, self.eps
 
         def attend(u):
+            # a leading example axis of 1, as sdpa_latent takes them
             with scope("mla_q"):
                 cq = rms_norm(dense(u, W["qa"], dt), W["qn"], eps)
-                q = dense(cq, W["qb"], dt).reshape(L, H, dn + dr)
+                # W_qb's columns by part, so that each part comes out of
+                # its own product whole, in the layout the core reads
+                qb = W["qb"].reshape(qr, H, dn + dr)
+                q_nope = dense(cq, qb[..., :dn].reshape(qr, H * dn), dt)
+                q_pe = dense(cq, qb[..., dn:].reshape(qr, H * dr), dt)
             with scope("mla_kv"):
                 ckv, k_pe = jnp.split(dense(u, W["kva"], dt), [kvr], axis=-1)
                 kv = dense(rms_norm(ckv, W["kvn"], eps), W["kvb"], dt)
-                k_nope, v = jnp.split(kv.reshape(L, H, dn + dv), [dn],
+                k_nope, v = jnp.split(kv.reshape(1, L, H, dn + dv), [dn],
                                       axis=-1)
             with scope("rope"):
-                q = jnp.concatenate(
-                    [q[..., :dn], rotate(q[..., dn:], cos, sin)], axis=-1)
-                k_pe = rotate(k_pe[:, None, :], cos, sin)
-                k = jnp.concatenate(
-                    [k_nope, jnp.broadcast_to(k_pe, (L, H, dr))], axis=-1)
-
-            def one_tile(c):
-                qc = jax.lax.dynamic_slice_in_dim(q, c * C, C)
-                return sdpa(qc[None], k[None], v[None])[0]
-
+                q_pe = rotate(q_pe.reshape(1, L, H, dr), cos, sin)
+                k_pe = rotate(k_pe[None, :, None], cos, sin)[:, :, 0]
             with scope("attention"):
-                out = jax.lax.map(one_tile, jnp.arange(L // C))
+                out = sdpa_latent(q_nope.reshape(1, L, H, dn), q_pe, k_nope,
+                                  k_pe, v, q_chunk=C)
                 return dense(out.reshape(L, H * dv), W["o"], dt,
                              jnp.float32)
 

@@ -25,8 +25,14 @@ epilogues):
     above takes it, else xla: ``flash_attention`` is never chosen
     (:func:`_pallas_auto` has the measurements).
 
-Two ops are registered: ``'sdpa'`` (plain) and ``'sdpa_selected'`` (with
-``keep``); each has its own ``supports`` and ``auto`` policy.
+Three ops are registered: ``'sdpa'`` (plain), ``'sdpa_selected'`` (with
+``keep``) and ``'sdpa_latent'`` (multi-head latent attention's core: one
+rotary key shared by all heads, values narrower than queries and keys);
+each has its own ``supports`` and ``auto`` policy.  ``sdpa_latent`` runs
+the Pallas kernel ``latent_attention`` on a TPU process where it supports
+the operands (the ``pangu_ultra_moe`` cell's five sites) and the XLA
+expression by query tiles everywhere else; ``sdpa`` on such widths keeps
+resolving to XLA.
 
 All shapes here are ``[B, L, n_heads, head_dim]`` (jax.nn convention).
 """
@@ -74,6 +80,46 @@ def _xla_narrow_values(q, k, v) -> jnp.ndarray:
     e = jnp.exp(s - m)
     p = e / jnp.sum(e, axis=-1, keepdims=True)
     return jnp.einsum("bhts,bshd->bthd", p.astype(k.dtype), v)
+
+
+def _xla_latent(q_nope, q_pe, k_nope, k_pe, v,
+                q_chunk: int | None = None) -> jnp.ndarray:
+    """Latent attention's core as XLA computes it: ``q = [q_nope |
+    q_pe]``, ``k = [k_nope | k_pe]`` with the one rotary key ``k_pe [B,
+    Lk, Dr]`` broadcast to every head, then :func:`_xla_narrow_values`,
+    one tile of ``q_chunk`` queries at a time (all at once if None): the
+    float32 scores are ``[B, H, q_chunk, Lk]`` a tile."""
+    B, Lq, H, _ = q_nope.shape
+    Lk = k_nope.shape[1]
+    q = jnp.concatenate([q_nope, q_pe], axis=-1)
+    k = jnp.concatenate([k_nope, jnp.broadcast_to(
+        k_pe[:, :, None], (B, Lk, H, k_pe.shape[-1]))], axis=-1)
+    C = q_chunk or Lq
+    if C >= Lq:
+        return _xla_narrow_values(q, k, v)
+
+    def one_tile(c):
+        return _xla_narrow_values(
+            jax.lax.dynamic_slice_in_dim(q, c * C, C, axis=1), k, v)
+
+    out = jax.lax.map(one_tile, jnp.arange(Lq // C))    # [T, B, C, H, Dv]
+    return jnp.moveaxis(out, 0, 1).reshape(B, Lq, H, v.shape[-1])
+
+
+def _pallas_latent(q_nope, q_pe, k_nope, k_pe, v,
+                   q_chunk: int | None = None) -> jnp.ndarray:
+    """The kernel on all queries at once: it tiles them itself, and XLA
+    does not hoist a kernel's wrapper ops out of a ``lax.map`` (PERF.md
+    section 5, the attention site alone)."""
+    from diff3d_tpu.ops.pallas_attention import latent_attention
+
+    return latent_attention(q_nope, q_pe, k_nope, k_pe, v)
+
+
+def _pallas_latent_supports(q_nope, q_pe, k_nope, k_pe, v) -> bool:
+    from diff3d_tpu.ops.pallas_attention import latent_supports
+
+    return latent_supports(q_nope, q_pe, k_nope, k_pe, v)
 
 
 def _pallas_auto(q, k, v) -> bool:
@@ -142,6 +188,11 @@ dispatch.register("sdpa", "pallas", _pallas_sdpa,
 dispatch.register("sdpa_selected", "xla", _xla_selected)
 dispatch.register("sdpa_selected", "pallas", _pallas_selected,
                   supports=_pallas_selected_supports)
+# Latent attention's core: the kernel wherever it runs (PERF.md section
+# 5, the latent core alone), no 'auto' policy beyond 'supports'.
+dispatch.register("sdpa_latent", "xla", _xla_latent)
+dispatch.register("sdpa_latent", "pallas", _pallas_latent,
+                  supports=_pallas_latent_supports)
 
 
 def _resolve_auto(q: jnp.ndarray) -> str:
@@ -158,8 +209,10 @@ def _resolve_auto(q: jnp.ndarray) -> str:
 def sdpa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
          impl: str = "auto", keep: jnp.ndarray | None = None) -> jnp.ndarray:
     """Scaled dot-product attention over ``[B, L, H, D]`` tensors.  ``v``
-    may have a head dim of its own (the output's): no Pallas core takes
-    such operands, so ``'auto'`` resolves to the XLA expression.
+    may have a head dim of its own (the output's): no Pallas core of this
+    op takes such operands, so ``'auto'`` resolves to the XLA expression
+    (latent attention's core, whose keys share one rotary part, is
+    :func:`sdpa_latent`'s, with a kernel of its own).
 
     ``keep`` (optional ``[B, Lq, Lk]`` bool, shared by all heads) is a
     selection: the softmax runs over the kept keys alone, as the sparse
@@ -199,6 +252,34 @@ def sdpa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     core = dispatch.resolve("sdpa", impl, q, k, v)
     count(f"sdpa.plain.{core.name}")
     return core.fn(q, k, v)
+
+
+def sdpa_latent(q_nope: jnp.ndarray, q_pe: jnp.ndarray,
+                k_nope: jnp.ndarray, k_pe: jnp.ndarray, v: jnp.ndarray,
+                impl: str = "auto", q_chunk: int | None = None
+                ) -> jnp.ndarray:
+    """Multi-head latent attention's core: ``q_nope, q_pe [B, Lq, H,
+    Dn | Dr]``, ``k_nope [B, Lk, H, Dn]``, one rotary key ``k_pe [B, Lk,
+    Dr]`` shared by every head, ``v [B, Lk, H, Dv]`` -> ``[B, Lq, H,
+    Dv]``; head ``h`` scores ``[q_nope_h | q_pe_h] . [k_nope_h | k_pe]``
+    over ``(Dn + Dr)^1/2``.
+
+    Op ``'sdpa_latent'`` of the registry, two cores: the Pallas forward
+    kernel ``latent_attention`` (all queries in one call; the shared key
+    read once a key block for all heads of a grid step, the score tile in
+    VMEM), and the XLA expression (the concatenation, ``k_pe`` broadcast
+    to the heads, :func:`_xla_narrow_values`) one tile of ``q_chunk``
+    queries at a time, which is also the kernel's gradient.  ``'auto'``
+    takes the kernel on a TPU process when its ``supports`` holds (``Dn``
+    and ``Dv`` whole lane tiles, ``Dr`` at most one, whole query and key
+    blocks) and the XLA expression otherwise, so CPU processes lower as
+    they did; ``'pallas'`` is honoured or raises.  Each traced site adds
+    1 to the recorder's ``sdpa.latent.pallas`` or ``sdpa.latent.xla``.
+    """
+    core = dispatch.resolve("sdpa_latent", impl, q_nope, q_pe, k_nope,
+                            k_pe, v)
+    count(f"sdpa.latent.{core.name}")
+    return core.fn(q_nope, q_pe, k_nope, k_pe, v, q_chunk=q_chunk)
 
 
 def multi_head_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,

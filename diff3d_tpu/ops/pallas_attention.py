@@ -1,17 +1,20 @@
 """TPU Pallas attention kernels: flash attention (forward + backward),
-attention under a selection (forward) and plain grouped-query attention
-(forward).
+attention under a selection (forward), plain grouped-query attention
+(forward) and latent attention's core (forward).
 
-Three kernels share this file and its helpers.  :func:`flash_attention`,
+Four kernels share this file and its helpers.  :func:`flash_attention`,
 described first, is the X-UNet's, asked for by hand: no mask, one
 key-value head per query head, float32 dots, 128 x 128 tiles, its own
 backward kernels.  :func:`selected_attention` is the token denoiser's: a
 selection ``keep [B, Lq, Lk]``, grouped queries, operands to the MXU in
 the dtype given, blocks sized from the shape, the XLA expression's
-gradient.  :func:`plain_attention` (the last section) is that algorithm
-without a selection and made to run at head dim 64: what ``sdpa``
-resolves to on a TPU process from 2048 keys on.  The last two share
-their online-softmax step and block rule.
+gradient.  :func:`plain_attention` is that algorithm without a selection
+and made to run at head dim 64: what ``sdpa`` resolves to on a TPU
+process from 2048 keys on.  :func:`latent_attention` (the last section)
+is that algorithm for multi-head latent attention: queries and keys of
+a per-head part and a rotary part whose key all heads share, values of
+their own width (``sdpa_latent``).  The last three share their
+online-softmax step and block rule.
 
 Replaces the reference's ``torch.nn.MultiheadAttention`` sdpa core
 (``/root/reference/xunet.py:154-177``, which delegates to cuDNN) with a
@@ -797,3 +800,165 @@ def plain_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     if interpret is None:
         interpret = dispatch.interpret_default()
     return _plain(q, k, v, bool(interpret))
+
+
+# --------------------------------------------------------------------------
+# latent attention's core: forward kernel, one rotary key for all heads
+# --------------------------------------------------------------------------
+
+LATENT_KV_BLOCK = 8 << 20  # bytes of a step's k_nope | v block: sets heads
+LATENT_MAX_D = 256          # q_nope / k_nope / v widths, whole lane tiles
+
+
+def latent_supports(q_nope: jnp.ndarray, q_pe: jnp.ndarray,
+                    k_nope: jnp.ndarray, k_pe: jnp.ndarray,
+                    v: jnp.ndarray) -> bool:
+    """Shapes/dtypes :func:`latent_attention` handles: ``q_nope [B, Lq,
+    H, Dn]``, ``q_pe [B, Lq, H, Dr]``, ``k_nope [B, Lk, H, Dn]``, ``k_pe
+    [B, Lk, Dr]``, ``v [B, Lk, H, Dv]`` of one dtype (bf16 or float32);
+    ``Dn`` and ``Dv`` whole lane tiles up to ``LATENT_MAX_D``, ``Dr`` at
+    most one lane tile; ``Lq`` / ``Lk`` whole query / key blocks."""
+    ops = (q_nope, q_pe, k_nope, k_pe, v)
+    if tuple(a.ndim for a in ops) != (4, 4, 4, 3, 4):
+        return False
+    if q_nope.dtype not in (jnp.float32, jnp.bfloat16) \
+            or any(a.dtype != q_nope.dtype for a in ops):
+        return False
+    B, Lq, H, dn = q_nope.shape
+    Lk, dr, dv = k_nope.shape[1], q_pe.shape[3], v.shape[3]
+    if q_pe.shape[:3] != (B, Lq, H) or k_nope.shape != (B, Lk, H, dn) \
+            or k_pe.shape != (B, Lk, dr) or v.shape[:3] != (B, Lk, H):
+        return False
+    lanes = all(d % LANE == 0 and 0 < d <= LATENT_MAX_D for d in (dn, dv))
+    return lanes and 0 < dr <= LANE \
+        and _selected_blocks(Lq, Lk, PLAIN_TILE) is not None
+
+
+def _latent_kernel(qn_ref, qp_ref, kv_ref, kp_ref, o_ref, m_scr, l_scr,
+                   acc_scr, *, scale: float):
+    """One (example, block of heads, query block, key block) step.  Head
+    ``h`` of the block is a lane slice, whole lane tiles, of ``q_nope``,
+    of ``q_pe`` (zero-padded to a lane tile) and of ``kv`` (``[k_nope |
+    v]`` a head, as the mixer's projection lays them out).  Its scores
+    are one dot, ``[q_nope | q_pe] [k_nope | k_pe]^T``, with the one
+    ``k_pe`` block that every head of the step shares."""
+    heads, _, dv = acc_scr.shape
+    dn = qn_ref.shape[-1] // heads
+    wkv = kv_ref.shape[-1] // heads
+    ki = pl.program_id(3)
+    pl.when(ki == 0)(lambda: _init_running(m_scr, l_scr, acc_scr))
+    kp = kp_ref[0]                                         # [bk, 128]
+    for h in range(heads):
+        kv = kv_ref[0, :, h * wkv:(h + 1) * wkv]
+        q = jnp.concatenate([qn_ref[0, :, h * dn:(h + 1) * dn],
+                             qp_ref[0, :, h * LANE:(h + 1) * LANE]], axis=1)
+        k = jnp.concatenate([kv[:, :dn], kp], axis=1)
+        _online_softmax(_scores(q, k) * scale, kv[:, dn:], m_scr, l_scr,
+                        acc_scr, h)
+
+    @pl.when(ki == pl.num_programs(3) - 1)
+    def _finalize():
+        for h in range(heads):
+            o_ref[0, :, h * dv:(h + 1) * dv] = (
+                acc_scr[h] / l_scr[h, :, :1]).astype(o_ref.dtype)
+
+
+def _latent_heads(H: int, bk: int, width: int, dtype) -> int:
+    """Heads a grid step: the most, a power of two dividing ``H``, whose
+    ``[k_nope | v]`` block of ``bk`` keys stays within ``LATENT_KV_BLOCK``
+    (8 of 128 + 128 at bf16 and 2048 keys; float32 4).  One v5e, the
+    cell's core at 512 x 2048 blocks, ms a layer-example: 4 / 8 heads
+    49.0 / 48.2; 16 is over the VMEM limit (PERF.md section 5)."""
+    heads = 1
+    while H % (2 * heads) == 0 and 2 * heads * bk * width * \
+            jnp.dtype(dtype).itemsize <= LATENT_KV_BLOCK:
+        heads *= 2
+    return heads
+
+
+def _lane_pad(x):
+    return jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, LANE - x.shape[-1]),))
+
+
+def _latent_fwd(q_nope, q_pe, k_nope, k_pe, v, interpret: bool,
+                blocks=None):
+    """``blocks``: (query block, key block), the rule's if None."""
+    B, Lq, H, dn = q_nope.shape
+    Lk, dr, dv = k_nope.shape[1], q_pe.shape[3], v.shape[3]
+    bq, bk = blocks or _selected_blocks(Lq, Lk, PLAIN_TILE)
+    heads = _latent_heads(H, bk, dn + dv, q_nope.dtype)
+
+    def by_query(lanes):
+        return pl.BlockSpec((1, bq, heads * lanes),
+                            lambda b, h, qi, ki: (b, qi, h))
+
+    # [k_nope | v] a head: the mixer's projection lays them out so, and XLA
+    # folds the concatenation of its two slices away
+    kv = jnp.concatenate([k_nope, v], axis=-1)
+    out = pl.pallas_call(
+        functools.partial(_latent_kernel,
+                          scale=float(1.0 / np.sqrt(dn + dr))),
+        grid=(B, H // heads, Lq // bq, Lk // bk),
+        in_specs=[by_query(dn), by_query(LANE),
+                  pl.BlockSpec((1, bk, heads * (dn + dv)),
+                               lambda b, h, qi, ki: (b, ki, h)),
+                  pl.BlockSpec((1, bk, LANE),
+                               lambda b, h, qi, ki: (b, ki, 0))],
+        out_specs=by_query(dv),
+        out_shape=_out_struct((B, Lq, H * dv), q_nope.dtype, q_nope),
+        scratch_shapes=[_vmem((heads, bq, LANE)), _vmem((heads, bq, LANE)),
+                        _vmem((heads, bq, dv))],
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=SELECT_VMEM_BYTES),
+        interpret=interpret,
+    )(q_nope.reshape(B, Lq, H * dn),
+      _lane_pad(q_pe).reshape(B, Lq, H * LANE),
+      kv.reshape(B, Lk, H * (dn + dv)), _lane_pad(k_pe))
+    return out.reshape(B, Lq, H, dv)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _latent(q_nope, q_pe, k_nope, k_pe, v, interpret: bool):
+    return _latent_fwd(q_nope, q_pe, k_nope, k_pe, v, interpret)
+
+
+def _latent_vjp_fwd(q_nope, q_pe, k_nope, k_pe, v, interpret: bool):
+    return (_latent_fwd(q_nope, q_pe, k_nope, k_pe, v, interpret),
+            (q_nope, q_pe, k_nope, k_pe, v))
+
+
+def _latent_vjp_bwd(interpret, res, g):
+    from diff3d_tpu.ops.attention import _xla_latent
+
+    return jax.vjp(_xla_latent, *res)[1](g)
+
+
+_latent.defvjp(_latent_vjp_fwd, _latent_vjp_bwd)
+
+
+def latent_attention(q_nope: jnp.ndarray, q_pe: jnp.ndarray,
+                     k_nope: jnp.ndarray, k_pe: jnp.ndarray, v: jnp.ndarray,
+                     interpret: Optional[bool] = None) -> jnp.ndarray:
+    """Latent attention's core: head ``h`` of ``q = [q_nope | q_pe]``
+    attends all keys ``k_h = [k_nope_h | k_pe]`` — one rotary key ``k_pe
+    [B, Lk, Dr]`` shared by every head — with scores over ``(Dn +
+    Dr)^1/2``, and returns ``[B, Lq, H, Dv]``: the XLA expression on the
+    concatenated operands (``ops/attention._xla_latent``) with no ``[H,
+    Lq, Lk]`` array and no ``k_pe`` broadcast to ``H`` heads in HBM.
+
+    :func:`plain_attention`'s online softmax and block rule; a grid step
+    takes several heads (:func:`_latent_heads`) and one ``k_pe`` block for
+    all of them (:func:`_latent_kernel`).  ``q_pe`` and ``k_pe`` are
+    zero-padded to a lane tile: a 64-deep pass costs the MXU what a
+    128-deep one does.  Scores in float32 (the scale, not a power of two,
+    on the float32 scores), the probabilities cast to ``v``'s dtype for
+    ``PV``, float32 accumulation.  The gradient is the XLA expression's
+    on the saved operands: there is no backward kernel.
+    """
+    assert latent_supports(q_nope, q_pe, k_nope, k_pe, v), tuple(
+        (a.shape, a.dtype) for a in (q_nope, q_pe, k_nope, k_pe, v))
+    if interpret is None:
+        interpret = dispatch.interpret_default()
+    return _latent(q_nope, q_pe, k_nope, k_pe, v, bool(interpret))

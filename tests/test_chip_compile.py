@@ -63,12 +63,17 @@ it; neither cell's view program holds a chunk-batched decay tile or
 ``M`` (``[32, H, 256, 256]`` in either float dtype), and they hold 10 and
 50 custom calls.
 
-The latent-attention cell (``pangu_ultra_moe_tok128``) has no kernel
-site: its attention core has values narrower than queries and keys, and
-its experts' matrices and rows are past what ``expert_ffn``,
+The latent-attention cell (``pangu_ultra_moe_tok128``) has one kernel
+site, the latent core of each of its five layers: ``latent_attention``
+(op ``sdpa_latent``) on all 8192 queries of an example against its 8192
+keys, 128 heads of 128 + a 64-wide rotary part shared by all heads,
+values 128; compiled alone in both float dtypes, under the sampler's
+``vmap`` over two objects and with the XLA expression's VJP behind it.
+Its experts' matrices and rows are past what ``expert_ffn``,
 ``expert_rows`` and ``expert_combine`` take.  Its whole view program (five
-layers, 3.18 B parameters held in bf16) is compiled once, 17 s, and its
-memory held to the chip's with a gigabyte spare.
+layers, 3.18 B parameters held in bf16) is compiled once, 26 s: five
+custom calls, no score tile and no rotary key broadcast to the heads in
+HBM, and its memory held to the chip's with three gigabytes spare.
 
 Fixture rules (on-chip-measurement guide, section 2): the topology is
 described inside a module-scoped, non-autouse fixture of THIS file, which
@@ -87,6 +92,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from diff3d_tpu.ops.pallas_attention import (flash_attention,
+                                             latent_attention,
                                              plain_attention,
                                              selected_attention)
 from diff3d_tpu.ops.pallas_film import fused_groupnorm
@@ -637,12 +643,60 @@ def test_the_hybrid_moe_cells_view_program_compiles_and_fits_a_v5e(
     assert not re.search(DECAYS, text)
 
 
+# pangu_ultra_moe_tok128's latent core: (Lq, Lk, H, Dn, Dr, Dv)
+LATENT_SITE = (8192, 8192, 128, 128, 64, 128)
+
+
+def _latent_operands(one_chip, dtype, lead=(), Lq=None):
+    L, Lk, H, dn, dr, dv = LATENT_SITE
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(lead + shape, jnp.dtype(dtype),
+                                    sharding=one_chip)
+    Lq = Lq or L
+    return (sds(1, Lq, H, dn), sds(1, Lq, H, dr), sds(1, Lk, H, dn),
+            sds(1, Lk, dr), sds(1, Lk, H, dv))
+
+
+def _latent(*ops):
+    return latent_attention(*ops, interpret=False)
+
+
+@pytest.mark.parametrize("dtype", [BF16, F32])
+def test_latent_attention_forward_compiles_for_v5e(
+        one_chip, no_persistent_cache, dtype):
+    compiled = _compile_for_chip(_latent, *_latent_operands(one_chip, dtype))
+    # the score tile stays on chip, and the rotary key is not broadcast
+    text = compiled.as_text()
+    assert not re.search(r"f32\[[\d,]*128,\d+,8192\]", text)
+    assert not re.search(r"\[[\d,]*8192,128,192\]", text)
+
+
+def test_latent_attention_under_the_samplers_vmap_compiles_for_v5e(
+        one_chip, no_persistent_cache):
+    _compile_for_chip(jax.vmap(_latent),
+                      *_latent_operands(one_chip, BF16, lead=(2,)))
+
+
+def test_latent_attention_gradient_compiles_for_v5e(
+        one_chip, no_persistent_cache):
+    """A train step's pair on one query tile: the kernel forward, the XLA
+    expression's VJP backward (which does write the scores)."""
+    def loss(*ops):
+        return jnp.sum(_latent(*ops).astype(jnp.float32) ** 2)
+
+    compiled = _compile_for_chip(
+        jax.grad(loss, argnums=tuple(range(5))),
+        *_latent_operands(one_chip, BF16, Lq=512))
+    assert re.search(r"f32\[[\d,]*128,512,8192\]", compiled.as_text())
+
+
 def test_the_latent_attention_cells_view_program_compiles_and_fits_a_v5e(
         one_chip, no_persistent_cache, monkeypatch):
     """``Sampler._run_view_many`` of ``benchmark/configs/
     pangu_ultra_moe_tok128.json`` on one object, as the cell calls it,
-    resolved as a TPU process resolves it: every op XLA's, the score
-    tile of one query tile and no more."""
+    resolved as a TPU process resolves it: the latent core of each layer
+    on its kernel, every other op XLA's."""
     import json
     import os
 
@@ -676,13 +730,14 @@ def test_the_latent_attention_cells_view_program_compiles_and_fits_a_v5e(
     assert 2 * 3_179_865_612 <= mem.argument_size_in_bytes < 6.37e9
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
              + mem.generated_code_size_in_bytes)
-    # 15.85 GB of the chip's 16.91 at q_chunk 256 (16.93 at 512: over)
-    assert total < 15.9e9, mem
+    # 13.82 GB of the chip's 16.91 (temporaries 7.41) since the latent
+    # core's kernel; 15.85 (9.46) with XLA's core at q_chunk 256
+    assert total < 13.9e9, mem
+    # the five latent cores on their kernel: no float32 score tile of any
+    # query tile, no rotary key broadcast to the 128 heads in HBM (it was
+    # fused into the 192-wide keys), no reduce-window
     text = compiled.as_text()
-    assert "tpu_custom_call" not in text
-    assert re.search(r"f32\[128,256,8192\]", text)
-    assert not re.search(r"f32\[[\d,]*128,512,8192\]", text)
-    # the softmax's row max as a reduce, not as a reduce-window over the
-    # whole score tile (15x slower on the chip)
+    assert text.count("tpu_custom_call") == 5
+    assert not re.search(r"f32\[[\d,]*128,\d+,8192\]", text)
+    assert not re.search(r"bf16\[[\d,]*8192,128,192\]", text)
     assert "reduce-window" not in text
-    assert not re.search(r"f32\[[\d,]*128,8192,8192\]", text)

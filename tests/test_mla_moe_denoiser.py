@@ -296,7 +296,9 @@ def test_counters_once_per_traced_site(tiny, monkeypatch):
     8 / 256 at the cell's widths, where every expert op is XLA's on a TPU
     process too: one expert's matrices are past ``expert_ffn``'s VMEM
     budget, and a row of 7680 is no whole number of ``(8, 128)`` tiles
-    for the row kernels' DMAs)."""
+    for the row kernels' DMAs).  The latent core is ``sdpa_latent``'s,
+    never plain ``sdpa``'s: its XLA expression here, its kernel on a TPU
+    process at the cell's widths."""
     def traced(cfg, model, params, B):
         before = RECORDER.counters()
         batch = jax.eval_shape(lambda: make_batch(
@@ -312,7 +314,7 @@ def test_counters_once_per_traced_site(tiny, monkeypatch):
                  "experts.xla": 2, "experts.rows.xla": 2,
                  "experts.combine.xla": 2, "attention.latent": 3,
                  "router.sigmoid": 2, "residual.post_norm": 6,
-                 "sdpa.plain.xla": 3}
+                 "sdpa.latent.xla": 3}
     with open(os.path.join(CONFIGS, "pangu_ultra_moe_tok128.json")) as f:
         cfg = adapters_mla_moe.build_config(json.load(f))
     from diff3d_tpu.train.trainer import init_params
@@ -326,7 +328,7 @@ def test_counters_once_per_traced_site(tiny, monkeypatch):
                  "experts.xla": 4, "experts.rows.xla": 4,
                  "experts.combine.xla": 4, "attention.latent": 5,
                  "router.sigmoid": 4, "residual.post_norm": 10,
-                 "sdpa.plain.xla": 5}
+                 "sdpa.latent.pallas": 5}
 
 
 # ------------------------------------------------------ sampler and CLIs
