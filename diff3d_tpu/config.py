@@ -122,6 +122,18 @@ class TokenModelConfig:
     on each half's output before its residual add.  ``param_dtype`` is
     the dtype the parameters are made and held in.
 
+    A ``qwen3_next`` block (``full_attention_interval > 0`` and an empty
+    pattern): layer ``i`` is ``"full_attention"`` where ``(i + 1) %
+    full_attention_interval == 0``, else ``"linear_attention"``, a Gated
+    DeltaNet mixer (``linear_*``: key and value heads, their widths, the
+    conv's taps; ``delta_chunk`` tokens a chunk of its scan).
+    ``"full_attention"`` is the ``"attention"`` layer with an output gate
+    from a second half of ``W_q``, per-head RMSNorms on ``q`` and ``k`` and
+    the rotary turn on ``partial_rotary_factor`` of each head's dims.
+    Every RMSNorm of such a block but the mixer's gated one scales by
+    ``1 + w`` (``w`` made 0), and its shared expert's output is times
+    ``sigmoid(w_g^T u)`` (:attr:`qwen3_next`).
+
     ``experts_held`` is ``(first, count)``: the router scores all
     ``num_experts``, the layer holds the weights of experts ``first ..
     first + count - 1`` and computes their part of the result (all of
@@ -177,11 +189,21 @@ class TokenModelConfig:
     scoring_func: str = "softmax"  # the router's: 'softmax' | 'sigmoid'
     routed_scaling_factor: float = 1.0
     sandwich_norm: bool = False
+    # Gated DeltaNet beside gated full attention (0: none).
+    full_attention_interval: int = 0
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 4
+    partial_rotary_factor: float = 1.0
     # Tile sizes, not semantics: queries of one example attended at a
-    # time; tokens routed at a time; rows of one expert's matmul.
+    # time; tokens routed at a time; rows of one expert's matmul; tokens
+    # of one chunk of the delta rule's scan.
     q_chunk: int = 512
     expert_token_chunk: int = 8192
     expert_block: int = 256
+    delta_chunk: int = 64
     emb_ch: int = 256              # width of the logSNR sinusoid
     logsnr_clip: float = 20.0      # as ModelConfig.logsnr_clip
     dtype: str = "bfloat16"        # compute dtype
@@ -194,9 +216,29 @@ class TokenModelConfig:
     @property
     def mixers(self) -> Tuple[str, ...]:
         """The mixer kind of every layer, the empty pattern filled in."""
+        if self.layer_types:
+            return tuple(self.layer_types)
+        if self.full_attention_interval:
+            return tuple(
+                "full_attention" if (i + 1) % self.full_attention_interval
+                == 0 else "linear_attention"
+                for i in range(self.num_hidden_layers))
         default = ("latent_attention" if self.kv_lora_rank
                    else "sparse_attention")
-        return tuple(self.layer_types) or (default,) * self.num_hidden_layers
+        return (default,) * self.num_hidden_layers
+
+    @property
+    def qwen3_next(self) -> bool:
+        """Whether the stack is a ``qwen3_next`` block (it has a
+        ``linear_attention`` or ``full_attention`` layer): its norms are
+        zero-centred and its shared expert gated."""
+        return bool({"linear_attention", "full_attention"}
+                    & set(self.mixers))
+
+    @property
+    def rotary_dim(self) -> int:
+        """Dims of a ``full_attention`` head the rotary turn takes."""
+        return int(self.head_dim * self.partial_rotary_factor)
 
     def dense_layer(self, i: int) -> bool:
         """Whether layer ``i`` is one of the leading dense layers."""
@@ -213,23 +255,28 @@ class TokenModelConfig:
                 f"divide num_attention_heads={self.num_attention_heads}")
         kinds = self.mixers
         unknown = set(kinds) - {"sparse_attention", "attention", "mamba",
-                                "latent_attention"}
+                                "latent_attention", "linear_attention",
+                                "full_attention"}
         if unknown or len(kinds) != self.num_hidden_layers:
             raise ValueError(
                 f"layer_types={kinds} must name 'sparse_attention', "
-                f"'attention', 'mamba' or 'latent_attention' for each of "
-                f"the {self.num_hidden_layers} layers")
+                f"'attention', 'mamba', 'latent_attention', "
+                f"'linear_attention' or 'full_attention' for each of the "
+                f"{self.num_hidden_layers} layers")
         if "latent_attention" in kinds:
             self._validate_latent_attention()
+        if {"linear_attention", "full_attention"} & set(kinds):
+            self._validate_gated_deltanet()
         if self.param_dtype not in ("float32", self.dtype):
             raise ValueError(
                 f"param_dtype={self.param_dtype!r} must be 'float32' or "
                 f"the compute dtype {self.dtype!r}")
-        if self.param_dtype != "float32" and set(kinds) != {
-                "latent_attention"}:
+        if self.param_dtype != "float32" and set(kinds) - {
+                "latent_attention", "linear_attention", "full_attention"}:
             raise ValueError(
                 f"param_dtype={self.param_dtype!r}: only a stack of "
-                "'latent_attention' layers makes its parameters in it (the "
+                "'latent_attention', 'linear_attention' and "
+                "'full_attention' layers makes its parameters in it (the "
                 "other mixers make theirs in float32)")
         if self.first_k_dense_replace:
             if not (self.num_experts and self.dense_intermediate_size > 0
@@ -263,7 +310,7 @@ class TokenModelConfig:
             self._validate_experts()
         if "sparse_attention" in kinds:
             self._validate_sparse_attention()
-        if (set(kinds) - {"mamba"}
+        if (set(kinds) - {"mamba", "linear_attention"}
                 and self.tokens % min(self.q_chunk, self.tokens)):
             raise ValueError(
                 f"q_chunk={self.q_chunk} must divide the {self.tokens} "
@@ -299,6 +346,22 @@ class TokenModelConfig:
         if self.scoring_func == "softmax" and self.routed_scaling_factor != 1:
             raise ValueError("routed_scaling_factor scales sigmoid gates "
                              "only: the softmax's top-k sum to one")
+
+    def _validate_gated_deltanet(self) -> None:
+        heads = (self.linear_num_key_heads, self.linear_num_value_heads,
+                 self.linear_key_head_dim, self.linear_value_head_dim,
+                 self.linear_conv_kernel_dim, self.delta_chunk)
+        if min(heads) < 1 or (self.linear_num_value_heads
+                              % self.linear_num_key_heads):
+            raise ValueError(
+                "Gated DeltaNet needs linear_* heads, widths, conv taps and "
+                "delta_chunk >= 1, and key heads dividing value heads "
+                f"(have {heads})")
+        r = self.rotary_dim
+        if r < 2 or r % 2 or r > self.head_dim:
+            raise ValueError(
+                f"partial_rotary_factor={self.partial_rotary_factor} turns "
+                f"{r} of head_dim={self.head_dim}: must be even and >= 2")
 
     def _validate_latent_attention(self) -> None:
         ranks = (self.q_lora_rank, self.kv_lora_rank, self.qk_nope_head_dim,
@@ -661,6 +724,34 @@ def mla_moe_test_config(imgsize: int = 16) -> Config:
     )
 
 
+def gdn_moe_test_config(imgsize: int = 16) -> Config:
+    """Tiny token-denoiser config on a ``qwen3_next`` block: one period,
+    three Gated DeltaNet layers (2 key and 4 value heads of 16, chunks of
+    24: five whole and a part of a sixth of the 128 tokens) and one gated
+    attention layer (4 / 2 heads of 32, the rotary turn on 8 dims), every
+    layer's experts 8 of 16 held, top-4, beside a gated shared expert,
+    zero-centred norms, hidden 64."""
+    tokens = 2 * (imgsize // 2) ** 2
+    return Config(
+        model=TokenModelConfig(
+            H=imgsize, W=imgsize, hidden_size=64, num_hidden_layers=4,
+            full_attention_interval=4, num_attention_heads=4,
+            num_key_value_heads=2, head_dim=32, partial_rotary_factor=0.25,
+            rope_theta=10_000_000, rms_norm_eps=1e-6,
+            linear_num_key_heads=2, linear_num_value_heads=4,
+            linear_key_head_dim=16, linear_value_head_dim=16,
+            linear_conv_kernel_dim=4, num_experts=16,
+            num_experts_per_tok=4, moe_intermediate_size=32,
+            experts_held=(0, 8), shared_intermediate_size=32,
+            q_chunk=tokens // 2, expert_token_chunk=2 * tokens,
+            expert_block=16, delta_chunk=24, emb_ch=32, dtype="float32"),
+        train=TrainConfig(global_batch=8, warmup_examples=1024,
+                          max_steps=4, ckpt_every=2, log_every=1),
+        data=DataConfig(imgsize=imgsize),
+        diffusion=DiffusionConfig(timesteps=4),
+    )
+
+
 def test_config(imgsize: int = 16, ch: int = 8,
                 shallow: bool = False) -> Config:
     """Tiny config for unit tests / CPU-mesh dry runs.
@@ -690,7 +781,8 @@ NAMED_CONFIGS = {"srn64": "srn64_config", "srn128": "srn128_config",
                  "test": "test_config", "token_test": "token_test_config",
                  "hybrid_test": "hybrid_test_config",
                  "hybrid_moe_test": "hybrid_moe_test_config",
-                 "mla_moe_test": "mla_moe_test_config"}
+                 "mla_moe_test": "mla_moe_test_config",
+                 "gdn_moe_test": "gdn_moe_test_config"}
 
 
 def named_config(name: str) -> Config:

@@ -40,32 +40,43 @@ from diff3d_tpu.ops.ssd import ssd
 from diff3d_tpu.utils.profiling import scope
 
 
-def causal_conv(x: jnp.ndarray, taps: jnp.ndarray, bias: jnp.ndarray
-                ) -> jnp.ndarray:
+def causal_conv(x: jnp.ndarray, taps: jnp.ndarray,
+                bias: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Depthwise causal convolution of ``x [L, C]`` by ``taps [K, C]``
-    (the last tap on the token itself) plus ``bias [C]``, float32."""
+    (the last tap on the token itself) plus ``bias [C]`` where there is
+    one, float32."""
     L, K = x.shape[0], taps.shape[0]
     xp = jnp.pad(x.astype(jnp.float32), ((K - 1, 0), (0, 0)))
-    return bias + sum(taps[j] * xp[j:j + L] for j in range(K))
+    out = sum(taps[j] * xp[j:j + L] for j in range(K))
+    return out if bias is None else bias + out
 
 
 def _a_log_init(key, shape):
     return jnp.log(jnp.arange(1, shape[0] + 1, dtype=jnp.float32))
 
 
-def _dt_bias_init(key, shape):
+def _dt_bias_init(key, shape, dtype=jnp.float32):
     """The inverse softplus of a log-uniform step in [1e-3, 1e-1]."""
     dt = jnp.exp(jax.random.uniform(key, shape) * np.log(100.0)
                  + np.log(1e-3))
-    return dt + jnp.log(-jnp.expm1(-dt))
+    return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
 
 
 class ConvTaps(nn.Module):
+    """The taps ``[taps, channels]`` and the bias (``None`` without
+    ``bias``)."""
+
+    bias: bool = True
+    param_dtype: jnp.dtype = jnp.float32
+
     @nn.compact
     def __call__(self, taps: int, channels: int):
-        return (self.param("kernel", nn.initializers.normal(taps ** -0.5),
-                           (taps, channels)),
-                self.param("bias", nn.initializers.zeros, (channels,)))
+        kernel = self.param("kernel", nn.initializers.normal(taps ** -0.5),
+                            (taps, channels), self.param_dtype)
+        if not self.bias:
+            return kernel, None
+        return kernel, self.param("bias", nn.initializers.zeros,
+                                  (channels,), self.param_dtype)
 
 
 class Mamba2Mixer(nn.Module):

@@ -128,10 +128,19 @@ class Kernel(nn.Module):
 
 
 class Scale(nn.Module):
+    """A norm's weight ``w``, made 1.  ``centred``: the zero-centred form
+    of a ``qwen3_next`` block, ``w`` made 0 and the norm scaling by ``1 +
+    w`` (float32), which is what this returns."""
+
     param_dtype: jnp.dtype = jnp.float32
+    centred: bool = False
 
     @nn.compact
     def __call__(self, n: int) -> jnp.ndarray:
+        if self.centred:
+            w = self.param("scale", nn.initializers.zeros, (n,),
+                           self.param_dtype)
+            return 1.0 + w.astype(jnp.float32)
         return self.param("scale", nn.initializers.ones, (n,),
                           self.param_dtype)
 

@@ -1,9 +1,11 @@
 """A token denoiser: both frames as one sequence of patches through
 decoder layers, each a sequence mixer (selected-key attention, attention
-over all keys, or a Mamba-2 state-space mixer: the config's
-``layer_types``) and a feed-forward (routed experts, a dense gated MLP,
-or both on one normed input under one residual add: routed experts
-beside a shared expert).
+over all keys, multi-head latent attention, a Mamba-2 state-space mixer,
+a Gated DeltaNet mixer, or gated attention over all keys: the config's
+``layer_types``, or the pattern its ``full_attention_interval`` makes)
+and a feed-forward (routed experts, a dense gated MLP, or both on one
+normed input under one residual add: routed experts beside a shared
+expert, which may carry a sigmoid gate of its own).
 
 The second kind of denoiser beside the X-UNet, on the same forward
 contract (docs/DESIGN.md §1): batch dict with ``x [B,H,W,3]``,
@@ -24,11 +26,13 @@ computed at ``G`` rows and meet the examples in one broadcast add.
            sinusoid; the sum times ``embedding_multiplier``.
   layers   pre-norm, ``h += r mixer(norm(h)); h += r ffn(norm(h))`` with
            ``r = residual_multiplier`` (:mod:`.sparse_attention`,
-           :mod:`.token_layers`, :mod:`.mamba`, :mod:`.latent_attention`;
-           :mod:`.moe`); with both feed-forwards, ``ffn(u) = routed(u) +
-           mlp(u)``; with ``sandwich_norm``, each half's output normed
-           again before its add; the first ``first_k_dense_replace``
-           layers with a dense MLP for ``ffn``.
+           :mod:`.token_layers`, :mod:`.mamba`, :mod:`.latent_attention`,
+           :mod:`.gated_deltanet`; :mod:`.moe`); with both feed-forwards,
+           ``ffn(u) = routed(u) + mlp(u)``; with ``sandwich_norm``, each
+           half's output normed again before its add; the first
+           ``first_k_dense_replace`` layers with a dense MLP for ``ffn``;
+           in a ``qwen3_next`` block, every norm but the Gated DeltaNet
+           mixer's gated one (and the final norm) scaling by ``1 + w``.
   output   RMSNorm, a linear head to ``patch^2 * 3`` values per target
            token over ``logits_scaling``, un-patchified.
 
@@ -46,6 +50,7 @@ from diff3d_tpu.geometry import (pinhole_rays_cam, pinhole_rays_world,
                                  posenc_ddpm, posenc_nerf)
 from diff3d_tpu.models.conditioning import (DIR_DEG, POS_DEG,
                                             conditioning_rows)
+from diff3d_tpu.models.gated_deltanet import GatedDeltaNet
 from diff3d_tpu.models.latent_attention import LatentAttention
 from diff3d_tpu.models.mamba import Mamba2Mixer
 from diff3d_tpu.models.moe import RoutedExperts, rms_norm
@@ -95,14 +100,15 @@ class DecoderLayer(nn.Module):
     @property
     def halves(self):
         """The names of the layer's two halves in its parameter tree."""
-        return ("mamba" if self.kind == "mamba" else "attn",
+        mixer = {"mamba": "mamba", "linear_attention": "linear_attn"}
+        return (mixer.get(self.kind, "attn"),
                 "moe" if self.cfg.num_experts and not self.dense else "mlp")
 
     def setup(self):
         cfg = self.cfg
         pd = jnp.dtype(cfg.param_dtype)
         for half in self.halves:
-            setattr(self, half + "_norm", Scale(pd))
+            setattr(self, half + "_norm", Scale(pd, cfg.qwen3_next))
             if cfg.sandwich_norm:
                 setattr(self, half + "_post_norm", Scale(pd))
         common = dict(eps=cfg.rms_norm_eps,
@@ -114,12 +120,24 @@ class DecoderLayer(nn.Module):
                 d_head=cfg.mamba_d_head, d_state=cfg.mamba_d_state,
                 d_conv=cfg.mamba_d_conv, chunk=cfg.mamba_chunk_size,
                 **common)
-        elif self.kind == "attention":
+        elif self.kind == "linear_attention":
+            self.linear_attn = GatedDeltaNet(
+                hidden=cfg.hidden_size, key_heads=cfg.linear_num_key_heads,
+                value_heads=cfg.linear_num_value_heads,
+                key_dim=cfg.linear_key_head_dim,
+                value_dim=cfg.linear_value_head_dim,
+                conv=cfg.linear_conv_kernel_dim, chunk=cfg.delta_chunk,
+                param_dtype=pd, **common)
+        elif self.kind in ("attention", "full_attention"):
+            gated = (dict(param_dtype=pd, gated=True,
+                          rotary_dim=cfg.rotary_dim,
+                          rope_theta=cfg.rope_theta)
+                     if self.kind == "full_attention" else {})
             self.attn = FullAttention(
                 hidden=cfg.hidden_size, num_heads=cfg.num_attention_heads,
                 num_kv_heads=cfg.num_key_value_heads,
                 head_dim=cfg.head_dim, q_chunk=cfg.q_chunk,
-                scale=cfg.attention_multiplier, **common)
+                scale=cfg.attention_multiplier, **gated, **common)
         elif self.kind == "latent_attention":
             self.attn = LatentAttention(
                 hidden=cfg.hidden_size, num_heads=cfg.num_attention_heads,
@@ -152,7 +170,10 @@ class DecoderLayer(nn.Module):
                  else cfg.shared_intermediate_size)
         if width:
             self.mlp = GatedMLP(hidden=cfg.hidden_size, width=width,
-                                param_dtype=pd, **common)
+                                param_dtype=pd, gated=(
+                                    cfg.qwen3_next
+                                    and self.halves[1] == "moe"),
+                                **common)
 
     def _norms(self, half: str, D: int):
         """The half's norm scale and its post-norm's (``None`` without
@@ -193,7 +214,7 @@ class TokenDenoiser(nn.Module):
         self.logsnr_mlp_1 = nn.Dense(D, dtype=dtype, param_dtype=pd)
         self.layers = [DecoderLayer(cfg, kind, cfg.dense_layer(i))
                        for i, kind in enumerate(cfg.mixers)]
-        self.final_norm = Scale(pd)
+        self.final_norm = Scale(pd, cfg.qwen3_next)
         self.head = nn.Dense(cfg.patch * cfg.patch * 3, dtype=dtype,
                              param_dtype=pd,
                              kernel_init=nn.initializers.zeros)

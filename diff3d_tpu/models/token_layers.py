@@ -20,7 +20,19 @@ size of the whole call.
              are ``[Hq, q_chunk, L]``, not ``[Hq, L, L]``; on a TPU
              process the tile is one call of the Pallas kernel
              ``plain_attention``, which writes no scores at all.
-  MLP        ``[a | b] = W_1 u``, ``W_2 (silu(a) * b)``, no bias.
+             The ``full_attention`` layer of a ``qwen3_next`` block is
+             the same layer with three more parts (``gated``): ``[q_h |
+             gate_h] = (W_q u)_h``, each ``head_dim`` wide; per-head
+             zero-centred RMSNorms on ``q`` and ``k`` (``1 + w``); the
+             rotary turn on the first ``rotary_dim`` dims of every
+             ``q`` and ``k`` head by the token's index in the sequence
+             (pair ``i`` is ``(x_i, x_{i + rotary_dim / 2})``), the rest
+             passed through; the heads' outputs times ``sigmoid(gate)``
+             before ``W_o``.  Each traced site with the gate adds 1 to
+             the recorder's ``attention.gated``.
+  MLP        ``[a | b] = W_1 u``, ``W_2 (silu(a) * b)``, no bias; as a
+             ``qwen3_next`` shared expert (``gated``), times
+             ``sigmoid(w_g^T u)`` per token.
 """
 
 from __future__ import annotations
@@ -31,10 +43,11 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from diff3d_tpu.models.moe import residual_half
-from diff3d_tpu.models.sparse_attention import Kernel
+from diff3d_tpu.models.moe import residual_half, rms_norm
+from diff3d_tpu.models.sparse_attention import (Kernel, Scale, mrope_tables,
+                                                rotate)
 from diff3d_tpu.ops.attention import sdpa
-from diff3d_tpu.utils.profiling import scope
+from diff3d_tpu.utils.profiling import count, scope
 
 
 def dense(u: jnp.ndarray, kernel: jnp.ndarray, dtype,
@@ -55,12 +68,37 @@ class FullAttention(nn.Module):
     eps: float = 1e-6
     residual: float = 1.0
     dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32
+    # a qwen3_next block's full_attention layer
+    gated: bool = False
+    rotary_dim: int = 0
+    rope_theta: float = 1e7
 
     def setup(self):
-        self.q_proj = Kernel(self.num_heads * self.head_dim)
-        self.k_proj = Kernel(self.num_kv_heads * self.head_dim)
-        self.v_proj = Kernel(self.num_kv_heads * self.head_dim)
-        self.o_proj = Kernel(self.hidden)
+        pd, d = self.param_dtype, self.head_dim
+        self.q_proj = Kernel(self.num_heads * d * (2 if self.gated else 1),
+                             pd)
+        self.k_proj = Kernel(self.num_kv_heads * d, pd)
+        self.v_proj = Kernel(self.num_kv_heads * d, pd)
+        if self.gated:
+            self.q_norm = Scale(pd, centred=True)
+            self.k_norm = Scale(pd, centred=True)
+        self.o_proj = Kernel(self.hidden, pd)
+
+    def _gated_parts(self, q, k, q_norm, k_norm):
+        """``(W_q u)`` split into ``q`` and the gate, both ``[L, Hq, d]``,
+        and ``q``, ``k`` normed and turned."""
+        L, d, r = k.shape[0], self.head_dim, self.rotary_dim
+        q, gate = jnp.split(q.reshape(L, self.num_heads, 2 * d), 2, axis=-1)
+        q = rms_norm(q, q_norm, self.eps)
+        k = rms_norm(k, k_norm, self.eps)
+        with scope("rope"):
+            # one axis: token t turned by t alone
+            cos, sin = mrope_tables(L, 1, 1, r, self.rope_theta,
+                                    (r // 2, 0, 0))
+            q, k = (jnp.concatenate([rotate(x[..., :r], cos, sin),
+                                     x[..., r:]], axis=-1) for x in (q, k))
+        return q, gate, k
 
     def __call__(self, h: jnp.ndarray, norm_scale: jnp.ndarray,
                  post_scale: Optional[jnp.ndarray] = None) -> jnp.ndarray:
@@ -71,12 +109,19 @@ class FullAttention(nn.Module):
                 f"q_chunk={self.q_chunk} must divide the {L} tokens")
         Wq, Wk, Wv = self.q_proj(D), self.k_proj(D), self.v_proj(D)
         Wo = self.o_proj(self.num_heads * d)
+        if self.gated:
+            count("attention.gated")
+            norms = self.q_norm(d), self.k_norm(d)
 
         def attend(u):
             with scope("attention"):
-                q = dense(u, Wq, self.dtype).reshape(L, self.num_heads, d)
+                q = dense(u, Wq, self.dtype)
+                if not self.gated:
+                    q = q.reshape(L, self.num_heads, d)
                 k = dense(u, Wk, self.dtype).reshape(L, self.num_kv_heads, d)
                 v = dense(u, Wv, self.dtype).reshape(L, self.num_kv_heads, d)
+                if self.gated:
+                    q, gate, k = self._gated_parts(q, k, *norms)
                 if self.scale is not None:
                     q = q * jnp.asarray(self.scale * d ** 0.5, q.dtype)
 
@@ -85,6 +130,10 @@ class FullAttention(nn.Module):
                     return sdpa(qc[None], k[None], v[None])[0]
 
                 out = jax.lax.map(one_tile, jnp.arange(L // C))
+                if self.gated:
+                    out = (out.reshape(L, self.num_heads, d).astype(
+                        jnp.float32) * jax.nn.sigmoid(
+                            gate.astype(jnp.float32))).astype(self.dtype)
                 return dense(out.reshape(L, self.num_heads * d), Wo,
                              self.dtype, jnp.float32)
 
@@ -100,24 +149,37 @@ class GatedMLP(nn.Module):
     residual: float = 1.0
     dtype: jnp.dtype = jnp.float32
     param_dtype: jnp.dtype = jnp.float32
+    gated: bool = False
 
     def setup(self):
         self.w1 = Kernel(2 * self.width, self.param_dtype)
         self.w2 = Kernel(self.hidden, self.param_dtype)
+        if self.gated:
+            self.gate = Kernel(1, self.param_dtype)
 
     def branch(self):
         """``u [T, D] -> W_2 (silu(a) * b)`` float32, a plain function of
         the layer's kernels: what a map's body may call (no module can
         be), here over examples and, where the MLP is a shared expert
         beside routed ones, in their chunk map
-        (:class:`~diff3d_tpu.models.moe.RoutedExperts` ``beside``)."""
+        (:class:`~diff3d_tpu.models.moe.RoutedExperts` ``beside``).
+        ``gated``: times ``sigmoid(w_g^T u)``, one number a token; each
+        traced branch with the gate adds 1 to the recorder's
+        ``experts.shared_gate``."""
         W1, W2 = self.w1(self.hidden), self.w2(self.width)
+        Wg = self.gate(self.hidden) if self.gated else None
+        if self.gated:
+            count("experts.shared_gate")
 
         def mlp(u):
             with scope("mlp"):
                 a, b = jnp.split(dense(u, W1, self.dtype), 2, axis=-1)
                 g = nn.silu(a.astype(jnp.float32)) * b.astype(jnp.float32)
-                return dense(g, W2, self.dtype, jnp.float32)
+                y = dense(g, W2, self.dtype, jnp.float32)
+                if Wg is not None:
+                    y = y * jax.nn.sigmoid(dense(u, Wg, self.dtype,
+                                                 jnp.float32))
+                return y
 
         return mlp
 

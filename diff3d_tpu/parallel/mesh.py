@@ -227,7 +227,14 @@ def tp_param_sharding(mesh: Mesh, path, shape: Sequence[int],
       * every leaf of a state-space mixer (``mamba/...``) — replicated,
         explicitly: the fused ``[z | xBC | dt]`` projection does not
         split at one column boundary, and the heads' state, conv and
-        gated norm would have to follow it (ROADMAP reach B3).
+        gated norm would have to follow it (ROADMAP reach B3).  The same
+        for a Gated DeltaNet mixer (``linear_attn/...``), whose
+        ``[q | k | v | z]`` projection is four runs of columns that a
+        split by heads would have to regroup by key head first.  A gated
+        attention layer's ``q_proj`` (``[q_h | gate_h]`` by head) is
+        column-parallel as above, by whole heads where they divide; its
+        per-head norms and the shared expert's one-column ``gate`` are
+        replicated.
       * everything else (norm scales, learned pose embeddings, tiny
         leaves) — replicated.
 
@@ -243,8 +250,8 @@ def tp_param_sharding(mesh: Mesh, path, shape: Sequence[int],
         return len(shape) > dim and shape[dim] % tp == 0 and shape[dim] >= tp
 
     is_kernel = names and names[-1] == "kernel"
-    if "mamba" in names or any(n in ("q_a_proj", "kv_a_proj")
-                               for n in names):
+    if "mamba" in names or "linear_attn" in names or any(
+            n in ("q_a_proj", "kv_a_proj") for n in names):
         pass                               # whole on every device
     elif tp > 1 and names and names[-1] in ("w_gate", "w_up", "w_down"):
         if len(shape) == 3 and shardable(0):

@@ -68,7 +68,11 @@ SCOPES = ("conv", "film", "groupnorm", "attention", "conditioning",
           # latent_attention.py: W_qa, its norm, W_qb; W_kva, its norm,
           # W_kvb); its rotary turn is "rope", its core and W_o
           # "attention"
-          "mla_q", "mla_kv")
+          "mla_q", "mla_kv",
+          # a Gated DeltaNet mixer's four (models/gated_deltanet.py:
+          # W_qkvz, W_ba and W_out; the causal conv; the l2 norms, beta,
+          # the decays and the delta rule's scan; the gated norm)
+          "delta_proj", "delta_conv", "delta_scan", "delta_gate")
 
 
 def scope(tag: str):

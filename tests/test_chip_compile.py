@@ -741,3 +741,55 @@ def test_the_latent_attention_cells_view_program_compiles_and_fits_a_v5e(
     assert not re.search(r"f32\[[\d,]*128,\d+,8192\]", text)
     assert not re.search(r"bf16\[[\d,]*8192,128,192\]", text)
     assert "reduce-window" not in text
+
+
+def test_the_gated_deltanet_cells_view_program_compiles_and_fits_a_v5e(
+        one_chip, no_persistent_cache, monkeypatch):
+    """``Sampler._run_view_many`` of ``benchmark/configs/
+    qwen3_next_tok128.json`` on one object, as the cell calls it,
+    resolved as a TPU process resolves it: the gated attention's query
+    tiles on ``plain_attention`` at head dim 256, the four expert layers'
+    rows and blocks on their kernels (256 of 512 experts held), the three
+    delta-rule scans XLA's."""
+    import json
+    import os
+
+    from benchmark import adapters_gdn_moe
+    from diff3d_tpu.models import build_model
+    from diff3d_tpu.ops import dispatch
+    from diff3d_tpu.sampling import Sampler
+    from diff3d_tpu.train.trainer import init_params
+
+    monkeypatch.setattr(dispatch, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(dispatch, "interpret_default", lambda: False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "qwen3_next_tok128.json")) as f:
+        cfg = adapters_gdn_moe.build_config(json.load(f))
+    model = build_model(cfg)
+    params = jax.eval_shape(
+        lambda: init_params(model, cfg, jax.random.PRNGKey(0)))
+    assert {str(x.dtype) for x in jax.tree.leaves(params)} == {BF16}
+    sampler = Sampler(model, params, cfg, sampler_kind="ddim", steps=8)
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=one_chip)
+    H, B = cfg.model.H, len(cfg.diffusion.guidance_weights)
+    compiled = sampler._run_view_many.lower(
+        jax.tree.map(lambda x: sds(x.shape, x.dtype), params),
+        sds((1, 2, B, H, H, 3), F32), sds((1, 2, 3, 3), F32),
+        sds((1, 2, 3), F32), sds((1,), "int32"), sds((1, 3, 3), F32),
+        sds((1, 2), "uint32")).compile()
+    mem = compiled.memory_analysis()
+    assert 2 * 3_372_403_788 <= mem.argument_size_in_bytes < 6.75e9
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.generated_code_size_in_bytes)
+    # 10.60 GB of the chip's 16.91 at expert chunks of 16384 tokens in
+    # blocks of 128 rows (10.91 at blocks of 256, 12.32 at chunks of
+    # 32768), temporaries 3.82
+    assert total < 11.0e9, mem
+    # four expert sites (rows in, blocks, re-tiling, gated sum back) and
+    # the attention site on their kernels; no score array of a query tile
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 17
+    assert not re.search(r"f32\[[\d,]*16,512,8192\]", text)

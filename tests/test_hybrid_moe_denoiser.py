@@ -457,6 +457,12 @@ HYBRID_TEXT = "cfd14b67f3ba5bccf4edd754aa6c8772558b2c0eef92760ee36038b638b31275"
 #: registry: a CPU process resolves both to the expressions it had
 MOE_TREE = "89f38fcfa91661377fe93c69a9e72023020d673ba996c36796bee200ff78751c"
 MOE_TEXT = "736d90cd019782bd217b9378e8daa7779467ab9b97f7c71cf6473f36a258eb08"
+#: the same of ``mla_moe_test`` (latent attention under sandwich norms),
+#: taken from commit eeff66a before the qwen3_next block's edits of the
+#: shared layers (``Scale``, ``FullAttention``, ``GatedMLP``,
+#: ``causal_conv``, the config's pattern)
+MLA_TREE = "e9dedecaa4a19b5698f1e133f9f3c8d12f797e2fb96ec267853d8688c89a8c1a"
+MLA_TEXT = "d7a10e46dbff378d1f14bab74d4a864b1cbeb12e49ae1fc584faf104df50d731"
 
 
 def digests(cfg):
@@ -473,14 +479,15 @@ def digests(cfg):
 
 
 @pytest.mark.parametrize("preset", ["token_test", "hybrid_test",
-                                    "hybrid_moe_test"])
+                                    "hybrid_moe_test", "mla_moe_test"])
 def test_a_presets_tree_and_lowered_text_are_the_parents(preset):
     from diff3d_tpu.config import named_config
     from test_hybrid_denoiser import KEYE_TEXT, KEYE_TREE
 
     want = {"token_test": (KEYE_TREE, KEYE_TEXT),
             "hybrid_test": (HYBRID_TREE, HYBRID_TEXT),
-            "hybrid_moe_test": (MOE_TREE, MOE_TEXT)}[preset]
+            "hybrid_moe_test": (MOE_TREE, MOE_TEXT),
+            "mla_moe_test": (MLA_TREE, MLA_TEXT)}[preset]
     assert digests(named_config(preset)) == want
 
 

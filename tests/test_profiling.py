@@ -394,6 +394,8 @@ TOKEN_SCOPES = {"patch_embed", "moe_router", "experts", "indexer",
 HYBRID_SCOPES = {"ssm_proj", "ssm_conv", "ssm_scan", "ssm_gate", "mlp"}
 #: ... and those of latent attention (models/latent_attention.py)
 MLA_SCOPES = {"mla_q", "mla_kv"}
+#: ... and those of a Gated DeltaNet mixer (models/gated_deltanet.py)
+DELTA_SCOPES = {"delta_proj", "delta_conv", "delta_scan", "delta_gate"}
 
 
 def test_the_token_denoisers_classes_are_in_its_view_program():
@@ -513,6 +515,35 @@ def test_a_latent_attention_stack_splits_into_its_classes():
     assert len(named_untagged) / len(tags) < 0.002, named_untagged[:5]
 
 
+def test_a_gated_deltanet_stack_splits_into_its_classes():
+    """The view program of the ``qwen3_next`` preset: the Gated DeltaNet
+    mixer's four classes, the gated attention layer whole as
+    ``attention`` with its rotary turn as ``rope``, the router, the
+    experts and the gated shared expert (``mlp``), the norms under
+    ``residual``."""
+    from diff3d_tpu.config import gdn_moe_test_config
+    from diff3d_tpu.models import build_model
+    from diff3d_tpu.sampling import Sampler
+
+    cfg = gdn_moe_test_config()
+    model = build_model(cfg)
+    params = jax.eval_shape(
+        lambda: init_params(model, cfg, jax.random.PRNGKey(0)))
+    low = Sampler(model, params, cfg, sampler_kind="ddim",
+                  steps=2).lower_step_many(1, 2)
+    with no_compile_cache():
+        text = low.compile().as_text()
+    ops = op_names(text)
+    tags = [op_class(n)[0] for _, n in ops]
+    assert set(tags) - {None} == DELTA_SCOPES | {
+        "rope", "attention", "mlp", "moe_router", "experts", "residual",
+        "patch_embed", "conditioning", "sampler", "record"}
+    assert tags.count(None) / len(tags) < 0.15
+    named_untagged = [n for (_, n), t in zip(ops, tags)
+                      if t is None and "/" in n]
+    assert len(named_untagged) / len(tags) < 0.002, named_untagged[:5]
+
+
 def test_every_class_is_in_the_compiled_programs_and_few_ops_have_none(
         programs):
     train, view = programs["lower"]()
@@ -531,7 +562,7 @@ def test_every_class_is_in_the_compiled_programs_and_few_ops_have_none(
                           if t is None and n]
         assert len(named_untagged) / len(tags) < 0.002, named_untagged[:5]
     assert seen - {None} == (set(SCOPES) - TOKEN_SCOPES - HYBRID_SCOPES
-                             - MLA_SCOPES)
+                             - MLA_SCOPES - DELTA_SCOPES)
     # forward and backward share a tag; the backward is told by transpose(
     bwd = {op_class(n) for _, n in op_names(texts["train"])}
     for tag in ("conv", "film", "groupnorm", "attention", "conditioning",
